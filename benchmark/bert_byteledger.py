@@ -19,6 +19,8 @@ import numpy as onp
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     mode = os.environ.get("BBL_MODE", "baseline")
     batch = int(os.environ.get("BBL_BATCH", 64))
     seq = int(os.environ.get("BBL_SEQ", 128))

@@ -12,8 +12,10 @@ import jax.numpy as jnp
 import os
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import mxnet_tpu as mx
-from mxnet_tpu import autograd
+from mxnet_tpu import autograd, cache
 from mxnet_tpu.ndarray import ndarray as ndmod
+
+cache.enable_compile_cache()
 
 
 def fetch(nd_or_jax):

@@ -4,6 +4,9 @@ import jax, jax.numpy as jnp
 import os
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import mxnet_tpu as mx
+from mxnet_tpu import cache
+
+cache.enable_compile_cache()
 
 def timeit(label, f, n=8, warmup=3):
     for _ in range(warmup): f()

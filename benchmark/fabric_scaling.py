@@ -145,6 +145,8 @@ def run_slice(net, ref_out, probes, size, args):
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     env = os.environ.get
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--sizes", default=env("FS_SIZES", "1,2,4,8"))
@@ -218,4 +220,8 @@ def main():
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    from mxnet_tpu import runtime
+    # the one-device reference endpoint follows the context: the chip, or
+    # the CPU only under an explicit JAX_PLATFORMS=cpu
+    with runtime.measurement_context():
+        sys.exit(main())

@@ -20,6 +20,8 @@ import numpy as onp
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     S = int(os.environ.get("FSW_S", 32768))
     B, H, D = 1, 12, 64
     reps = int(os.environ.get("FSW_REPS", 3))

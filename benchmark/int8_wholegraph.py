@@ -180,6 +180,8 @@ def _time(fn, args):
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     batch = int(os.environ.get("I8_BATCH", 128))
     import jax
     import jax.numpy as jnp

@@ -34,6 +34,8 @@ def build_step(batch=128):
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     import jax
     step, x, y = build_step(int(os.environ.get("BENCH_BATCH", 128)))
     placed = step.place_batch(x, y)

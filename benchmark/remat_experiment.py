@@ -43,5 +43,7 @@ def run(policy, batch=128, k=40, calls=3):
 
 
 if __name__ == "__main__":
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     for policy in sys.argv[1:] or ["none", "conv"]:
         run(policy)

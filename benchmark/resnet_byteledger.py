@@ -25,6 +25,8 @@ import numpy as onp
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     mode = os.environ.get("RBL_MODE", "baseline")
     batch = int(os.environ.get("RBL_BATCH", 128))
     k = int(os.environ.get("RBL_K", 20))

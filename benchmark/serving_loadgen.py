@@ -113,6 +113,14 @@ Prints one JSON line per (dtype, concurrency[, tenant]):
    "compiles":..., "batches":...}
 and a final per-dtype summary line with the direct (unserved) single-batch
 forward rate for reference.
+
+Placement: every net, endpoint and pool is built under
+``mxnet_tpu.runtime.measurement_context()`` — ``mx.tpu(0)`` when JAX's default
+backend is the TPU, ``mx.cpu(0)`` only when the run was started with
+``JAX_PLATFORMS=cpu``, an error otherwise — and every row carries
+``platform`` / ``device_kind`` / ``device_count``. The ``--restart`` parent
+never initialises a JAX backend: a chip belongs to one process, and its two
+children need it in turn.
 """
 import argparse
 import json
@@ -125,6 +133,12 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as onp
+
+
+def _emit(row):
+    """Print one result row; every row names the device it was taken on."""
+    from mxnet_tpu import runtime
+    print(json.dumps({**row, **runtime.device_row()}), flush=True)
 
 
 def _build_net(name, classes, img, dtype):
@@ -345,14 +359,14 @@ def _run_decode(args):
         "step_p50_ms": round(snap["step"]["p50_us"] / 1e3, 2),
         "compiles": compiles_warm,
     })
-    print(json.dumps(row), flush=True)
+    _emit(row)
     for tenant in ("gold", "bulk"):     # the per-tenant inter-token table
         trow = {"decode": True, "tenant": tenant,
                 "seqs": per[tenant]["seqs"],
                 "tokens": per[tenant]["tokens"]}
         trow.update({f"intertoken_{k}": v
                      for k, v in _percentiles(per[tenant]["gaps_ms"]).items()})
-        print(json.dumps(trow), flush=True)
+        _emit(trow)
 
 
 def _run_dlrm(args):
@@ -436,7 +450,7 @@ def _run_dlrm(args):
            "compiles": compiles_warm}
     row.update(_percentiles(lat_ms))
     row.update(_queue_wait_fields(snap))
-    print(json.dumps(row), flush=True)
+    _emit(row)
     serving.unregister("loadgen_dlrm")
 
 
@@ -464,19 +478,22 @@ def _run_hedge(args):
     with the perf-gate metrics: hedge rate, win rate, the wasted-duplicate-
     work share (bounded by the hedge token bucket) and budget
     exhaustions."""
-    from mxnet_tpu import config, serving
+    from mxnet_tpu import config, current_context, serving
     from mxnet_tpu.resilience import faults
     from mxnet_tpu.serving import DeadlineExceeded, tailguard
 
     in_dim, n = 8, args.tail_requests
     deadline_ms = args.deadline_ms or 30000.0
 
+    ctx = current_context()
+
     def factory(rid):
-        srv = serving.InferenceServer(batch_timeout_ms=1.0,
-                                      max_queue=max(256, n * 8))
-        srv.register(serving.ModelEndpoint(
-            "loadgen_hedge", _tail_mlp(in_dim), input_shapes=(in_dim,),
-            max_batch_size=4))
+        with ctx:       # a pool may build a replica on another thread
+            srv = serving.InferenceServer(batch_timeout_ms=1.0,
+                                          max_queue=max(256, n * 8))
+            srv.register(serving.ModelEndpoint(
+                "loadgen_hedge", _tail_mlp(in_dim), input_shapes=(in_dim,),
+                max_batch_size=4))
         return srv
 
     saved = config.get("MXNET_HEDGE_DELAY_MIN_MS")
@@ -528,7 +545,7 @@ def _run_hedge(args):
            "hedge_budget_exhausted": d["mxtpu_hedge_budget_exhausted_total"],
            "hedge_budget_ratio": ratio}
     row.update(_percentiles(lat_ms))
-    print(json.dumps(row), flush=True)
+    _emit(row)
 
 
 def _run_storm(args):
@@ -537,19 +554,22 @@ def _run_storm(args):
     every drop — ``storm_client_error_rate`` is the ==0 perf-gate row —
     and ``storm_amplification`` (fault-site attempts per request) shows the
     budget holding re-send traffic near 1x."""
-    from mxnet_tpu import serving
+    from mxnet_tpu import current_context, serving
     from mxnet_tpu.resilience import faults
     from mxnet_tpu.serving.fabric import FrontDoor
     from mxnet_tpu.serving.tailguard import RETRY_BUDGETS
 
     in_dim, n = 8, args.tail_requests
 
+    ctx = current_context()
+
     def factory(name):
-        srv = serving.InferenceServer(batch_timeout_ms=1.0,
-                                      max_queue=max(256, n * 8))
-        srv.register(serving.ModelEndpoint(
-            "loadgen_storm", _tail_mlp(in_dim), input_shapes=(in_dim,),
-            max_batch_size=4))
+        with ctx:       # a front door may rebuild a host on another thread
+            srv = serving.InferenceServer(batch_timeout_ms=1.0,
+                                          max_queue=max(256, n * 8))
+            srv.register(serving.ModelEndpoint(
+                "loadgen_storm", _tail_mlp(in_dim), input_shapes=(in_dim,),
+                max_batch_size=4))
         srv.start()
         return srv
 
@@ -589,7 +609,7 @@ def _run_storm(args):
            "retry_budget_exhausted": _metric_total(
                "mxtpu_retry_budget_exhausted_total") - ex_before}
     row.update(_percentiles(lat_ms))
-    print(json.dumps(row), flush=True)
+    _emit(row)
 
 
 def _run_restart_child(args, phase):
@@ -667,7 +687,7 @@ def _run_restart_child(args, phase):
         serving.unregister("restart_sharded")
     if args.decode:
         serving.unregister("restart_lm")
-    print(json.dumps({
+    _emit({
         "restart_child": phase,
         "restart_to_first_request_s": round(
             max(dense_t, dec_t or 0.0, fab_t or 0.0), 3),
@@ -681,7 +701,7 @@ def _run_restart_child(args, phase):
         "duplicates": cls["duplicates"],
         "dense_digest": dense_digest,
         "decode_digest": dec_digest,
-    }), flush=True)
+    })
     return 0
 
 
@@ -691,6 +711,10 @@ def _run_restart(args):
     compile nothing) and emit the perf-gate row."""
     import subprocess
     import tempfile
+    from jax._src import xla_bridge
+    # the device on this parent's rows is copied from its children's: asking
+    # JAX here would take the chip from them
+    from mxnet_tpu.runtime import DEVICE_ROW_KEYS as _DEVICE_KEYS
     cache_dir = tempfile.mkdtemp(prefix="slg-exec-cache-")
     ledger_dir = tempfile.mkdtemp(prefix="slg-ledger-")
     child_flags = ["--model", args.model, "--img", str(args.img),
@@ -715,6 +739,7 @@ def _run_restart(args):
     spool_dir = str(_config.get("MXNET_SPAN_SPOOL_DIR", "") or "")
     for phase in ("cold", "warm"):
         env = dict(os.environ)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)   # cold means cold
         env["MXNET_EXEC_CACHE_DIR"] = cache_dir
         env["MXNET_COMPILE_LEDGER_DIR"] = ledger_dir
         # only AOT serving compiles are the contract; keep the eager jit
@@ -733,6 +758,12 @@ def _run_restart(args):
             env["MXNET_SPAN_SPOOL_DIR"] = spool_dir
         cmd = [sys.executable, os.path.abspath(__file__),
                "--restart-child", phase] + child_flags
+        # a chip belongs to one process: this parent imports the package
+        # for its trace id, but must never initialise a backend, or the
+        # child would find the chip taken
+        assert not xla_bridge.backends_are_initialized(), \
+            "--restart parent initialised a JAX backend; its children " \
+            "cannot have the chip"
         proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
         row = None
         for line in proc.stdout.splitlines():
@@ -754,7 +785,7 @@ def _run_restart(args):
                              ("restart_to_first_request_s", "dense_first_s",
                               "fabric_first_s", "decode_first_s",
                               "compiles", "cache_hits",
-                              "fresh_compiles", "duplicates")}}),
+                              "fresh_compiles", "duplicates") + _DEVICE_KEYS}}),
               flush=True)
     cold, warm = rows["cold"], rows["warm"]
     assert warm["fresh_compiles"] == 0, \
@@ -766,10 +797,9 @@ def _run_restart(args):
     assert warm["cache_hits"] == cold["compiles"], \
         f"warm hit {warm['cache_hits']} entries but cold compiled " \
         f"{cold['compiles']}"
-    for k in ("dense_digest", "fabric_digest", "decode_digest"):
+    for k in ("dense_digest", "fabric_digest", "decode_digest") + _DEVICE_KEYS:
         assert cold[k] == warm[k], \
-            f"{k}: warm first-request output differs from cold " \
-            f"({cold[k]} vs {warm[k]})"
+            f"{k}: warm phase differs from cold ({cold[k]} vs {warm[k]})"
     warm_s, cold_s = (warm["restart_to_first_request_s"],
                       cold["restart_to_first_request_s"])
     print(json.dumps({
@@ -779,6 +809,7 @@ def _run_restart(args):
         "warm_fresh_compiles": warm["fresh_compiles"],
         "warm_cache_hits": warm["cache_hits"],
         "outputs_bitwise_equal": True,
+        **{k: warm[k] for k in _DEVICE_KEYS},
     }), flush=True)
     return 0
 
@@ -849,11 +880,17 @@ def _parse_args():
 
 def main():
     args = _parse_args()
-    if args.restart_child:
-        return _run_restart_child(args, args.restart_child)
     if args.restart:
-        return _run_restart(args)
-    return _run_sweep(args)
+        return _run_restart(args)    # parent only: stays off JAX
+    from mxnet_tpu import cache, runtime
+    # every net, endpoint and pool below is built on this thread, so the
+    # scope places them all: the chip, or the CPU the user asked for
+    with runtime.measurement_context():
+        if args.restart_child:
+            # no JAX compile cache here: the cold phase has to compile
+            return _run_restart_child(args, args.restart_child)
+        cache.enable_compile_cache()
+        return _run_sweep(args)
 
 
 def _run_sweep(args):
@@ -919,7 +956,7 @@ def _run_sweep(args):
                     snaps[names[0]] if tenants == 1 else
                     max((snaps[n] for n in names),
                         key=lambda s: s["latency"]["p99_us"])))
-                print(json.dumps(agg), flush=True)
+                _emit(agg)
                 if tenants > 1:
                     for name in names:        # the per-tenant latency table
                         row = {"tenant": name, "conc": conc,
@@ -927,7 +964,7 @@ def _run_sweep(args):
                         row.update(_percentiles(per[name]["lat_ms"]))
                         row.update(_queue_wait_fields(snaps[name]))
                         row["shed"] = snaps[name]["shed"]
-                        print(json.dumps(row), flush=True)
+                        _emit(row)
         finally:
             server.stop(drain=True)
         snaps = serving.stats()
@@ -936,14 +973,14 @@ def _run_sweep(args):
                 compiles_after_warmup[name], \
                 "serving traffic recompiled beyond warmup buckets"
         direct = _direct_rate(nets[0], img, in_dtype, max_batch)
-        print(json.dumps({
+        _emit({
             "dtype": dtype, "summary": True,
             "direct_b{}_img_s".format(max_batch): round(direct, 1),
             "buckets": list(eps[0].buckets),
             "compiles": sum(snaps[n]["counters"]["compiles"] for n in names),
             "prep_overlap_ratio": round(
                 server.health()["prep_overlap_ratio"], 3),
-        }), flush=True)
+        })
         for name in names:
             serving.unregister(name)
 
@@ -964,23 +1001,23 @@ def _run_sweep(args):
     # train-step + dataloader families (zero here), device memory gauges
     from mxnet_tpu import telemetry
     tsnap = telemetry.snapshot()
-    print(json.dumps({"telemetry_summary": telemetry.summary_line(),
-                      "metric_families": len(tsnap["metrics"])}), flush=True)
+    _emit({"telemetry_summary": telemetry.summary_line(),
+           "metric_families": len(tsnap["metrics"])})
     # compile-ledger rollup: every serving-bucket compile of the run, the
     # distinct programs behind them, and the seconds re-spent on programs
     # the process had already compiled (what a persistent cache would save)
     cls = telemetry.compile_ledger.summary()
-    print(json.dumps({"compile_ledger": {
+    _emit({"compile_ledger": {
         "compiles": cls["compiles"],
         "distinct_fingerprints": cls["distinct_fingerprints"],
         "duplicates": cls["duplicates"],
         "dup_waste_s": cls["dup_waste_s"],
         "wall_s": round(cls["lower_s"] + cls["compile_s"], 3),
-    }}), flush=True)
+    }})
     dump_path = os.environ.get("SLG_TELEMETRY", "")
     if dump_path:
         telemetry.dump(dump_path)
-        print(json.dumps({"telemetry_snapshot": dump_path}), flush=True)
+        _emit({"telemetry_snapshot": dump_path})
     return 0
 
 

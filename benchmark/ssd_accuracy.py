@@ -20,6 +20,8 @@ sys.path.insert(0, os.path.join(
 
 
 def main():
+    from mxnet_tpu import cache
+    cache.enable_compile_cache()
     from train_shapes import evaluate, train
     from mxnet_tpu.test_utils import get_shapes_detection
 

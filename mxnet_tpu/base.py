@@ -104,8 +104,10 @@ class Context:
             devs = jax.local_devices(backend="cpu")
         else:
             devs = _accelerator_devices()
-            if not devs:  # CPU-only host: transparently fall back (tests, CI)
-                devs = jax.local_devices(backend="cpu")
+            if not devs:
+                raise MXNetError(
+                    f"{self}: JAX found no accelerator (default backend "
+                    f"{jax.default_backend()!r}); a CPU run uses mx.cpu()")
         if self.device_id >= len(devs):
             raise MXNetError(f"{self}: only {len(devs)} device(s) available")
         return devs[self.device_id]

@@ -20,8 +20,9 @@ Correctness before speed:
     miss, never a wrong load;
   * entries are two files, payload (``ent-<key>.bin``) and manifest
     (``ent-<key>.json``), each written tmp + fsync + rename so a reader
-    only ever sees a complete entry; concurrent writers race benignly
-    (last atomic rename wins, both wrote identical bytes);
+    only ever sees complete files; concurrent writers of one key each
+    write until the payload on disk is the one its manifest names (two
+    serializations of one executable differ in their bytes);
   * the manifest carries the payload's sha256; :func:`load` verifies it
     before unpickling, so a truncated or bit-flipped payload is detected,
     warned about, deleted, and answered with a miss — the caller falls
@@ -137,11 +138,8 @@ def _device_identity() -> Dict[str, Any]:
         out["platform"] = str(devs[0].platform) if devs else "?"
         out["device_kind"] = str(devs[0].device_kind) if devs else "?"
         out["device_count"] = len(devs)
-        try:
-            out["platform_version"] = str(
-                jax.extend.backend.get_backend().platform_version)
-        except Exception as e:  # optional key refinement, not load-bearing
-            log.debug("platform_version probe failed: %s", e)
+        from jax.extend import backend as _backend
+        out["platform_version"] = str(_backend.get_backend().platform_version)
     except Exception as e:      # no backend yet: '?' keys still partition safely
         log.debug("device identity probe failed: %s", e)
         out["platform"] = "?"
@@ -204,6 +202,17 @@ def _atomic_write(path: str, data: bytes):
         except OSError:
             pass
         raise
+
+
+def _pair_agrees(bin_path: str, man_path: str) -> bool:
+    """Does the payload on disk carry the digest its manifest names?"""
+    try:
+        with open(man_path, "rb") as f:
+            want = json.loads(f.read().decode("utf-8")).get("payload_sha256")
+        with open(bin_path, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest() == want
+    except (OSError, ValueError):
+        return False
 
 
 def _total_bytes(d: str) -> int:
@@ -277,12 +286,24 @@ def store(key: Dict[str, Any], compiled) -> bool:
         digest = key_digest(key)
         os.makedirs(d, exist_ok=True)
         bin_path, man_path = _paths(d, digest)
-        _atomic_write(bin_path, blob)
+        # the serialized executable and its shardings name their devices by
+        # id; load() hands exactly these back as the execution devices
         manifest = {"key": key, "payload_sha256":
                     hashlib.sha256(blob).hexdigest(),
-                    "payload_bytes": len(blob), "created": time.time()}
-        _atomic_write(man_path, (json.dumps(manifest, sort_keys=True)
-                                 + "\n").encode("utf-8"))
+                    "payload_bytes": len(blob), "created": time.time(),
+                    "device_ids": [int(dv.id) for dv in
+                                   compiled.runtime_executable()
+                                   .local_devices()]}
+        man_blob = (json.dumps(manifest, sort_keys=True) + "\n").encode("utf-8")
+        # two serializations of one executable are not the same bytes, so
+        # writers racing on one key can leave one's payload under the
+        # other's manifest. Write again until the pair on disk agrees
+        # (anybody's pair will do: the key says they are interchangeable)
+        for _ in range(16):
+            _atomic_write(bin_path, blob)
+            _atomic_write(man_path, man_blob)
+            if _pair_agrees(bin_path, man_path):
+                break
         _evict(d, max_bytes())
         _BYTES.set(_total_bytes(d))
         with _LOCK:
@@ -334,10 +355,17 @@ def load(key: Dict[str, Any]):
             _drop_entry(d, digest)
             _BYTES.set(_total_bytes(d))
             return _miss("corrupt")
+        import jax
         from jax.experimental import serialize_executable as _jse
         t0 = time.perf_counter()
         payload, in_tree, out_tree = pickle.loads(blob)
-        compiled = _jse.deserialize_and_load(payload, in_tree, out_tree)
+        # execution_devices=None would mean EVERY visible device: a
+        # one-device or one-slice executable then refuses its arguments on
+        # any host that has more
+        by_id = {dv.id: dv for dv in jax.devices()}
+        compiled = _jse.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in manifest["device_ids"]])
         dt = time.perf_counter() - t0
         _DESER_S.inc(dt)
         try:

@@ -90,15 +90,6 @@ def dedup_ids(idx, vocab: int):
     return _dedup_ids_fn()(idx, int(vocab))
 
 
-def _shard_map():
-    try:
-        from jax import shard_map as sm
-        return sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm
-
-
 class ShardedEmbedding:
     """One embedding table, partitioned (or replicated) over a mesh axis.
 
@@ -231,16 +222,17 @@ class ShardedEmbedding:
                              tbl.at[local].get(mode="fill", fill_value=0), 0)
             return jax.lax.psum(rows, axis)
 
-        return _shard_map()(
+        return jax.shard_map(
             _local, mesh=self.mesh.mesh,
             in_specs=(P(axis, None), P()), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
 
     def dispatch_gather_fn(self):
         """Pure ``(table, local_ids) -> (n_local, D)`` for ids SHARDED over
         the axis: all_to_all index dispatch → local gather → all_to_all
         result return (the EP-style exchange; one owner contributes each
         row, the sum over owners adds exact zeros)."""
+        import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from ..parallel import collectives
@@ -264,15 +256,16 @@ class ShardedEmbedding:
             back = collectives.all_to_all(rows, axis, 0, 0)
             return back.sum(0)
 
-        return _shard_map()(
+        return jax.shard_map(
             _local, mesh=self.mesh.mesh,
             in_specs=(P(axis, None), P(axis)), out_specs=P(axis),
-            check_rep=False)
+            check_vma=False)
 
     def scatter_add_fn(self):
         """Pure ``(table, uniq_ids, updates) -> table`` for ids REPLICATED
         over the axis: shard-local scatter-add of already-deduped row
         updates (non-owned and sentinel rows drop)."""
+        import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
@@ -287,15 +280,16 @@ class ShardedEmbedding:
             local, _ = self._owner_local(jnp, ids)
             return tbl.at[local].add(upd.astype(tbl.dtype), mode="drop")
 
-        return _shard_map()(
+        return jax.shard_map(
             _local, mesh=self.mesh.mesh,
             in_specs=(P(axis, None), P(), P()), out_specs=P(axis, None),
-            check_rep=False)
+            check_vma=False)
 
     def dispatch_scatter_add_fn(self):
         """Pure ``(table, local_ids, local_updates) -> table`` for ids
         SHARDED over the axis: the reverse exchange — route each shard's row
         gradients to the owning shards, then scatter-add locally."""
+        import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from ..parallel import collectives
@@ -317,10 +311,10 @@ class ShardedEmbedding:
                 recv_upd.reshape(-1, upd.shape[-1]).astype(tbl.dtype),
                 mode="drop")
 
-        return _shard_map()(
+        return jax.shard_map(
             _local, mesh=self.mesh.mesh,
             in_specs=(P(axis, None), P(axis), P(axis)),
-            out_specs=P(axis, None), check_rep=False)
+            out_specs=P(axis, None), check_vma=False)
 
     # ------------------------------------------------------------------
     # telemetry

@@ -43,7 +43,7 @@ import numpy as onp
 
 from ..base import MXNetError
 from ..resilience import faults as _faults
-from .table import ShardedEmbedding, dedup_ids, _shard_map
+from .table import ShardedEmbedding, dedup_ids
 
 __all__ = ["DLRMTrainStep", "init_mlp_params", "dlrm_forward", "bce_loss",
            "synthetic_dlrm_batches"]
@@ -228,10 +228,10 @@ class DLRMTrainStep:
             mlp = jax.tree_util.tree_map(lambda w, g: w - lr * g, mlp, g_mlp)
             return tbl, mlp, jax.lax.pmean(loss, axis)
 
-        wrapped = _shard_map()(
+        wrapped = jax.shard_map(
             _local, mesh=t.mesh.mesh,
             in_specs=(P(axis, None), P(), P(axis), P(axis), P(axis)),
-            out_specs=(P(axis, None), P(), P()), check_rep=False)
+            out_specs=(P(axis, None), P(), P()), check_vma=False)
         return jax.jit(wrapped)
 
     # -- host surface ---------------------------------------------------

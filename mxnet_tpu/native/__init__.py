@@ -8,13 +8,17 @@ read/write dependency semantics. recordio.cc implements the dmlc recordio
 framing byte-compatibly; image_pipeline.cc is the ImageRecordIter stack
 (decode→augment→batch→prefetch threads over OpenCV).
 
-Built lazily with `make` on first use (ctypes bindings — no pybind11 in this
-image). Falls back gracefully: `available()` is False if the toolchain or a
-build dependency is missing, and the Python implementations take over.
+Built with `make` on first use in every process (ctypes bindings — no
+pybind11 in this image); `make` is a no-op when the library is newer than its
+sources, and rebuilds one that is not, so a stale binary never outlives them.
+No `.so` is tracked in git. Falls back gracefully: `available()` is False if
+the toolchain or a build dependency is missing, and the Python
+implementations take over.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -29,8 +33,12 @@ _build_error = None
 def _build():
     global _build_error
     try:
-        res = subprocess.run(["make", "-C", _DIR], capture_output=True,
-                             text=True, timeout=300)
+        # one make at a time across processes (test workers start together):
+        # a second one would otherwise load a half-written library
+        with open(os.path.join(_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            res = subprocess.run(["make", "-C", _DIR], capture_output=True,
+                                 text=True, timeout=300)
         if res.returncode != 0:
             _build_error = res.stderr[-2000:]
             return False
@@ -46,7 +54,7 @@ def get_lib():
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)

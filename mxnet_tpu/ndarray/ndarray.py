@@ -61,7 +61,9 @@ class NDArray:
                 elif npdata.dtype == onp.int64:
                     npdata = npdata.astype(onp.int32)  # x64 disabled on this stack
             dev = (ctx or current_context()).jax_device()
-            arr = jax.device_put(jnp.asarray(npdata), dev)
+            # straight to the context's device: jnp.asarray would stage the
+            # host data on JAX's default device (the chip) first
+            arr = jax.device_put(npdata, dev)
             if dtype is not None:
                 arr = arr.astype(dtype)
         self._data = arr

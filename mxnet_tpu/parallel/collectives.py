@@ -94,12 +94,8 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     """
     import jax
     import os
-    try:
-        from jax._src.distributed import global_state
-        if global_state.client is not None:
-            return  # already initialized by the launcher
-    except ImportError:
-        pass  # private API moved: fall through, tolerate double-init below
+    if jax.distributed.is_initialized():
+        return  # already initialized by the launcher
     if coordinator_address is None and "MXNET_TPU_COORDINATOR" in os.environ:
         # env bootstrapping written by tools/launch.py (the DMLC_PS_ROOT_URI/
         # DMLC_NUM_WORKER/DMLC_ROLE analog); missing count/id fall through as
@@ -110,13 +106,9 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
         if process_id is None and "MXNET_TPU_WORKER_ID" in os.environ:
             process_id = int(os.environ["MXNET_TPU_WORKER_ID"])
     if coordinator_address is not None:
-        try:
-            jax.distributed.initialize(coordinator_address=coordinator_address,
-                                       num_processes=num_processes,
-                                       process_id=process_id)
-        except RuntimeError as e:
-            if "already" not in str(e).lower():
-                raise
+        jax.distributed.initialize(coordinator_address=coordinator_address,
+                                   num_processes=num_processes,
+                                   process_id=process_id)
 
 
 def rank() -> int:

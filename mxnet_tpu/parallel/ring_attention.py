@@ -95,11 +95,8 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = False,
     o0 = jnp.zeros((B, H, S, D), jnp.float32)
     # mark the fresh accumulators as varying over the ring axis so the scan
     # carry type matches its output (shard_map vma tracking)
-    try:
-        m0, l0, o0 = (lax.pcast(a, (axis_name,), to="varying")
-                      for a in (m0, l0, o0))
-    except AttributeError:  # older jax: no vma tracking, nothing to do
-        pass
+    m0, l0, o0 = (lax.pcast(a, (axis_name,), to="varying")
+                  for a in (m0, l0, o0))
     carry = (k, v, m0, l0, o0)
     carry, _ = lax.scan(body, carry, jnp.arange(n))
     _, _, m_f, l_f, o_f = carry

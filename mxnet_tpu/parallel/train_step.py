@@ -137,8 +137,11 @@ class ParallelTrainStep:
             compute_dtype = jnp.dtype(compute_dtype)
         self._compute_dtype = compute_dtype
 
-        # place parameter values on the mesh
-        self._params = [jax.device_put(p.data().data, sh)
+        # place parameter values on the mesh. Never an alias of the block's
+        # own array, which device_put may hand back when that array already
+        # sits on a device of the mesh: the first donating step would
+        # delete the block's parameters with it
+        self._params = [jax.device_put(p.data().data, sh, may_alias=False)
                         for p, sh in zip(params, self._param_shardings)]
 
         # optimizer state per trainable param, sharded like its param

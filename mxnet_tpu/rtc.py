@@ -41,17 +41,23 @@ class Kernel:
         args: input NDArrays; out_shapes: list of output shapes (required);
         out_dtypes: matching dtypes (default: dtype of the first input)."""
         import jax
+        import jax.numpy as jnp
         import numpy as onp
         from jax.experimental import pallas as pl
 
         if out_shapes is None:
             raise MXNetError("launch requires out_shapes")
-        arrays = [a.data if isinstance(a, NDArray) else a for a in args]
+        arrays = [a.data if isinstance(a, NDArray) else jnp.asarray(a)
+                  for a in args]
         if out_dtypes is None:
             out_dtypes = [arrays[0].dtype] * len(out_shapes)
+        # Mosaic compiles for the TPU only; anywhere else the kernel runs
+        # in the interpreter. Decided by where the first input lives, since
+        # that is where the call will run
+        interpret = any(d.platform != "tpu" for d in arrays[0].devices())
         key = (tuple(tuple(s) for s in out_shapes),
                tuple(str(d) for d in out_dtypes),
-               None if grid is None else tuple(grid),
+               None if grid is None else tuple(grid), interpret,
                # values matter, not just names: a different in_specs/out_specs
                # must not reuse the stale executable
                tuple(sorted((k, repr(v)) for k, v in pallas_kwargs.items())))
@@ -60,7 +66,6 @@ class Kernel:
             out_shape = [jax.ShapeDtypeStruct(tuple(s), onp.dtype(d))
                          for s, d in zip(out_shapes, out_dtypes)]
             shape_arg = out_shape if len(out_shape) > 1 else out_shape[0]
-            interpret = jax.default_backend() != "tpu"  # Mosaic needs TPU
             call = jax.jit(pl.pallas_call(
                 self._fn, out_shape=shape_arg,
                 **({"grid": tuple(grid)} if grid else {}),
@@ -83,10 +88,7 @@ class PallasModule:
         # the kernel source is Python-over-Pallas; give it the usual aliases
         import jax
         import jax.numpy as jnp
-        try:
-            from jax.experimental import pallas as pl
-        except ImportError:  # pragma: no cover
-            pl = None
+        from jax.experimental import pallas as pl
         self._namespace.update({"jax": jax, "jnp": jnp, "pl": pl})
         try:
             exec(compile(source, "<rtc>", "exec"), self._namespace)

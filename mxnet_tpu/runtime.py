@@ -65,3 +65,37 @@ def feature_list():
 
 
 libinfo_features = feature_list
+
+
+# ---------------------------------------------------------------------------
+# where a measurement runs (bench.py, benchmark/*.py, chip_smoke.py)
+# ---------------------------------------------------------------------------
+DEVICE_ROW_KEYS = ("platform", "device_kind", "device_count")
+
+
+def device_row() -> dict:
+    """The device as JAX reports it, under DEVICE_ROW_KEYS; every row a
+    measurement prints carries these, so a CPU timing can never pass for a
+    chip's."""
+    import jax
+    devs = jax.devices()
+    return dict(zip(DEVICE_ROW_KEYS,
+                    (devs[0].platform, devs[0].device_kind, len(devs))))
+
+
+def measurement_context():
+    """The context a measurement places its work on: ``tpu(0)`` when JAX's
+    default backend is the TPU, ``cpu(0)`` only when the user asked for a
+    CPU run with ``JAX_PLATFORMS=cpu``. A host where JAX found no chip and
+    nobody said so is an error, not a CPU run."""
+    import os
+    import jax
+    from .base import MXNetError, cpu, tpu
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return tpu(0)
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return cpu(0)
+    raise MXNetError(
+        f"JAX's default backend is {backend!r}, not the TPU; set "
+        "JAX_PLATFORMS=cpu to run on the CPU on purpose")

@@ -223,7 +223,12 @@ class ModelEndpoint:
         donation (with a warning), so keep it off there. Decided once, before
         the first compile, so every bucket shares one executable signature —
         the compiled-once-per-bucket property is preserved."""
-        return self.ctx.jax_device().platform in ("tpu", "gpu")
+        return self._platform() in ("tpu", "gpu")
+
+    def _platform(self) -> str:
+        """Platform of the device(s) the executables run on. Sharded
+        endpoints answer from their mesh, not from ``ctx``."""
+        return self.ctx.jax_device().platform
 
     def _place_inputs(self, arrays):
         """Host->device placement of one batch's input arrays. The hook a
@@ -234,10 +239,14 @@ class ModelEndpoint:
         return tuple(jax.device_put(a, dev) for a in arrays)
 
     def _jit_infer(self, infer, donate):
-        """Wrap the traced inference function in ``jax.jit``. Sharded
+        """Wrap the traced inference function in ``jax.jit``, pinned to the
+        context's device: lowered from bare ShapeDtypeStructs it would
+        compile for JAX's default device, whatever ``ctx`` says. Sharded
         endpoints override to pin NamedSharding in/out shardings."""
         import jax
-        return jax.jit(infer, donate_argnums=donate)
+        dev = jax.sharding.SingleDeviceSharding(self.ctx.jax_device())
+        return jax.jit(infer, donate_argnums=donate, in_shardings=dev,
+                       out_shardings=dev)
 
     def _infer_fn(self):
         if self._jfn is None:

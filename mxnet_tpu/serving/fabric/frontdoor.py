@@ -209,7 +209,10 @@ class FrontDoor:
         env["FABRIC_HB_PATH"] = h.hb_path
         env["FABRIC_DUMP_PATH"] = h.dump_path
         env["FABRIC_TICK_S"] = str(_config.get("MXNET_FABRIC_HEARTBEAT_S"))
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the agent is a CPU stand-in whatever the parent runs on: the
+        # front door's own process holds the chip, and a chip has room for
+        # one process
+        env["JAX_PLATFORMS"] = "cpu"
         h.agent = subprocess.Popen(
             [sys.executable, "-c", _HOST_AGENT_SRC], env=env,
             stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)

@@ -157,12 +157,11 @@ class ShardedEndpoint(ModelEndpoint):
             else repl
             for p in self._params)
 
+    def _platform(self) -> str:
+        return self._dmesh.mesh.devices.flat[0].platform
+
     def _device_label(self) -> str:
-        try:
-            platform = self.ctx.jax_device().platform
-        except Exception:
-            platform = "?"
-        return f"{platform}:{_mesh_label(self._dmesh)}"
+        return f"{self._platform()}:{_mesh_label(self._dmesh)}"
 
     def _compile_key(self, bucket: int) -> Dict[str, object]:
         # the mesh label rides into the compile ledger AND the cost-model
@@ -272,12 +271,11 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
         self.pool.update_arrays(jax.device_put(self.pool.k_pool, repl),
                                 jax.device_put(self.pool.v_pool, repl))
 
+    def _platform(self) -> str:
+        return self._dmesh.mesh.devices.flat[0].platform
+
     def _device_label(self) -> str:
-        try:
-            platform = self.ctx.jax_device().platform
-        except Exception:
-            platform = "?"
-        return f"{platform}:{_mesh_label(self._dmesh)}"
+        return f"{self._platform()}:{_mesh_label(self._dmesh)}"
 
     def _cost_key(self, kind: str, bucket: int) -> Dict[str, object]:
         # mirror the dense twin: slice topology reaches the ledger and the

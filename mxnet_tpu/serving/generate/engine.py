@@ -108,7 +108,8 @@ class DecodeEndpoint:
         self.pool = PagedKVPool(name, int(block.num_layers),
                                 int(block.units), self.max_seq_len,
                                 page_size=page_size, num_pages=num_pages,
-                                dtype=self._param_datas()[0].dtype)
+                                dtype=self._param_datas()[0].dtype,
+                                device=self.ctx.jax_device())
 
     # ------------------------------------------------------------------
     def _probe(self):
@@ -145,10 +146,12 @@ class DecodeEndpoint:
         the pool is the largest recurring operand and every step consumes
         the previous step's arrays, so donation makes the cache update
         in-place on TPU/GPU. CPU warns on donation — keep it off there."""
-        try:
-            return self.ctx.jax_device().platform in ("tpu", "gpu")
-        except Exception:
-            return False
+        return self._platform() in ("tpu", "gpu")
+
+    def _platform(self) -> str:
+        """Platform of the device(s) the executables run on. Sharded twins
+        answer from their mesh, not from ``ctx``."""
+        return self.ctx.jax_device().platform
 
     def _param_datas(self):
         return tuple(p.data(self.ctx).data for p in self._params)
@@ -159,14 +162,16 @@ class DecodeEndpoint:
         assignment here; the single-device path needs nothing."""
 
     def _jit_prefill(self, fn, donate):
-        """Wrap the traced prefill; sharded twins add in/out shardings."""
+        """Wrap the traced prefill, pinned to the context's device (see
+        ModelEndpoint._jit_infer); sharded twins pin their mesh instead."""
         import jax
-        return jax.jit(fn, donate_argnums=donate)
+        dev = jax.sharding.SingleDeviceSharding(self.ctx.jax_device())
+        return jax.jit(fn, donate_argnums=donate, in_shardings=dev,
+                       out_shardings=dev)
 
     def _jit_decode(self, fn, donate):
-        """Wrap the traced decode step; sharded twins add shardings."""
-        import jax
-        return jax.jit(fn, donate_argnums=donate)
+        """Wrap the traced decode step; same pinning as the prefill."""
+        return self._jit_prefill(fn, donate)
 
     # ------------------------------------------------------------------
     # traced programs

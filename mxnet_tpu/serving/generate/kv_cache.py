@@ -132,7 +132,9 @@ class PagedKVPool:
 
     def __init__(self, name: str, num_layers: int, kv_dim: int,
                  max_seq_len: int, page_size: Optional[int] = None,
-                 num_pages: Optional[int] = None, dtype="float32"):
+                 num_pages: Optional[int] = None, dtype="float32",
+                 device=None):
+        import jax
         import jax.numpy as jnp
         if page_size is None:
             page_size = int(_config.get("MXNET_KV_PAGE_SIZE"))
@@ -156,8 +158,11 @@ class PagedKVPool:
                 f"pages for max_seq_len={max_seq_len} but the pool only has "
                 f"{self.num_pages - 1} usable pages")
         shape = (self.num_layers, self.num_pages, self.page_size, self.kv_dim)
-        self.k_pool = jnp.zeros(shape, dtype=dtype)
-        self.v_pool = jnp.zeros(shape, dtype=dtype)
+        # allocated on ``device`` (None: JAX's default), never staged
+        # through another one — the pool is the largest array decode holds
+        with jax.default_device(device):
+            self.k_pool = jnp.zeros(shape, dtype=dtype)
+            self.v_pool = jnp.zeros(shape, dtype=dtype)
         self._lock = threading.Lock()
         # LIFO free list, page 0 (scratch) excluded for the pool's lifetime
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))

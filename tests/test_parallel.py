@@ -122,6 +122,23 @@ def test_train_step_batchnorm_aux_updates():
     assert not onp.allclose(before, after)
 
 
+def test_donating_step_leaves_block_parameters_alive():
+    """A block whose parameters sit on a device of the mesh (a net built
+    under ``mx.tpu(0)`` and trained on that chip) keeps them: the step
+    donates its own copies, never an alias of the block's arrays."""
+    import jax
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, in_units=4, activation="relu"), nn.Dense(2))
+    net.initialize()
+    x = onp.random.randn(4, 4).astype("float32")
+    want = net(mx.nd.array(x)).asnumpy()
+    mesh = parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = parallel.ParallelTrainStep(
+        net, gloss.L2Loss(), mx.optimizer.SGD(learning_rate=0.1), mesh)
+    step(x, onp.zeros((4, 2), "float32"))
+    assert onp.array_equal(net(mx.nd.array(x)).asnumpy(), want)
+
+
 def test_param_format_auto_matches_default():
     """param_format='auto' (XLA-chosen carried-state layouts via AOT
     compile) must train to the same weights as the default layout path."""

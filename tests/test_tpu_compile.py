@@ -1,0 +1,106 @@
+"""The main path's kernels, compiled at real widths by the TPU's own compiler
+for a v5e chip that is described, not attached.
+
+Interpret mode (every other kernel test here) cannot see what Mosaic refuses:
+a slice off the tiling, too much fast memory, a shape it cannot partition.
+These compiles can, in a few seconds and with no chip. Nothing runs, so they
+say nothing about results or speed.
+
+Only one process may load the TPU's library, and it keeps it until it exits:
+the topology is described inside a module-scoped fixture (never at import, so
+every xdist worker collects the same tests), all such tests live in this one
+file (one worker gets it), and every compile happens in the test's own process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                  single_query_attention)
+from mxnet_tpu.ops.pallas.fused_conv1x1 import conv1x1_bn_act
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to JAX's persistent
+    # cache but never read back without the chip: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes_dtypes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (B, H, S, D), causal, kernels in the backward: BERT-base at seq 512, whose
+# backward is a dense recompute (S <= 1024), and a 16K-token causal decoder
+# head, whose backward is the two Pallas kernels
+FLASH = [pytest.param((8, 12, 512, 64), False, 0, id="bert_s512"),
+         pytest.param((1, 8, 16384, 128), True, 2, id="causal_s16k")]
+
+
+@pytest.mark.parametrize("shape,causal,bwd_kernels", FLASH)
+def test_flash_forward_compiles_to_one_kernel(one_chip, shape, causal,
+                                              bwd_kernels):
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        interpret=False),
+        *[(shape, jnp.bfloat16)] * 3, sharding=one_chip)
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("shape,causal,bwd_kernels", FLASH)
+def test_flash_gradient_compiles(one_chip, shape, causal, bwd_kernels):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *[(shape, jnp.bfloat16)] * 3, sharding=one_chip)
+    assert text.count("tpu_custom_call") == 1 + bwd_kernels
+
+
+# (N*H*W, Cin, Cout) of ResNet-50's first and last 1x1 convolutions at b32
+@pytest.mark.parametrize("m,k,n", [
+    pytest.param(32 * 56 * 56, 64, 256, id="stage1_56x56_64to256"),
+    pytest.param(32 * 7 * 7, 2048, 512, id="stage4_7x7_2048to512")])
+def test_conv1x1_bn_act_compiles(one_chip, m, k, n):
+    text = _compiled_text(
+        lambda x, w, scale, shift: conv1x1_bn_act(x, w, scale, shift,
+                                                  relu=True),
+        ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
+        ((k,), jnp.float32), ((k,), jnp.float32), sharding=one_chip)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_single_query_attention_is_plain_xla(one_chip):
+    """The decode step's attention at BERT-base widths over a 512-lane
+    context: fused XLA, no kernel of ours, nothing left unfused but the
+    softmax's pieces."""
+    B, L, units, heads = 8, 512, 768, 12
+    f32 = jnp.float32
+    text = _compiled_text(
+        lambda q, kc, vc, kn, vn, lens: single_query_attention(
+            q, kc, vc, kn, vn, lens, heads=heads),
+        ((B, units), f32), ((B, L, units), f32), ((B, L, units), f32),
+        ((B, units), f32), ((B, units), f32), ((B,), jnp.int32),
+        sharding=one_chip)
+    assert "tpu_custom_call" not in text
+    assert "fusion(" in text and "exponential(" in text

@@ -43,7 +43,6 @@ def _gen_corpus(d, bad):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from mxnet_tpu.telemetry import compile_ledger as cl
 
     os.makedirs(d, exist_ok=True)
@@ -108,8 +107,8 @@ def _gen_corpus(d, bad):
     # IR1004 — topology. Both corpora compile the same 2-device psum; the
     # bad key claims a 4-device mesh, the clean key tells the truth.
     mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
-    pf = shard_map(lambda x: jax.lax.psum(x * 2.0, "dp"), mesh=mesh,
-                   in_specs=P("dp"), out_specs=P())
+    pf = jax.shard_map(lambda x: jax.lax.psum(x * 2.0, "dp"), mesh=mesh,
+                       in_specs=P("dp"), out_specs=P())
     jfn = jax.jit(pf)
     compile_(jfn, (sd((8, 16), f32),), "serving_bucket",
              {"endpoint": "shard", "bucket": 8,

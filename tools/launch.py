@@ -10,7 +10,11 @@ variables (MXNET_TPU_COORDINATOR / MXNET_TPU_NUM_WORKERS / MXNET_TPU_WORKER_ID,
 the DMLC_PS_ROOT_URI / DMLC_NUM_WORKER / DMLC_ROLE analog) which
 ``mxnet_tpu.parallel.initialize_distributed()`` — and any ``dist_*`` kvstore —
 reads at startup. On real multi-host TPU pods the runtime provides its own
-launcher; this tool covers local multi-process runs (tests, CPU simulation).
+launcher; this tool covers local multi-process runs on the CPU
+(``JAX_PLATFORMS=cpu``: tests, CPU simulation). Do not run it on a machine
+with chips: it gives no worker a chip of its own, so every worker would reach
+for all of them, and a chip belongs to one process. There one process drives
+all the chips of a host through a mesh (``chip_smoke.py --chips 4``).
 
 Usage:
     python tools/launch.py -n 2 [--launcher local] [--env K=V ...] CMD...

@@ -159,8 +159,7 @@ def gate(budgets, measured):
 
 def _run(cmd, env_extra):
     env = dict(os.environ)
-    env.update(env_extra)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env.update(env_extra)       # the budgets file pins the platform
     proc = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
                           text=True)
     return proc.returncode, proc.stdout, proc.stderr
@@ -417,8 +416,6 @@ def main(argv=None):
     if "loadgen" in sources and "loadgen" in wanted:
         vals, _ = measure_loadgen(env)
         measured.update(vals)
-    if "eager" in sources and "eager" in wanted:
-        measured.update(measure_eager())
     if "restart" in sources and "restart" in wanted:
         vals, _ = measure_restart(env)
         measured.update(vals)
@@ -428,6 +425,10 @@ def main(argv=None):
     if "tailguard" in sources and "tailguard" in wanted:
         vals, _ = measure_tailguard(env)
         measured.update(vals)
+    # last: the one in-process source. Until here this parent has not
+    # touched JAX, so no child found its device taken
+    if "eager" in sources and "eager" in wanted:
+        measured.update(measure_eager())
 
     # metrics whose source was excluded by --only are reported, not gated
     gated_budgets = {
