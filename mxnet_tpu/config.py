@@ -343,7 +343,7 @@ register("MXNET_FLIGHT_DIR", "", str,
          "recording but disables automatic bundle dumps; explicit "
          "flight.dump() still works. Also arms the unhandled-exception "
          "crash hooks at import when set.")
-register("MXNET_FLIGHT_SPANS", 512, int,
+register("MXNET_FLIGHT_SPANS", 8192, int,
          "FlightRecorder: capacity of the finished-span ring buffer.")
 register("MXNET_FLIGHT_EVENTS", 256, int,
          "FlightRecorder: capacity of the structured-event ring buffer "

@@ -32,6 +32,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as onp
 
 from ... import config as _config
+from ... import telemetry as _telemetry
 from ...base import Context, MXNetError, current_context
 from .. import bucketing
 from ..router import StepCostEWMA
@@ -282,17 +283,15 @@ class DecodeEndpoint:
             if comp is not None:
                 return comp
             import jax
-            from ... import telemetry
             from ...resilience import faults as _faults
             from ...telemetry import compile_ledger as _ledger
             from ...telemetry import memstats as _memstats
-            t0 = _now_us()
             _faults.check("compile")
             param_sds = tuple(
                 jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
                 for a in self._param_datas())
-            with telemetry.span("serving.compile", endpoint=self.name,
-                                bucket=bucket, kind=kind):
+            with _telemetry.span("serving.compile", endpoint=self.name,
+                                 bucket=bucket, kind=kind):
                 # compile-once gate (see ModelEndpoint._get_executable):
                 # contenders need this executable and wait for it either way
                 comp = _ledger.lower_and_compile(  # mxlint: disable=CONC202
@@ -309,7 +308,6 @@ class DecodeEndpoint:
                 nbytes=sum(mem.get(k, 0) for k in
                            ("output_bytes", "temp_bytes", "code_bytes")))
             self.stats.record_compile()
-            _ = _now_us() - t0
             return comp
 
     def _get_prefill(self, seq_bucket: int):
@@ -384,18 +382,20 @@ class DecodeEndpoint:
         """Run one prompt through its sequence-length bucket's prefill
         executable; the sequence's pages fill with K/V and the first
         generated token comes back."""
-        import jax
         n = len(prompt)
         S = bucketing.bucket_for(n, self.prefill_buckets)
         comp = self._get_prefill(S)
-        toks = onp.zeros((1, S), onp.int32)
-        toks[0, :n] = prompt
-        length = onp.asarray([n], onp.int32)
+        with _telemetry.span("decode.pack"):
+            toks = onp.zeros((1, S), onp.int32)
+            toks[0, :n] = prompt
+            length = onp.asarray([n], onp.int32)
         t0 = _now_us()
-        next_id, k, v = comp(self._param_datas(), toks, length,
-                             table.reshape(1, -1), self.pool.k_pool,
-                             self.pool.v_pool)
-        out = int(onp.asarray(next_id)[0])     # sync point
+        with _telemetry.span("decode.launch", kind="prefill", bucket=S):
+            next_id, k, v = comp(self._param_datas(), toks, length,
+                                 table.reshape(1, -1), self.pool.k_pool,
+                                 self.pool.v_pool)
+        with _telemetry.span("decode.fetch", kind="prefill"):
+            out = int(onp.asarray(next_id)[0])     # sync point
         self.pool.update_arrays(k, v)
         dt = _now_us() - t0
         self._observe_cost(self.prefill_cost, "prefill", "decode_prefill",
@@ -412,20 +412,23 @@ class DecodeEndpoint:
         n = len(rows)
         B = bucketing.bucket_for(n, self.decode_buckets)
         P = self.pool.pages_per_seq
-        ids = onp.zeros((B,), onp.int32)
-        pos = onp.zeros((B,), onp.int32)
-        tables = onp.zeros((B, P), onp.int32)
-        valid = onp.zeros((B,), bool)
-        for i, (tok, p, table) in enumerate(rows):
-            ids[i] = tok
-            pos[i] = p
-            tables[i] = table
-            valid[i] = True
         comp = self._get_decode(B)
+        with _telemetry.span("decode.pack"):
+            ids = onp.zeros((B,), onp.int32)
+            pos = onp.zeros((B,), onp.int32)
+            tables = onp.zeros((B, P), onp.int32)
+            valid = onp.zeros((B,), bool)
+            for i, (tok, p, table) in enumerate(rows):
+                ids[i] = tok
+                pos[i] = p
+                tables[i] = table
+                valid[i] = True
         t0 = _now_us()
-        next_ids, k, v = comp(self._param_datas(), ids, pos, tables, valid,
-                              self.pool.k_pool, self.pool.v_pool)
-        out = onp.asarray(next_ids)            # sync point
+        with _telemetry.span("decode.launch", kind="step", bucket=B):
+            next_ids, k, v = comp(self._param_datas(), ids, pos, tables,
+                                  valid, self.pool.k_pool, self.pool.v_pool)
+        with _telemetry.span("decode.fetch", kind="step"):
+            out = onp.asarray(next_ids)        # sync point
         self.pool.update_arrays(k, v)
         dt = _now_us() - t0
         self._observe_cost(self.step_cost, "step", "decode_step",

@@ -43,6 +43,7 @@ from ...base import MXNetError
 from ...resilience import faults as _faults
 from ...resilience.faults import FaultInjected
 from ...telemetry import flight as _flight
+from .. import bucketing
 from .. import tailguard as _tailguard
 from ..errors import DeadlineExceeded, KVPoolExhausted, ServerClosedError
 from .streams import TokenStream
@@ -54,6 +55,9 @@ _RUNNING, _DRAINING, _STOPPED = "running", "draining", "stopped"
 # sequence states
 _S_WAITING, _S_RUNNING, _S_PAUSED = "waiting", "running", "paused"
 _S_DONE, _S_FAILED, _S_CANCELLED = "done", "failed", "cancelled"
+
+# what a pass of the decode loop tells the loop to do next
+_EXIT, _AGAIN, _REST = "exit", "again", "rest"
 
 
 def _now_us() -> int:
@@ -71,7 +75,7 @@ class _Tenant:
 class _Seq:
     __slots__ = ("sid", "tenant", "prompt", "max_new", "eos_id", "stream",
                  "state", "emitted", "pos", "prefilled", "enqueue_us",
-                 "last_token_us", "deadline")
+                 "admitted", "last_token_us", "deadline", "trace_id")
 
     def __init__(self, sid: int, tenant: _Tenant, prompt: Sequence[int],
                  max_new: int, eos_id: Optional[int], stream: TokenStream,
@@ -87,8 +91,13 @@ class _Seq:
         self.pos = len(self.prompt)      # tokens materialised in the KV cache
         self.prefilled = False
         self.enqueue_us = _now_us()
+        self.admitted = False            # a failover requeue admits again
         self.last_token_us = 0
         self.deadline = deadline         # propagated tailguard.Deadline
+        # the submitter's trace: its own span, this sequence's wait and its
+        # decode.prefill share one id across the queue hop
+        self.trace_id = (_telemetry.current_trace_id()
+                         or _telemetry.new_trace_id())
 
 
 class DecodeScheduler:
@@ -272,84 +281,136 @@ class DecodeScheduler:
     # the decode loop (worker thread)
     # ------------------------------------------------------------------
     def _loop(self, epoch: int):
-        while True:
-            with self._cond:
-                if self._epoch != epoch:
-                    return              # fenced-out zombie generation
-                if self._state == _STOPPED:
-                    return
-                if self._state == _DRAINING and not self._waiting \
-                        and not self._active:
-                    return
-                if self._state == _RUNNING and not self._waiting \
-                        and not self._active:
-                    self._cond.wait(0.05)
-                    continue
-                admits = self._admit_locked()
-            for seq in admits:
-                if seq.prefilled:
-                    continue            # requeued by failover: pages intact
-                try:
-                    tok = self.engine.prefill(
-                        seq.prompt, self.engine.pool.table(seq.sid))
-                except BaseException as e:
-                    with self._cond:
-                        self._fail_seq_locked(seq, e)
-                    if not isinstance(e, Exception):
-                        raise           # WorkerKilled et al: thread dies
-                    continue
-                seq.prefilled = True
+        verdict = _REST
+        while verdict != _EXIT:
+            if verdict == _REST:
+                # the last pass left nothing to run: the fence checks and
+                # the wait happen here, under no span
                 with self._cond:
                     if self._epoch != epoch:
+                        return          # fenced-out zombie generation
+                    if self._state == _STOPPED:
                         return
-                    self._emit_locked(seq, tok)
-            with self._cond:
-                if self._epoch != epoch:
-                    return
-                # the per-token deadline hop: a sequence whose end-to-end
-                # budget ran out mid-generation is retired BEFORE it costs
-                # another device step
-                for s in list(self._active):
-                    if s.state == _S_RUNNING and s.deadline is not None \
-                            and s.deadline.expired():
-                        _tailguard.deadline_expired("decode_token")
-                        self._fail_seq_locked(s, DeadlineExceeded(
-                            f"sequence {s.sid} overran its deadline after "
-                            f"{len(s.emitted)} of {s.max_new} tokens"))
-                rows = [s for s in self._active if s.state == _S_RUNNING]
-                if not rows:
-                    if not admits:
-                        self._cond.wait(0.005)   # all paused / pool-blocked
-                    continue
-                batch = [(s, s.emitted[-1], s.pos,
-                          self.engine.pool.table(s.sid)) for s in rows]
+                    if not self._runnable_locked():
+                        if self._active:
+                            self._cond.wait(0.005)      # all paused
+                        elif self._state == _DRAINING:
+                            return
+                        else:
+                            self._cond.wait(0.05)
+                        continue
+            verdict = self._iteration(epoch)
+
+    def _runnable_locked(self) -> bool:    # mxlint: disable=CONC200
+        return bool(self._waiting) or any(
+            s.state == _S_RUNNING for s in self._active)
+
+    def _iteration(self, epoch: int) -> str:
+        """One working pass under a ``decode.iteration`` span: admit,
+        prefill what was admitted, build the batch, step, emit. Tells the
+        loop what comes next: ``_EXIT`` once this generation is fenced out
+        or the scheduler stopped, ``_AGAIN`` while its last look under the
+        lock saw more to run (the next pass then starts with ``decode.admit``
+        taking the lock, so that waiting for it is inside a span), else
+        ``_REST``."""
+        with _telemetry.span("decode.iteration", admits=0, rows=0) as it:
+            with _telemetry.span("decode.admit") as sp:
+                with self._cond:
+                    if self._epoch != epoch or self._state == _STOPPED:
+                        return _EXIT
+                    sp.attrs["waiting"] = len(self._waiting)
+                    admits = self._admit_locked()
+                sp.attrs["admitted"] = it.attrs["admits"] = len(admits)
+            for seq in admits:
+                # requeued by failover: pages intact, nothing to prefill
+                if not seq.prefilled and not self._prefill(seq, epoch):
+                    return _EXIT
+            with _telemetry.span("decode.build") as sp:
+                with self._cond:
+                    if self._epoch != epoch:
+                        return _EXIT
+                    # the per-token deadline hop: a sequence whose
+                    # end-to-end budget ran out mid-generation is retired
+                    # BEFORE it costs another device step
+                    for s in list(self._active):
+                        if s.state == _S_RUNNING and s.deadline is not None \
+                                and s.deadline.expired():
+                            _tailguard.deadline_expired("decode_token")
+                            self._fail_seq_locked(s, DeadlineExceeded(
+                                f"sequence {s.sid} overran its deadline "
+                                f"after {len(s.emitted)} of {s.max_new} "
+                                "tokens"))
+                    rows = [s for s in self._active if s.state == _S_RUNNING]
+                    if not rows:
+                        if not admits:
+                            self._cond.wait(0.005)  # pool-blocked
+                        return _REST
+                    batch = [(s, s.emitted[-1], s.pos,
+                              self.engine.pool.table(s.sid)) for s in rows]
+                sp.attrs["rows"] = it.attrs["rows"] = len(batch)
             try:
                 _faults.check("decode")
-                toks = self.engine.decode_step(
-                    [(tok, pos, table) for _, tok, pos, table in batch])
+                with _telemetry.span(
+                        "decode.step", rows=len(batch),
+                        bucket=bucketing.bucket_for(
+                            len(batch), self.engine.decode_buckets)):
+                    toks = self.engine.decode_step(
+                        [(tok, pos, table) for _, tok, pos, table in batch])
             except FaultInjected as e:
                 _telemetry.event("decode_fault_absorbed", kind=e.kind,
                                  endpoint=self.engine.name)
-                continue                # transient: re-form and retry
+                return _REST            # transient: re-form and retry
             except Exception as e:
                 with self._cond:
                     for s, _, _, _ in batch:
                         self._fail_seq_locked(s, e)
-                continue
+                return _REST
             # one decode step = one unit of real work funding the decode
             # tier's retry budget (failover requeues spend from it)
             _tailguard.retry_deposit("decode")
-            with self._cond:
-                if self._epoch != epoch:
-                    return              # died-and-replaced mid-step: the
+            with _telemetry.span("decode.emit") as sp:
+                with self._cond:
+                    if self._epoch != epoch:
+                        return _EXIT    # died-and-replaced mid-step: the
                                         # new generation already owns these
                                         # sequences; emitting now would dup
-                for (s, _, _, _), tok in zip(batch, toks):
-                    if s.state not in (_S_RUNNING, _S_PAUSED):
-                        continue        # retired concurrently (cancel)
-                    s.pos += 1
-                    self._emit_locked(s, tok)
-                self._stats.set_queue_depth(len(self._waiting))
+                    live = [(s, tok) for (s, _, _, _), tok in zip(batch, toks)
+                            # the others were retired concurrently (cancel)
+                            if s.state in (_S_RUNNING, _S_PAUSED)]
+                    for s, tok in live:
+                        s.pos += 1
+                        self._emit_locked(s, tok)
+                    self._stats.set_queue_depth(len(self._waiting))
+                    more = self._runnable_locked()
+                sp.attrs["tokens"] = len(live)
+        return _AGAIN if more else _REST
+
+    def _prefill(self, seq: "_Seq", epoch: int) -> bool:
+        """Prefill one admitted sequence and emit its first token, under a
+        ``decode.prefill`` span of the request's own trace. False once this
+        generation is fenced out."""
+        n = len(seq.prompt)
+        with _telemetry.span(
+                "decode.prefill", trace_id=seq.trace_id, sid=seq.sid,
+                prompt_len=n,
+                bucket=bucketing.bucket_for(n, self.engine.prefill_buckets),
+                queue_wait_us=_now_us() - seq.enqueue_us):
+            try:
+                tok = self.engine.prefill(
+                    seq.prompt, self.engine.pool.table(seq.sid))
+            except BaseException as e:
+                with self._cond:
+                    self._fail_seq_locked(seq, e)
+                if not isinstance(e, Exception):
+                    raise               # WorkerKilled et al: thread dies
+                return True
+            seq.prefilled = True
+            with _telemetry.span("decode.emit", tokens=1):
+                with self._cond:
+                    if self._epoch != epoch:
+                        return False
+                    self._emit_locked(seq, tok)
+        return True
 
     def _admit_locked(self) -> List[_Seq]:    # mxlint: disable=CONC200
         """EDF admission: pull waiting sequences into free batch slots,
@@ -383,6 +444,9 @@ class DecodeScheduler:
             seq.state = _S_RUNNING
             self._active.append(seq)
             self._stats.seq_event("admitted")
+            if not seq.admitted:
+                seq.admitted = True
+                self._stats.record_queue_wait(now - seq.enqueue_us)
             admits.append(seq)
         self._stats.set_queue_depth(len(self._waiting))
         return admits
@@ -403,6 +467,8 @@ class DecodeScheduler:
         now = _now_us()
         seq.emitted.append(tok)
         self._stats.tokens(1)
+        if len(seq.emitted) == 1:
+            self._stats.record_ttft(now - seq.enqueue_us)
         if seq.last_token_us:
             self._stats.record_intertoken(seq.tenant.name,
                                           now - seq.last_token_us)

@@ -57,6 +57,17 @@ _STEP_LAT = _telemetry.histogram(
     "mxtpu_decode_step_us",
     "Batched decode-step executable latency (microseconds).",
     labelnames=("endpoint",))
+_QUEUE_WAIT = _telemetry.histogram(
+    "mxtpu_decode_queue_wait_us",
+    "Time a sequence waited from submit() until the scheduler admitted it "
+    "into the batch (microseconds); a failover requeue is not counted again.",
+    labelnames=("endpoint",))
+_TTFT = _telemetry.histogram(
+    "mxtpu_decode_ttft_us",
+    "Time from submit() until the scheduler emitted the sequence's first "
+    "token to its stream: queue wait + the prefills ahead + its own "
+    "prefill (microseconds).",
+    labelnames=("endpoint",))
 _BACKPRESSURE = _telemetry.counter(
     "mxtpu_decode_stream_backpressure_total",
     "Sequences paused because their client stream buffer filled; the "
@@ -86,6 +97,8 @@ class DecodeStats:
         self.prefill = LatencyHistogram()
         self.step = LatencyHistogram()
         self.intertoken = LatencyHistogram()
+        self.queue_wait = LatencyHistogram()
+        self.ttft = LatencyHistogram()
         self._m_tokens = _TOKENS.labels(name)
         self._m_steps = _STEPS.labels(name)
         self._m_seqs = {ev: _SEQS.labels(name, ev) for ev in _SEQ_EVENTS}
@@ -93,6 +106,8 @@ class DecodeStats:
         self._m_queue_depth = _QUEUE_DEPTH.labels(name)
         self._m_prefill = _PREFILL.labels(name)
         self._m_step = _STEP_LAT.labels(name)
+        self._m_queue_wait = _QUEUE_WAIT.labels(name)
+        self._m_ttft = _TTFT.labels(name)
         self._m_backpressure = _BACKPRESSURE.labels(name)
         self._m_intertoken: Dict[str, object] = {}
 
@@ -128,6 +143,16 @@ class DecodeStats:
                     tenant, _INTERTOKEN.labels(self.name, tenant))
         child.observe(dur_us)
 
+    def record_queue_wait(self, dur_us: float):
+        with self._lock:
+            self.queue_wait.record(dur_us)
+        self._m_queue_wait.observe(dur_us)
+
+    def record_ttft(self, dur_us: float):
+        with self._lock:
+            self.ttft.record(dur_us)
+        self._m_ttft.observe(dur_us)
+
     def record_compile(self):
         with self._lock:
             self.counters["compiles"] += 1
@@ -148,4 +173,6 @@ class DecodeStats:
                 "prefill": self.prefill.snapshot(),
                 "step": self.step.snapshot(),
                 "intertoken": self.intertoken.snapshot(),
+                "queue_wait": self.queue_wait.snapshot(),
+                "ttft": self.ttft.snapshot(),
             }

@@ -11,11 +11,16 @@
     around batch assembly and the compiled device step, so a request's
     trace id survives the queue hop.
   - on exit every span feeds BOTH sinks: the profiler's chrome-trace event
-    stream (when a profiler session is running — the span lands in the same
-    ``traceEvents`` timeline as per-op events, with the trace id in
-    ``args`` so XPlane/Perfetto rows correlate with fleet metrics), and the
-    registry's ``mxtpu_span_duration_us{name=...}`` histogram (always on —
-    spans are the latency series dashboards scrape).
+    stream (when an ``mxnet_tpu.profiler`` session is running — the span
+    lands in the same ``traceEvents`` timeline as per-op events, with the
+    trace id in ``args``), and the registry's
+    ``mxtpu_span_duration_us{name=...}`` histogram (always on — spans are
+    the latency series dashboards scrape).
+  - once ``jax`` is imported a span also holds a
+    ``jax.profiler.TraceAnnotation`` of the same name (``trace_id`` and
+    ``span_id`` as its stats) for its whole life: a no-op while no
+    ``jax.profiler`` trace runs, and under one the span lies in the
+    XPlane's host plane, on the clock of the device's ops.
 
 Span names are dot-scoped ``layer.operation`` (``serving.batch``,
 ``train.step``, ``dataloader.wait`` — see OBSERVABILITY.md for the
@@ -50,8 +55,8 @@ from .metrics import REGISTRY
 from .flight import RECORDER as _FLIGHT_RECORDER, _clean_attrs
 
 __all__ = ["Span", "span", "current_span", "current_trace_id",
-           "new_trace_id", "spool_flush", "spool_path", "read_spool",
-           "journey"]
+           "new_trace_id", "self_times", "spool_flush", "spool_path",
+           "read_spool", "journey"]
 
 # pre-bound deque.append: the flight span ring rides every span exit, so the
 # hot path pays one bounded-deque append (GIL-atomic) and nothing else
@@ -138,9 +143,12 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
     s = Span(name, trace_id, parent.span_id if parent is not None else None,
              attrs)
     token = _CURRENT.set(s)
+    annotation = _xplane_annotation(s)
     try:
         yield s
     finally:
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         _CURRENT.reset(token)
         s.dur_us = _now_us() - s.t0_us
         _SPAN_DURATION.labels(name).observe(s.dur_us)
@@ -151,6 +159,20 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
         _emit_profiler(s)
 
 
+def _xplane_annotation(s: Span):
+    """An entered ``jax.profiler.TraceAnnotation`` for ``s``, or None while
+    ``jax`` is not imported (telemetry never imports it: lightweight
+    processes stay off it). No profile running, it is an inactive TraceMe."""
+    # None too while ``import jax`` itself is still running
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(
+        s.name, trace_id=s.trace_id, span_id=s.span_id)
+    annotation.__enter__()
+    return annotation
+
+
 def current_span() -> Optional[Span]:
     return _CURRENT.get()
 
@@ -158,6 +180,31 @@ def current_span() -> Optional[Span]:
 def current_trace_id() -> Optional[str]:
     s = _CURRENT.get()
     return s.trace_id if s is not None else None
+
+
+def self_times(spans) -> Dict[str, int]:
+    """``{span_id: self_us}`` for finished spans given as
+    ``flight.recent_spans()`` entries (a bundle's ``spans`` list is the
+    same): each span's duration minus the part of its interval that its
+    child spans cover. A child whose parent is not among ``spans`` only
+    counts for itself."""
+    children: Dict[str, List] = {}
+    for e in spans:
+        if e["parent_id"] is not None:
+            children.setdefault(e["parent_id"], []).append(e)
+    out = {}
+    for e in spans:
+        start, end = e["t0_us"], e["t0_us"] + e["dur_us"]
+        covered, upto = 0, start
+        for c in sorted(children.get(e["span_id"], ()),
+                        key=lambda c: c["t0_us"]):
+            c0 = max(c["t0_us"], upto)
+            c1 = min(c["t0_us"] + c["dur_us"], end)
+            if c1 > c0:
+                covered += c1 - c0
+                upto = c1
+        out[e["span_id"]] = e["dur_us"] - covered
+    return out
 
 
 # -- per-pid span spool (the fleet plane's raw material) ----------------------
