@@ -237,7 +237,7 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
     (its bucket ladder is constrained to multiples of the shard size, like
     the dense twin); prefill (batch 1) and the paged KV pools replicate —
     replication across N chips is trivially bitwise, and the pool write
-    scatter then moves bytes only. Parameters replicate (a generative
+    then moves bytes only. Parameters replicate (a generative
     model's embedding/vocab tables are the likeliest leading-axis
     mismatches, so the dense twin's fsdp-style spreading is not defaulted
     here).

@@ -10,12 +10,12 @@ accounting covers decode traffic:
 
 - **prefill**, bucketed by sequence length (``seq_buckets`` ladder): one
   full causal forward of a single prompt (``TransformerLM.prefill_collect``
-  traced via ``pure_apply(..., method=...)``), scattering every layer's K/V
+  traced via ``pure_apply(..., method=...)``), writing every layer's K/V
   into the sequence's pages and returning the first generated token.
 - **decode-step**, bucketed by batch size (pow2 ladder): one token for every
   running sequence — gather each row's cached context through its page
   table, run ``TransformerLM.decode_step`` (single_query_attention inside),
-  scatter the new K/V row, greedy-argmax the next token on device.
+  write the new K/V row, greedy-argmax the next token on device.
 
 Bitwise contract: every model op is per-row and masked lanes carry exactly
 zero softmax weight, so a row's output depends only on its own tokens and
@@ -145,8 +145,11 @@ class DecodeEndpoint:
     def _donate_pools(self) -> bool:
         """Donate the KV pool arguments on backends with buffer donation:
         the pool is the largest recurring operand and every step consumes
-        the previous step's arrays, so donation makes the cache update
-        in-place on TPU/GPU. CPU warns on donation — keep it off there."""
+        the previous step's arrays. Donation lets the output share the
+        input's buffer; that the update is in place on TPU besides — no
+        pool-sized copy in the program — is the doing of kv_cache's
+        ``dynamic_update_slice`` writes. CPU warns on donation — keep it
+        off there."""
         return self._platform() in ("tpu", "gpu")
 
     def _platform(self) -> str:
@@ -193,10 +196,9 @@ class DecodeEndpoint:
                 logits = outs[0]                       # (1, S, V)
                 ks = jnp.stack(outs[1::2], 0)[:, 0]    # (layers, S, kv)
                 vs = jnp.stack(outs[2::2], 0)[:, 0]
-                k_pool = write_prefill(k_pool, ks, table[0], length[0],
-                                       page_size)
-                v_pool = write_prefill(v_pool, vs, table[0], length[0],
-                                       page_size)
+                k_pool, v_pool = write_prefill(
+                    (k_pool, v_pool), (ks, vs), table[0], length[0],
+                    page_size)
                 next_id = jnp.argmax(logits[0, length[0] - 1]) \
                     .astype(jnp.int32)
                 return next_id.reshape(1), k_pool, v_pool
@@ -227,10 +229,9 @@ class DecodeEndpoint:
                 logits = outs[0]                   # (B, V)
                 ks = jnp.stack(outs[1::2], 0)      # (layers, B, kv)
                 vs = jnp.stack(outs[2::2], 0)
-                k_pool = write_step(k_pool, ks, tables, positions, valid,
-                                    page_size)
-                v_pool = write_step(v_pool, vs, tables, positions, valid,
-                                    page_size)
+                k_pool, v_pool = write_step(
+                    (k_pool, v_pool), (ks, vs), tables, positions, valid,
+                    page_size)
                 next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return next_ids, k_pool, v_pool
 

@@ -12,7 +12,7 @@ of which sequence owns which page.
 
 Layout: ``(num_layers, num_pages, page_size, kv_dim)`` per pool (one for K,
 one for V). **Page 0 is reserved as a scratch page** and never allocated:
-scatter writes for padded/invalid positions are routed to it, and padded
+a step's writes for padded/invalid rows are routed to it, and padded
 page-table entries gather from it. Whatever garbage accumulates there is
 masked to an exactly-zero softmax weight before it can touch a real row
 (``_NEG_INF`` underflow — see ops/pallas/flash_attention.py
@@ -20,9 +20,14 @@ masked to an exactly-zero softmax weight before it can touch a real row
 bitwise decode oracle rests on.
 
 Host-side management (alloc/free/defrag, counters, the memstats holder) is
-in :class:`PagedKVPool`; the jit-side scatter/gather helpers
+in :class:`PagedKVPool`; the jit-side write/gather helpers
 (:func:`write_prefill`, :func:`write_step`, :func:`gather_ctx`) are pure
-functions traced into the compiled executables.
+functions traced into the compiled executables. The writes are
+``dynamic_update_slice`` under a loop, not an advanced-index scatter: the
+TPU compiler performs them in the pool's own layout, so with the pools
+donated a step or a prefill touches only the rows it writes. The scatter
+form costs four pool-sized relayout copies per executable
+(:func:`write_step`).
 """
 from __future__ import annotations
 
@@ -77,35 +82,83 @@ _DEFRAG_MOVED = _telemetry.counter(
 # prefill / decode-step executables
 # ---------------------------------------------------------------------------
 def write_prefill(pool, vals, table_row, length, page_size: int):
-    """Scatter one sequence's prefill projections into the pool.
+    """Write one sequence's prefill projections into its pages, in place.
 
     ``pool`` (num_layers, num_pages, page_size, kv_dim); ``vals``
     (num_layers, S, kv_dim) — per-position K (or V) for positions 0..S-1;
     ``table_row`` (P,) int32 physical page ids (0-padded); ``length`` scalar
-    int32 — positions >= length are padding and their writes are routed to
-    scratch page 0 (where duplicate slots may land in any order; nothing
-    ever reads page 0 unmasked)."""
+    int32 — positions >= length are padding and are not written: in the
+    sequence's last page they keep what the page held, and pages wholly
+    past ``length`` are skipped. ``pool`` and ``vals`` may be matching
+    tuples (K and V): one loop then carries both.
+
+    One ``(num_layers, 1, page_size, kv_dim)`` block per live page, read
+    with ``dynamic_slice`` and written back with ``dynamic_update_slice``
+    (see :func:`write_step` for why not a scatter). S need not be a
+    multiple of ``page_size``, nor reach it."""
+    import jax
     import jax.numpy as jnp
-    S = vals.shape[1]
-    pos = jnp.arange(S, dtype=jnp.int32)
-    page = table_row[pos // page_size]
-    page = jnp.where(pos < length, page, 0)
-    slot = pos % page_size
-    return pool.at[:, page, slot, :].set(vals)
+    from jax import lax
+    S = jax.tree.leaves(vals)[0].shape[1]
+    n_pages = -(-S // page_size)
+    pad = n_pages * page_size - S
+    if pad:
+        vals = jax.tree.map(
+            lambda v: jnp.pad(v, ((0, 0), (0, pad), (0, 0))), vals)
+    length = jnp.minimum(length, S)     # the loop stays inside the bucket
+    lane = jnp.arange(page_size, dtype=jnp.int32)[None, None, :, None]
+
+    def body(j, pools):
+        start = (0, table_row[j], 0, 0)
+        keep = j * page_size + lane < length
+
+        def put(p, v):
+            new = lax.dynamic_slice_in_dim(v, j * page_size, page_size, 1)
+            old = lax.dynamic_slice(
+                p, start, (p.shape[0], 1, page_size, p.shape[3]))
+            return lax.dynamic_update_slice(
+                p, jnp.where(keep, new[:, None], old), start)
+
+        return jax.tree.map(put, pools, vals)
+
+    return lax.fori_loop(0, -(-length // page_size), body, pool)
 
 
 def write_step(pool, vals, tables, positions, valid, page_size: int):
-    """Scatter one decode step's new K (or V) row per sequence.
+    """Write one decode step's new K (or V) row per sequence, in place.
 
     ``vals`` (num_layers, B, kv_dim); ``tables`` (B, P) int32;
     ``positions`` (B,) int32 — the lane each row's new token occupies;
-    ``valid`` (B,) bool — padding rows route to scratch page 0."""
+    ``valid`` (B,) bool — padding rows route to scratch page 0 (where
+    duplicate slots land in row order; nothing ever reads page 0
+    unmasked). ``pool`` and ``vals`` may be matching tuples (K and V): one
+    loop then carries both.
+
+    One ``dynamic_update_slice`` of a ``(num_layers, 1, 1, kv_dim)`` row per
+    sequence, under a loop the pool passes through in its own layout. The
+    advanced-index scatter ``pool.at[:, page, slot, :].set(vals)`` writes
+    the same rows, but the TPU compiler gives its scatter a layout with the
+    scattered dimensions major and relayouts the whole pool into it and
+    back — two pool-sized copies per pool in every executable, donated or
+    not (tests/test_tpu_compile.py holds the compiled program to none)."""
+    import jax
     import jax.numpy as jnp
+    from jax import lax
     B = tables.shape[0]
     page = tables[jnp.arange(B), positions // page_size]
     page = jnp.where(valid, page, 0)
     slot = positions % page_size
-    return pool.at[:, page, slot, :].set(vals)
+
+    def body(i, pools):
+        start = (0, page[i], slot[i], 0)
+
+        def put(p, v):
+            row = lax.dynamic_slice_in_dim(v, i, 1, 1)     # (layers, 1, kv)
+            return lax.dynamic_update_slice(p, row[:, :, None], start)
+
+        return jax.tree.map(put, pools, vals)
+
+    return lax.fori_loop(0, B, body, pool)
 
 
 def gather_ctx(pool, tables):

@@ -9,6 +9,8 @@ greedy decode through the same executables.
 """
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as onp
 import pytest
 
@@ -19,7 +21,8 @@ from mxnet_tpu.gluon.model_zoo.bert import TransformerLM
 from mxnet_tpu.resilience import faults
 from mxnet_tpu.serving import KVPoolExhausted, bucketing
 from mxnet_tpu.serving.generate import (DecodeEndpoint, DecodeScheduler,
-                                        PagedKVPool, TokenStream)
+                                        PagedKVPool, TokenStream,
+                                        write_prefill, write_step)
 
 
 def _lm(seed=0, **kw):
@@ -122,6 +125,95 @@ def test_defrag_is_bitwise_invisible(engine):
         pos += 1
     engine.pool.free(sid)
     assert toks == oracle
+
+
+# ---------------------------------------------------------------------------
+# the jit-side pool writes against their plain reference
+# ---------------------------------------------------------------------------
+def _scatter_prefill(pool, vals, table_row, length, page_size):
+    """The plain reference for write_prefill: the advanced-index scatter it
+    replaced, padding positions routed to scratch page 0."""
+    pos = jnp.arange(vals.shape[1], dtype=jnp.int32)
+    page = jnp.where(pos < length, table_row[pos // page_size], 0)
+    return pool.at[:, page, pos % page_size, :].set(vals)
+
+
+def _scatter_step(pool, vals, tables, positions, valid, page_size):
+    """The plain reference for write_step, invalid rows to scratch page 0."""
+    page = tables[jnp.arange(tables.shape[0]), positions // page_size]
+    page = jnp.where(valid, page, 0)
+    return pool.at[:, page, positions % page_size, :].set(vals)
+
+
+def _random_pools(rng, num_pages, page_size, layers=3, kv=8):
+    shape = (layers, num_pages, page_size, kv)
+    return (jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            jnp.asarray(rng.standard_normal(shape), jnp.float32))
+
+
+def _same_but_scratch(got, want):
+    onp.testing.assert_array_equal(onp.asarray(got)[:, 1:],
+                                   onp.asarray(want)[:, 1:])
+
+
+# the cell's buckets, a ladder ending off the page grid (S = 100), a prompt
+# that fills its bucket, one-position pages, a page larger than the bucket
+@pytest.mark.parametrize("S,page_size,length", [
+    (16, 16, 1), (16, 16, 16), (128, 16, 97), (100, 16, 100), (100, 16, 33),
+    (32, 1, 7), (16, 64, 5)])
+def test_write_prefill_equals_scatter_reference(S, page_size, length):
+    """Every real page bitwise what the scatter left there: positions past
+    ``length`` in the last page keep the page's old contents, pages past it
+    are untouched, whether the table holds reserved pages there or zeros."""
+    rng = onp.random.default_rng(S * 1000 + page_size * 10 + length)
+    P = -(-S // page_size)
+    pools = _random_pools(rng, P + 4, page_size)
+    vals = tuple(jnp.asarray(rng.standard_normal((3, S, 8)), jnp.float32)
+                 for _ in pools)
+    reserved = rng.permutation(onp.arange(1, P + 4))[:P].astype(onp.int32)
+    padded = reserved.copy()
+    padded[-(-length // page_size):] = 0
+    write = jax.jit(write_prefill, static_argnums=4)
+    for table in (reserved, padded):
+        want = [_scatter_prefill(p, v, table, length, page_size)
+                for p, v in zip(pools, vals)]
+        both = write(pools, vals, table, jnp.int32(length), page_size)
+        alone = write(pools[0], vals[0], table, jnp.int32(length), page_size)
+        _same_but_scratch(both[0], want[0])
+        _same_but_scratch(both[1], want[1])
+        _same_but_scratch(alone, want[0])
+
+
+# page_size 4, three pages a row: (positions, valid) per case
+STEP_CASES = {
+    "all_valid": ([0, 5, 10, 2, 7, 9], [1, 1, 1, 1, 1, 1]),
+    "invalid_rows": ([0, 5, 10, 2, 7, 9], [1, 0, 1, 0, 1, 1]),
+    "duplicate_scratch_writes": ([6, 6, 6, 1, 6, 6], [0, 0, 0, 1, 0, 0]),
+    "last_slot_of_a_page": ([3, 7, 11, 3, 7, 11], [1, 1, 1, 1, 0, 1]),
+    "warmup_all_invalid": ([0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("positions,valid", STEP_CASES.values(),
+                         ids=STEP_CASES.keys())
+def test_write_step_equals_scatter_reference(positions, valid):
+    page_size, B, P = 4, 6, 3
+    rng = onp.random.default_rng(sum(positions))
+    pools = _random_pools(rng, B * P + 3, page_size)
+    vals = tuple(jnp.asarray(rng.standard_normal((3, B, 8)), jnp.float32)
+                 for _ in pools)
+    tables = rng.permutation(onp.arange(1, B * P + 3))[:B * P] \
+        .reshape(B, P).astype(onp.int32)
+    positions = onp.asarray(positions, onp.int32)
+    valid = onp.asarray(valid, bool)
+    write = jax.jit(write_step, static_argnums=5)
+    want = [_scatter_step(p, v, tables, positions, valid, page_size)
+            for p, v in zip(pools, vals)]
+    both = write(pools, vals, tables, positions, valid, page_size)
+    alone = write(pools[1], vals[1], tables, positions, valid, page_size)
+    _same_but_scratch(both[0], want[0])
+    _same_but_scratch(both[1], want[1])
+    _same_but_scratch(alone, want[1])
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ every xdist worker collects the same tests), all such tests live in this one
 file (one worker gets it), and every compile happens in the test's own process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
                                                   single_query_attention)
 from mxnet_tpu.ops.pallas.fused_conv1x1 import conv1x1_bn_act
+from mxnet_tpu.serving.generate.kv_cache import (gather_ctx, write_prefill,
+                                                 write_step)
 
 
 @pytest.fixture(scope="module")
@@ -104,3 +107,70 @@ def test_single_query_attention_is_plain_xla(one_chip):
         sharding=one_chip)
     assert "tpu_custom_call" not in text
     assert "fusion(" in text and "exponential(" in text
+
+
+# cell gpt1.decode_chat's KV pools: 12 layers, 2049 pages of 16 positions,
+# 768 wide, float32 — 1.21 GB each, 32 pages a sequence
+POOL = (12, 2049, 16, 768)
+POOL_HLO = "f32[%d,%d,%d,%d]" % POOL
+PAGE, PAGES_PER_SEQ = 16, 32
+I32 = jnp.int32
+
+
+def _step_alone(B):
+    def fn(k_pool, v_pool, ks, vs, tables, positions, valid):
+        return write_step((k_pool, v_pool), (ks, vs), tables, positions,
+                          valid, PAGE)
+    return fn, [((12, B, 768), jnp.float32)] * 2 + [
+        ((B, PAGES_PER_SEQ), I32), ((B,), I32), ((B,), jnp.bool_)]
+
+
+def _prefill_alone(S):
+    def fn(k_pool, v_pool, ks, vs, table_row, length):
+        return write_prefill((k_pool, v_pool), (ks, vs), table_row, length,
+                             PAGE)
+    return fn, [((12, S, 768), jnp.float32)] * 2 + [
+        ((PAGES_PER_SEQ,), I32), ((), I32)]
+
+
+def _decode_shaped(B):
+    """The decode step's cache traffic without its model: gather both pools
+    through the tables, reduce over the gathered lanes, write the result."""
+    def fn(k_pool, v_pool, tables, positions, valid):
+        row = (gather_ctx(k_pool, tables) * gather_ctx(v_pool, tables)).sum(2)
+        return write_step((k_pool, v_pool), (row, -row), tables, positions,
+                          valid, PAGE)
+    return fn, [((B, PAGES_PER_SEQ), I32), ((B,), I32), ((B,), jnp.bool_)]
+
+
+# (program, the scratch it needs besides the write's): the decode-shaped
+# program holds both gathered contexts, (12, 64, 512, 768) float32 each
+POOL_WRITES = [
+    pytest.param(_step_alone(64), 0, id="write_step_b64"),
+    pytest.param(_step_alone(8), 0, id="write_step_b8"),
+    pytest.param(_prefill_alone(16), 0, id="write_prefill_s16"),
+    pytest.param(_prefill_alone(128), 0, id="write_prefill_s128"),
+    pytest.param(_prefill_alone(512), 0, id="write_prefill_s512"),
+    pytest.param(_decode_shaped(64), 2 * 12 * 64 * 512 * 768 * 4,
+                 id="decode_shaped_b64")]
+
+
+@pytest.mark.parametrize("program,other_temp", POOL_WRITES)
+def test_pool_write_is_in_place(one_chip, program, other_temp):
+    """With the pools donated, the compiled program makes no pool-sized
+    copy and the write takes under 64 MB of scratch (the advanced-index
+    scatter compiled to a relayout copy before and after it, per pool, and
+    1.6 GB). The alias alone, which hlolint's IR1000 checks in the lowered
+    StableHLO, does not show that: the copies come from the TPU compiler's
+    layout assignment, after lowering."""
+    fn, rest = program
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [(POOL, jnp.float32)] * 2 + rest]
+    comp = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    text = comp.as_text()
+    assert "input_output_alias" in text.splitlines()[0]
+    makers = set(re.findall(
+        r"= " + re.escape(POOL_HLO) + r"\{[^}]*\} ([\w-]+)\(", text))
+    assert makers and "copy" not in makers, makers
+    temp = comp.memory_analysis().temp_size_in_bytes
+    assert temp < other_temp + (64 << 20), temp
