@@ -11,6 +11,13 @@ Times are the client's: a token's time is when the stream handed it to the
 request's ``on_token`` callback. Tokens count by that time, time to first
 token by requests submitted inside the window, and what is in flight when the
 window ends is drained outside it.
+
+A cell's optional ``generate`` group goes to ``server.generate`` as keyword
+arguments beside ``max_new_tokens`` and ``on_token``. The answers' check is
+the family's ``check_requests(bench, lm, good, vocab)`` where it has one (a
+generation step that is not one causal token brings its own; each finished
+request still holds its ``stream``, with whatever the system recorded of its
+steps), else ``_check_requests`` below.
 """
 import threading
 import time
@@ -24,6 +31,7 @@ from ..stats import median, percentile
 # second completed are the end-to-end metric; the tails swing by 5-16% from
 # run to run there (PERF.md, PR 23) and are per-layer metrics
 END_TO_END = {"decode_tokens_per_s": "tokens/s"}
+KIND = "decode"              # which per-layer readers apply (their ``KINDS``)
 NAME = "chipbench_lm"
 
 
@@ -47,12 +55,14 @@ def make_requests(cell, vocab, seed):
 
 
 class _Request:
-    __slots__ = ("prompt", "budget", "submitted", "stamps", "tokens", "error")
+    __slots__ = ("prompt", "budget", "submitted", "stamps", "stream",
+                 "tokens", "error")
 
     def __init__(self, prompt, budget):
         self.prompt, self.budget = prompt, budget
         self.submitted = None
         self.stamps = []
+        self.stream = None
         self.tokens = None
         self.error = None
 
@@ -61,9 +71,10 @@ class _Clients:
     """The callers. Each takes the next request of the shared sequence, sends
     it and waits for its whole answer."""
 
-    def __init__(self, server, requests, n, timeout):
+    def __init__(self, server, requests, n, timeout, generate=None):
         self._server, self._requests = server, requests
         self._timeout = timeout
+        self._generate = dict(generate or {})
         self._lock = threading.Lock()
         self._next = 0
         self.done = []
@@ -95,15 +106,60 @@ class _Clients:
             req.submitted = clock()
             try:
                 with span("submit"):
-                    stream = self._server.generate(
+                    req.stream = self._server.generate(
                         NAME, req.prompt, max_new_tokens=req.budget,
-                        on_token=lambda tok: stamps.append(clock()))
-                req.tokens = stream.result(timeout=self._timeout)
+                        on_token=lambda tok: stamps.append(clock()),
+                        **self._generate)
+                req.tokens = req.stream.result(timeout=self._timeout)
             except Exception as e:       # counted as failed, never dropped
                 req.error = e
             with self._lock:
                 self.done.append(req)
                 self.turned_over.add(ci)
+
+
+CHECK_ROWS = 4               # rows per reference forward: (4, 512, V) logits
+
+
+def sampled_rows(bench, done):
+    """(picks, tokens): the indices into ``done`` of a sample drawn from the
+    seed, with the longest finished sequence in it, and their prompt + answer
+    ids as rows right-padded to ``max_seq_len`` (causal, so padding reaches
+    no checked position)."""
+    cell = bench.cell
+    rng = onp.random.default_rng(seed32(bench.seed, 2))
+    picks = rng.choice(len(done), min(cell["checked_requests"], len(done)),
+                       replace=False)
+    longest = max(range(len(done)),
+                  key=lambda i: len(done[i].prompt) + len(done[i].tokens))
+    if longest not in picks:
+        picks[0] = longest
+    toks = onp.zeros((len(picks), cell["max_seq_len"]), onp.int32)
+    for row, i in enumerate(picks):
+        seq = done[i].prompt + done[i].tokens
+        toks[row, :len(seq)] = seq
+    return picks, toks
+
+
+def logits_in_blocks(logits_of, toks):
+    """``logits_of(rows)`` over ``toks`` in blocks of CHECK_ROWS rows (the
+    last one padded, so every call has one shape), each fetched to the host:
+    a block of logits at a time is all the device holds."""
+    for at in range(0, len(toks), CHECK_ROWS):
+        block = toks[at:at + CHECK_ROWS]
+        pad = onp.zeros((CHECK_ROWS - len(block), toks.shape[1]), toks.dtype)
+        yield at, onp.asarray(logits_of(onp.concatenate([block, pad])))
+
+
+def served_logits(done, picks, toks, logits_of):
+    """(request, logits (T, V)) for each sampled request with an answer: the
+    rows of ``logits_of`` that chose its T served tokens, teacher-forced."""
+    for at, logits in logits_in_blocks(logits_of, toks):
+        for row, i in enumerate(picks[at:at + CHECK_ROWS]):
+            r = done[i]
+            if r.tokens:
+                first = len(r.prompt) - 1
+                yield r, logits[row, first:first + len(r.tokens)]
 
 
 def _check_requests(bench, lm, family, done, vocab):
@@ -115,21 +171,13 @@ def _check_requests(bench, lm, family, done, vocab):
     cell = bench.cell
     ok_ids = all(0 <= t < vocab for r in done for t in r.tokens)
     ok_len = all(len(r.tokens) <= r.budget for r in done)
-    rng = onp.random.default_rng(seed32(bench.seed, 2))
-    picks = rng.choice(len(done), min(cell["checked_requests"], len(done)),
-                       replace=False)
-    width = cell["max_seq_len"]
-    toks = onp.zeros((len(picks), width), onp.int32)   # right-padded: causal
-    for row, i in enumerate(picks):
-        seq = done[i].prompt + done[i].tokens
-        toks[row, :len(seq)] = seq
-    logits = onp.asarray(family.reference_logits(lm, bench.config, toks))
+    picks, toks = sampled_rows(bench, done)
     worst = 0.0
-    for row, i in enumerate(picks):
-        r = done[i]
-        for j, tok in enumerate(r.tokens):
-            at = logits[row, len(r.prompt) + j - 1]
-            worst = max(worst, float(at.max() - at[tok]))
+    for r, here in served_logits(
+            done, picks, toks,
+            lambda rows: family.reference_logits(lm, bench.config, rows)):
+        served = here[onp.arange(len(r.tokens)), r.tokens]
+        worst = max(worst, float((here.max(-1) - served).max()))
     seen = {"ids_in_range": ok_ids, "within_budget": ok_len,
             "checked_requests": len(picks),
             "checked_tokens": int(sum(len(done[i].tokens) for i in picks)),
@@ -163,7 +211,7 @@ def run(bench):
                "output_len": _dist([b for _, b in requests])})
 
     clients = _Clients(server, requests, cell["clients"],
-                       cell["request_timeout_s"])
+                       cell["request_timeout_s"], cell.get("generate"))
     clients.start()
     # warm-up traffic: until every caller's slot has turned over once, or
     # the cell's limit, whichever comes first
@@ -186,6 +234,10 @@ def run(bench):
     clients.stop_and_drain()
     server.stop(drain=True)
     compiles += eng.stats.snapshot()["counters"]["compiles"] - warm_compiles
+    # read before the check's reference runs on the chip: a process's peak
+    # never falls again. (The pool stays: the kept streams hold the scheduler;
+    # a block of the reference's logits is 0.33 GB beside it.)
+    bench.memory_peak_bytes()
 
     done = clients.done
     failed = [r for r in done if r.error is not None
@@ -201,8 +253,18 @@ def run(bench):
                 if j:
                     later_tokens += 1
                     gaps.append(1e3 * (t - r.stamps[j - 1]))
-    ok, seen = _check_requests(bench, lm, family, good, vocab) if good \
-        else (False, {})
+    if not good:
+        ok, seen = False, {}
+    elif hasattr(family, "check_requests"):
+        ok, seen = family.check_requests(bench, lm, good, vocab)
+    else:
+        ok, seen = _check_requests(bench, lm, family, good, vocab)
+    # a family's check names what it compared under ``compared``
+    compared = seen.pop("compared", None) or {"worst_logit_deficit": {
+        "value": seen.get("worst_logit_deficit"),
+        "limit": seen.get("logit_tolerance")}}
+    compared["compiles_after_warmup"] = {"value": compiles, "limit": 0}
+    compared["failed_requests"] = {"value": len(failed), "limit": 0}
     checks = {"answers": ok, "no_compile_after_warmup": compiles == 0,
               "none_failed": not failed}
     bench.say({"check": checks, **seen, "compiles_after_warmup": compiles,
@@ -215,6 +277,7 @@ def run(bench):
     return {"correct": all(checks.values()),
             "attempted": len(done), "failed": len(failed),
             "end_to_end": {"decode_tokens_per_s": tokens / (t1 - t0)},
+            "compared": compared,
             # for the per-layer readers
             "ttft_p95_ms": percentile(ttft, 95),
             "tpot_p95_ms": percentile(gaps, 95),

@@ -15,6 +15,7 @@ from ..harness import seed32, span
 from ..stats import median
 
 END_TO_END = {"train_samples_per_s": "samples/s"}
+KIND = "train"               # which per-layer readers apply (their ``KINDS``)
 
 
 def _dispatch(step, batches):
@@ -110,6 +111,12 @@ def run(bench):
             "attempted": dispatches * k,
             "failed": sum(int((~onp.isfinite(x)).sum()) for x in losses),
             "end_to_end": {"train_samples_per_s": rate},
+            "compared": {
+                "first_loss_rel_diff": {"value": rel,
+                                        "limit": cell["first_loss_rtol"]},
+                "last_dispatch_mean_loss": {"value": float(losses[-1].mean()),
+                                            "limit": float(first.mean())},
+                "compiles_in_window": {"value": compiles, "limit": 0}},
             # for the per-layer readers
             "dispatch_s": spans, "steps_per_dispatch": k,
             "samples_per_s": rate, "chips": bench.chips,

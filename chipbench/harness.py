@@ -7,6 +7,7 @@ import importlib.util
 import json
 import os
 import shutil
+import sys
 import threading
 import time
 
@@ -99,6 +100,7 @@ class Bench:
         self.device_row = None
         self.trace_data = None
         self.setup_s = None
+        self._memory_peak = None
 
     # -- set-up accounting ------------------------------------------------
     def phase_done(self, name):
@@ -189,11 +191,16 @@ class Bench:
         reserves for its scratch (``peak_bytes_reserved``); a step holds both
         at once, so the peak is their sum (for bert_base.pretrain_s128 1.45 +
         11.83 GB against 13.11 GB from the step's ``memory_analysis()``). 0
-        where the backend keeps no count (the CPU)."""
-        stats = [d.memory_stats() or {} for d in self.devices]
-        self.say({"memory_stats": stats[0]})
-        return max(int(s.get("peak_bytes_in_use", 0))
-                   + int(s.get("peak_bytes_reserved", 0)) for s in stats)
+        where the backend keeps no count (the CPU). A process's peak never
+        falls, so the first reading stands: a driver whose check runs a
+        reference on the chip reads the peak before it does."""
+        if self._memory_peak is None:
+            stats = [d.memory_stats() or {} for d in self.devices]
+            self.say({"memory_stats": stats[0]})
+            self._memory_peak = max(
+                int(s.get("peak_bytes_in_use", 0))
+                + int(s.get("peak_bytes_reserved", 0)) for s in stats)
+        return self._memory_peak
 
 
 def span(name):
@@ -222,12 +229,13 @@ def layer_metric_modules():
         yield module
 
 
-def read_layer_metrics(run, driver):
-    """``{name: {"value", "unit"}}`` from every reader that applies to this
-    driver and finds something to read."""
+def read_layer_metrics(run, kind):
+    """``{name: {"value", "unit"}}`` from every reader whose ``KINDS`` hold
+    this kind of run (a driver's ``KIND``) and that finds something to read
+    in it."""
     out = {}
     for module in layer_metric_modules():
-        if driver not in module.DRIVERS:
+        if kind not in module.KINDS:
             continue
         value = module.read(run)
         if value is not None:
@@ -266,8 +274,8 @@ def main(argv=None):
     bench.say({"seed": bench.seed, "seconds": bench.seconds,
                "trace": int(bench.trace), "rehearse": bench.rehearse,
                "compile_cache": bench.cache_dir})
-    driver_name = bench.cell["driver"]
-    driver = importlib.import_module(f"chipbench.drivers.{driver_name}")
+    driver = importlib.import_module(
+        f"chipbench.drivers.{bench.cell['driver']}")
     run = driver.run(bench)
     trace = bench.trace_data if bench.trace_data and \
         bench.trace_data["devices"] else None       # none on the CPU
@@ -281,7 +289,7 @@ def main(argv=None):
               "count": bench.device_row["device_count"],
               "memory_peak_bytes": bench.memory_peak_bytes()}
     if bench.trace:
-        metrics = read_layer_metrics(run, driver_name)
+        metrics = read_layer_metrics(run, driver.KIND)
     else:
         metrics = {name: {"value": float(run["end_to_end"][name]),
                           "unit": unit}
@@ -297,7 +305,16 @@ def main(argv=None):
     if bench.rehearse:
         metrics = {REHEARSAL_PREFIX + k: v for k, v in metrics.items()}
     result.update(metrics=metrics, device=device)
+    # every number ``correct`` was decided by, beside its limit: the last
+    # lines of standard error, and the result line's last key
+    compared = run.get("compared")
+    if compared:
+        result["compared"] = compared
+        for name, row in compared.items():
+            print(f"compared {name} = {row['value']!r} limit {row['limit']!r}",
+                  file=sys.stderr, flush=True)
     # key order as the contract shows it
-    order = ("correct", "attempted", "failed", "metrics", "device", "breakdown")
+    order = ("correct", "attempted", "failed", "metrics", "device",
+             "breakdown", "compared")
     print(json.dumps({k: result[k] for k in order if k in result}), flush=True)
     return 0

@@ -13,7 +13,12 @@ loads it keeps neither that start nor the program's own annotations, so the
 offset between the two clocks is taken from events that both sides hold: the
 loop's thread launches every XLA module the chip runs, one at a time and in
 order, so the modules of the trace are a run of consecutive ``decode.launch``
-spans. The run is found by its rhythm. Then each launch must start before
+spans. The run is found by its rhythm: the lags from launch to module start
+spread least over the true run. One launch that the profiler's start stalls
+(the window's first: 51 ms against 9, PR 26) made the true run read 51.7 ms
+and the run shifted by one call 51.6, so the spread leaves out the largest
+lag, and of the few runs that spread least the first that leaves an interval
+is taken. Then each launch must start before
 its module does and each ``decode.fetch`` must end after its module has: the
 offsets that allow both are an interval, and the join is its middle. On the
 chip, under the profiler, a module starts 4 to 11 ms after its launch began
@@ -37,6 +42,10 @@ MIN_SPANS = 10               # fewer profiler-off samples than this: None
 MAX_LAG_NS = 20e6            # launch start -> module start: the median, and
                              # the width the offset is known to; twice what
                              # the chip showed under the profiler
+STALLED = 1                  # launches left out of a run's spread of lags:
+                             # ``start_trace`` stalls the window's first
+BEST_RUNS = 3                # runs tried, in order of spread, for one that
+                             # leaves an interval
 
 
 def ring(prefix):
@@ -75,13 +84,21 @@ def clock_join(run, spans):
     if n < 3 or len(calls) < n:
         return None
     # the run of n consecutive launches whose starts keep the modules' rhythm
-    best, at = None, None
+    spreads = []
     for k in range(len(calls) - n + 1):
-        diffs = [m[0] - c[0] for m, c in zip(modules, calls[k:k + n])]
-        spread = max(diffs) - min(diffs)
-        if best is None or spread < best:
-            best, at = spread, k
-    calls = calls[at:at + n]
+        lags = sorted(m[0] - c[0] for m, c in zip(modules, calls[k:k + n]))
+        spreads.append((lags[-1 - STALLED] - lags[0], k))
+    for _, k in sorted(spreads)[:BEST_RUNS]:
+        join = _interval(modules, calls[k:k + n])
+        if join is not None:
+            return join
+    return None
+
+
+def _interval(modules, calls):
+    """The join of ``modules`` onto as many ``calls``, or None where no
+    offset lets every launch start before its module and every fetch end
+    after it, or it is known too loosely, or the launches lag too far."""
     hi = min(m[0] - c[0] for m, c in zip(modules, calls))   # launch first
     lo = max(m[1] - c[1] for m, c in zip(modules, calls))   # fetch last
     if not 0 <= hi - lo <= MAX_LAG_NS:
@@ -92,7 +109,7 @@ def clock_join(run, spans):
     if lag > MAX_LAG_NS:
         return None
     return {"offset_ns": offset, "width_ns": hi - lo, "median_lag_ns": lag,
-            "modules": n}
+            "modules": len(modules)}
 
 
 def measured(run, spans):

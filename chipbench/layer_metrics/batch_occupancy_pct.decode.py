@@ -6,7 +6,7 @@ NAME = "batch_occupancy_pct.decode"
 UNIT = "%"
 LAYER = "serving host"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

@@ -8,7 +8,7 @@ NAME = "collective_pct.train"
 UNIT = "%"
 LAYER = "mesh and collectives"
 MOVES = "train_samples_per_s"
-DRIVERS = ("train_step",)
+KINDS = ("train",)
 
 
 def read(run):

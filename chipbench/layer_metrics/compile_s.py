@@ -4,7 +4,7 @@ NAME = "compile_s"
 UNIT = "s"
 LAYER = "compile and caches"
 MOVES = "setup_s"
-DRIVERS = ("train_step", "decode_closed")
+KINDS = ("train", "decode")
 
 
 def read(run):

@@ -6,7 +6,7 @@ NAME = "decode_step_ms.decode"
 UNIT = "ms"
 LAYER = "endpoints"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

@@ -6,7 +6,7 @@ NAME = "device_idle_pct.decode"
 UNIT = "%"
 LAYER = "device"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

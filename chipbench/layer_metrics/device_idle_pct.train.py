@@ -6,7 +6,7 @@ NAME = "device_idle_pct.train"
 UNIT = "%"
 LAYER = "device"
 MOVES = "train_samples_per_s"
-DRIVERS = ("train_step",)
+KINDS = ("train",)
 
 
 def read(run):

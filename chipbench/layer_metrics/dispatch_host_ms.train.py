@@ -13,7 +13,7 @@ NAME = "dispatch_host_ms.train"
 UNIT = "ms"
 LAYER = "train step"
 MOVES = "train_samples_per_s"
-DRIVERS = ("train_step",)
+KINDS = ("train",)
 
 
 # a 20 s window of the four-chip cell holds ten dispatches

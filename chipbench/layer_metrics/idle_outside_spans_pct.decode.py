@@ -8,7 +8,7 @@ NAME = "idle_outside_spans_pct.decode"
 UNIT = "%"
 LAYER = "serving host"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

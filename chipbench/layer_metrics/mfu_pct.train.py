@@ -9,7 +9,7 @@ NAME = "mfu_pct.train"
 UNIT = "%"
 LAYER = "model code"
 MOVES = "train_samples_per_s"
-DRIVERS = ("train_step",)
+KINDS = ("train",)
 
 _PEAKS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "peaks.json")

@@ -6,7 +6,7 @@ NAME = "prefill_ms.decode"
 UNIT = "ms"
 LAYER = "endpoints"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

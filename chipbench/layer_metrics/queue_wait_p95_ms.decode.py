@@ -8,7 +8,7 @@ NAME = "queue_wait_p95_ms.decode"
 UNIT = "ms"
 LAYER = "serving host"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

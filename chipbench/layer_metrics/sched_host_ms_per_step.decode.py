@@ -10,7 +10,7 @@ NAME = "sched_host_ms_per_step.decode"
 UNIT = "ms"
 LAYER = "serving host"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

@@ -6,7 +6,7 @@ NAME = "step_ms.train"
 UNIT = "ms"
 LAYER = "train step"
 MOVES = "train_samples_per_s"
-DRIVERS = ("train_step",)
+KINDS = ("train",)
 
 
 def read(run):

@@ -6,7 +6,7 @@ NAME = "ttft_p95_ms"
 UNIT = "ms"
 LAYER = "serving host"
 MOVES = "decode_tokens_per_s"
-DRIVERS = ("decode_closed",)
+KINDS = ("decode",)
 
 
 def read(run):

@@ -71,8 +71,14 @@ def test_per_layer_metrics_have_a_reader_that_agrees(bench):
             (reader.UNIT, reader.LAYER, reader.MOVES)
         # the metric it moves is reported in every cell where it is
         assert _cells_of(m, bench) <= _cells_of(e2e[m["moves"]], bench)
-        for cell in _cells_of(m, bench):
-            assert harness.load_cell(cell)[0]["driver"] in reader.DRIVERS
+        for cell in _cells_of(m, bench):     # the cell's kind of run is read
+            driver = importlib.import_module(
+                "chipbench.drivers." + harness.load_cell(cell)[0]["driver"])
+            assert driver.KIND in reader.KINDS
+    # what lists gpt1.decode_chat lists gpt1.decode_long: one model, two mixes
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        listed = m.get("workloads", [])
+        assert ("gpt1.decode_chat" in listed) == ("gpt1.decode_long" in listed)
     for cell in bench["workloads"]:      # each cell has a per-layer metric
         assert any(cell["name"] in _cells_of(m, bench)
                    for m in bench["per_layer"])

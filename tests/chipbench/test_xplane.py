@@ -155,9 +155,12 @@ def test_recorded_trace_busy_fits_its_window(trace):
     assert not any(label.startswith("while") for label, _ in top)
 
 
-def test_readers_and_breakdown_on_the_recorded_trace(trace):
+def test_readers_and_breakdown_on_the_recorded_trace(trace, monkeypatch):
     """What a traced run on the chip does after its window, on the fixture."""
     from chipbench import harness
+    from mxnet_tpu.telemetry import flight
+    # the ring is the process's: an earlier test's train.step_n spans lie there
+    monkeypatch.setattr(flight, "recent_spans", lambda: [])
     info = xplane.summary(trace)
     assert info["window_s"] == pytest.approx(3.506163991)
     run = {"trace": trace, "trace_summary": info, "chips": 1,
@@ -165,7 +168,7 @@ def test_readers_and_breakdown_on_the_recorded_trace(trace):
            "samples_per_s": 1468.0, "flops_per_sample": 69.8e9,
            "device_kind": "TPU v5 lite",
            "setup_compile": {"trace_s": 6.0, "lower_s": 6.5, "backend_s": 3.5}}
-    got = harness.read_layer_metrics(run, "train_step")
+    got = harness.read_layer_metrics(run, "train")
     assert set(got) == {"compile_s", "step_ms.train", "mfu_pct.train",
                         "device_idle_pct.train"}       # one chip: no collective
     assert got["step_ms.train"] == {"value": 175.0, "unit": "ms"}
@@ -179,13 +182,16 @@ def test_readers_and_breakdown_on_the_recorded_trace(trace):
         {"dispatch", "fetch", "between_ops", "unattributed"}
     run["device_kind"] = "TPU v9"
     with pytest.raises(KeyError):
-        harness.read_layer_metrics(run, "train_step")   # no peak, no default
+        harness.read_layer_metrics(run, "train")   # no peak, no default
 
 
-def test_decode_readers_find_both_programs_in_a_recorded_decode_trace():
+def test_decode_readers_find_both_programs_in_a_recorded_decode_trace(
+        monkeypatch):
     """fixtures/trace_gpt1_decode_v5e.json: the first ops, every module and
     span of a traced gpt1.decode_chat window."""
     from chipbench import harness
+    from mxnet_tpu.telemetry import flight
+    monkeypatch.setattr(flight, "recent_spans", lambda: [])
     with open(os.path.join(os.path.dirname(FIXTURE),
                            "trace_gpt1_decode_v5e.json")) as f:
         trace = json.load(f)
@@ -196,7 +202,7 @@ def test_decode_readers_find_both_programs_in_a_recorded_decode_trace():
            "decode_steps": 250, "max_batch_size": 64, "ttft_p95_ms": 165.0,
            "tpot_p95_ms": 118.0,
            "setup_compile": {"trace_s": 7.0, "lower_s": 2.0, "backend_s": 5.0}}
-    got = harness.read_layer_metrics(run, "decode_closed")
+    got = harness.read_layer_metrics(run, "decode")
     assert set(got) == {
         "compile_s", "decode_step_ms.decode", "prefill_ms.decode",
         "batch_occupancy_pct.decode", "device_idle_pct.decode",
