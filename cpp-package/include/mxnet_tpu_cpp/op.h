@@ -2745,6 +2745,28 @@ inline Symbol bipartite_matching(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
+// block_attention: positional inputs (q, k, v, positions, k_ctx, v_ctx)
+inline Symbol block_attention(
+    const std::string& name,
+    Symbol q,
+    Symbol k,
+    Symbol v,
+    Symbol positions,
+    Symbol k_ctx = Symbol(),
+    Symbol v_ctx = Symbol(),
+    const std::string& heads = "null" /* raw JSON */,
+    const std::string& kv_heads = "null" /* raw JSON */,
+    int64_t block_length = 1,
+    const std::string& extra_attrs_json = "") {
+  std::string j;
+  detail::Attr(&j, "heads", detail::Raw{heads});
+  detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
+  detail::Attr(&j, "block_length", static_cast<int64_t>(block_length));
+  std::vector<Symbol> ins{q, k, v, positions, k_ctx, v_ctx};
+  return Symbol::Create("block_attention", name, detail::Inputs(std::move(ins)),
+                        detail::Finish(std::move(j), extra_attrs_json));
+}
+
 // boolean_mask_dense: positional inputs (data, mask)
 inline Symbol boolean_mask_dense(
     const std::string& name,
@@ -4350,6 +4372,27 @@ inline Symbol mish(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
+// moe_ffn: positional inputs (x, router, w_gate, w_up, w_down)
+inline Symbol moe_ffn(
+    const std::string& name,
+    Symbol x,
+    Symbol router,
+    Symbol w_gate,
+    Symbol w_up,
+    Symbol w_down,
+    const std::string& top_k = "null" /* raw JSON */,
+    bool norm_topk = true,
+    int64_t first_expert = 0,
+    const std::string& extra_attrs_json = "") {
+  std::string j;
+  detail::Attr(&j, "top_k", detail::Raw{top_k});
+  detail::Attr(&j, "norm_topk", norm_topk);
+  detail::Attr(&j, "first_expert", static_cast<int64_t>(first_expert));
+  std::vector<Symbol> ins{x, router, w_gate, w_up, w_down};
+  return Symbol::Create("moe_ffn", name, detail::Inputs(std::move(ins)),
+                        detail::Finish(std::move(j), extra_attrs_json));
+}
+
 // moments: positional inputs (x)
 inline Symbol moments(
     const std::string& name,
@@ -5257,6 +5300,20 @@ inline Symbol rmspropalex_update(
   detail::Attr(&j, "clip_weights", clip_weights);
   std::vector<Symbol> ins{weight, grad, n, g_avg, delta};
   return Symbol::Create("rmspropalex_update", name, detail::Inputs(std::move(ins)),
+                        detail::Finish(std::move(j), extra_attrs_json));
+}
+
+// rotary_embedding: positional inputs (x, positions)
+inline Symbol rotary_embedding(
+    const std::string& name,
+    Symbol x,
+    Symbol positions,
+    double theta = 10000.0,
+    const std::string& extra_attrs_json = "") {
+  std::string j;
+  detail::Attr(&j, "theta", theta);
+  std::vector<Symbol> ins{x, positions};
+  return Symbol::Create("rotary_embedding", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 

@@ -24,6 +24,8 @@ TARGET_DTYPE_OPS = [
     "_contrib_interleaved_matmul_encdec_valatt", "multi_head_attention",
     "flash_attention", "single_query_attention", "Embedding",
     "_contrib_SparseEmbedding",
+    # their softmax, router and accumulation are float32 inside
+    "block_attention", "moe_ffn",
 ]
 
 # numerically sensitive ops pinned to fp32
@@ -54,6 +56,7 @@ CONDITIONAL_FP32_OPS = [
 
 # ops that take the widest dtype among inputs (safe in any float dtype)
 WIDEST_TYPE_CASTS = [
+    "rotary_embedding",     # rotates in float32, returns its input's dtype
     "broadcast_add", "broadcast_sub", "broadcast_mul", "broadcast_div",
     "broadcast_mod", "broadcast_power", "broadcast_maximum",
     "broadcast_minimum", "broadcast_hypot", "hypot", "elemwise_add", "elemwise_sub",

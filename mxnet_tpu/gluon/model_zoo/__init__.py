@@ -6,3 +6,4 @@ from .vision import get_model
 from .bert import (BERTModel, BERTForPretraining, bert_base, bert_large,
                    shard_for_tensor_parallel)
 from .dlrm import DLRM, dlrm_tiny
+from .moe_lm import MoEDecoderLM

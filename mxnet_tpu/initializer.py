@@ -2,6 +2,7 @@
 Uniform, Normal, Orthogonal, Constant, One, Zero, Bilinear, LSTMBias + registry)."""
 from __future__ import annotations
 
+import functools
 import json
 import math
 from typing import Optional
@@ -10,7 +11,7 @@ import numpy as onp
 
 from .base import Registry, MXNetError
 
-__all__ = ["Initializer", "Uniform", "Normal", "Orthogonal", "Xavier", "MSRAPrelu", "FusedRNN",
+__all__ = ["Initializer", "Uniform", "Normal", "DeviceNormal", "Orthogonal", "Xavier", "MSRAPrelu", "FusedRNN",
            "Constant", "Zero", "One", "Bilinear", "LSTMBias", "Load", "Mixed",
            "register", "InitDesc"]
 
@@ -119,6 +120,45 @@ class Normal(Initializer):
 
     def _init_weight(self, desc, arr):
         self._set(arr, _rng().normal(0, self.sigma, arr.shape))
+
+
+@functools.lru_cache(maxsize=None)
+def _device_normal(shape, dtype, sigma, device):
+    """One compiled draw per (shape, dtype, sigma, device)."""
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(
+        lambda key: (sigma * jax.random.normal(key, shape, jnp.float32))
+        .astype(dtype),
+        out_shardings=jax.sharding.SingleDeviceSharding(device))
+
+
+@register("devicenormal")
+class DeviceNormal(Initializer):
+    """N(0, sigma) drawn on the array's own device, in its dtype, from
+    ``seed`` and the parameter's name: no host draw and no transfer, for
+    models of billions of parameters (the host initialisers draw leaf by leaf
+    in numpy, 7 s for 110 M). A per-name ``scales`` entry multiplies sigma
+    for parameters whose name ends with its key."""
+
+    def __init__(self, sigma=0.02, seed=0, scales=None):
+        super().__init__(sigma=sigma, seed=seed, scales=scales)
+        self.sigma, self.seed, self.scales = sigma, seed, dict(scales or {})
+
+    def _init_weight(self, desc, arr):
+        import zlib
+        import jax
+        sigma = self.sigma
+        for suffix, scale in self.scales.items():
+            if str(desc).endswith(suffix):
+                sigma *= scale
+        device = next(iter(arr.data.devices()))
+        key = jax.random.fold_in(jax.random.key(self.seed, impl="rbg"),
+                                 zlib.crc32(str(desc).encode()) & 0x7FFFFFFF)
+        draw = _device_normal(tuple(arr.shape), arr.data.dtype, sigma, device)
+        arr._set_data(draw(jax.device_put(key, device)))
+
+    _init_default = _init_weight
 
 
 @register("constant")

@@ -11,6 +11,7 @@ is a lax.scan (compiled once, no per-step dispatch — the cuDNN-fused-RNN analo
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -737,6 +738,154 @@ def multi_head_attention(q, k, v, mask=None, *, heads=1, dropout=0.0, causal=Fal
     out = jnp.einsum("nhlm,nhmd->nhld", p, vh,
                      preferred_element_type=jnp.float32).astype(q.dtype)
     return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * D)
+
+
+# ---------------------------------------------------------------------------
+# Present-day decoder blocks: rotary positions, grouped KV heads under a
+# block mask, routed experts (no reference op: TPU-era extensions like RMSNorm)
+# ---------------------------------------------------------------------------
+_MASKED = -1e30      # underflows to an exactly-zero softmax weight in f32
+
+
+@register("rotary_embedding", jit=True)
+def rotary_embedding(x, positions, *, theta=10000.0):
+    """Rotary positions over the whole head, rotate-half: ``x`` (..., S,
+    heads, D), ``positions`` (..., S) int. Angles and the rotation are
+    float32; the result has ``x``'s dtype."""
+    D = x.shape[-1]
+    inv = float(theta) ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
+    xa = x.astype(jnp.float32)
+    half = jnp.concatenate([-xa[..., D // 2:], xa[..., :D // 2]], -1)
+    return (xa * cos + half * sin).astype(x.dtype)
+
+
+@register("block_attention", jit=True)
+def block_attention(q, k, v, positions, k_ctx=None, v_ctx=None, *, heads,
+                    kv_heads, block_length=1):
+    """Attention of rows to their own blocks and to everything before them.
+
+    ``q`` (B, S, heads*D), ``k``/``v`` (B, S, kv_heads*D): the rows' own
+    projections, ``positions`` (B, S) int32. Query head h attends with KV
+    head ``h // (heads / kv_heads)``. A row at position i sees a row at
+    position j iff ``j // block_length <= i // block_length``: both
+    directions inside a block, causal between blocks (``block_length`` 1 is
+    the causal mask). ``k_ctx``/``v_ctx`` (B, C, kv_heads*D), if given, are a
+    cache whose lane j holds position j; a row sees the lanes of blocks
+    strictly before its own (its own block's rows are in ``k``/``v``), so
+    whatever lies in later lanes is never read unmasked. Scores, the softmax
+    over both parts and the accumulation are float32; the probabilities are
+    cast to ``q``'s dtype for the product with the values."""
+    B, S, _ = q.shape
+    G = heads // kv_heads
+    D = q.shape[-1] // heads
+    qh = q.reshape(B, S, kv_heads, G, D)
+    blk = positions // block_length                        # (B, S)
+    parts = [(k, v, blk[:, None, :] <= blk[:, :, None])]   # (B, q, k)
+    if k_ctx is not None:
+        lane = jnp.arange(k_ctx.shape[1], dtype=positions.dtype)
+        parts.append((k_ctx, v_ctx,
+                      lane[None, None, :] // block_length < blk[:, :, None]))
+    scores = []
+    for kk, _, mask in parts:
+        kh = kk.reshape(B, -1, kv_heads, D)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qh, kh,
+                       preferred_element_type=jnp.float32) / math.sqrt(D)
+        scores.append(jnp.where(mask[:, None, None], s, _MASKED))
+    top = functools.reduce(jnp.maximum, [s.max(-1) for s in scores])
+    out, denom = 0.0, 0.0
+    for s, (_, vv, _) in zip(scores, parts):
+        e = jnp.exp(s - top[..., None])
+        denom = denom + e.sum(-1)
+        vh = vv.reshape(B, -1, kv_heads, D)
+        out = out + jnp.einsum("bhgqk,bkhd->bqhgd", e.astype(q.dtype), vh,
+                               preferred_element_type=jnp.float32)
+    out = out / denom.transpose(0, 3, 1, 2)[..., None]
+    return out.reshape(B, S, heads * D).astype(q.dtype)
+
+
+_GMM_WEIGHT_TILE_BYTES = 4 << 20     # of a (K, N) tile; Mosaic holds two
+
+
+def _gmm_tiling(m, k, n, itemsize):
+    """(tm, tk, tn) for the Pallas grouped matmul, or None where it does not
+    apply. A group's whole (K, N) matrix is one tile where it fits (3 MiB at
+    2048 x 768 in bfloat16): at 16 rows a group the product is bound by
+    reading each held expert's weights once, and one long DMA a group reads
+    them at 600 GB/s on a v5e where (128, 128, 128) tiles reach 80 and the
+    compiler's own ``ragged_dot`` 200-240 (microbenchmark, PR 28)."""
+    tm = 128 if m % 128 == 0 else m if m < 128 and m % 16 == 0 else None
+    if tm is None or k % 128 or n % 128:
+        return None
+    tk = k
+    while tk * n * itemsize > _GMM_WEIGHT_TILE_BYTES and tk % 256 == 0:
+        tk //= 2
+    return (tm, tk, n) if tk * n * itemsize <= _GMM_WEIGHT_TILE_BYTES else None
+
+
+def _grouped_matmul(rows, w, sizes):
+    """``rows`` (M, K), sorted by group, times each group's ``w`` (G, K, N):
+    (M, N) float32; rows past ``sizes.sum()`` are not computed (whatever they
+    hold is masked by the caller). On a TPU in bfloat16 the Pallas grouped
+    matmul of ``jax.experimental`` (megablox) at :func:`_gmm_tiling`; anywhere
+    else ``lax.ragged_dot``. Chosen from ``jax.default_backend()`` at trace
+    time, as ``flash_attention`` is."""
+    tiling = None
+    if jax.default_backend() == "tpu" and rows.dtype == jnp.bfloat16:
+        tiling = _gmm_tiling(rows.shape[0], rows.shape[1], w.shape[2],
+                             rows.dtype.itemsize)
+    if tiling is None:
+        return lax.ragged_dot(
+            rows, w, sizes, preferred_element_type=jnp.float32,
+            precision=(lax.Precision.DEFAULT if rows.dtype == jnp.bfloat16
+                       else lax.Precision.HIGHEST))
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    # the kernel's dot names no precision and would inherit the package's
+    # global "highest", which Mosaic rejects on bfloat16 operands ("Bad lhs
+    # type"); one MXU pass is exact for them
+    with jax.default_matmul_precision("bfloat16"):
+        return gmm(rows, w, sizes, preferred_element_type=jnp.float32,
+                   tiling=tiling)
+
+
+@register("moe_ffn", jit=True)
+def moe_ffn(x, router, w_gate, w_up, w_down, *, top_k, norm_topk=True,
+            first_expert=0):
+    """Routed SwiGLU experts over rows ``x`` (T, H): returns (the part of the
+    layer's result that the held experts give (T, H), rows routed to each held
+    expert (E_held,) int32).
+
+    ``router`` (H, E) scores every expert: a float32 softmax over all E, the
+    ``top_k`` largest, divided by their sum under ``norm_topk``. ``w_gate``,
+    ``w_up`` (E_held, H, F) and ``w_down`` (E_held, F, H) are the experts this
+    chip holds, ``first_expert`` onward; rows routed elsewhere add nothing
+    here. No row is dropped and there is no capacity: the (row, expert) pairs
+    are sorted by expert and each expert multiplies exactly its own rows
+    (:func:`_grouped_matmul`), so the work grows with the rows routed and not
+    with rows x experts. Accumulation is float32."""
+    T, _ = x.shape
+    held = w_gate.shape[0]
+    probs = jax.nn.softmax(
+        jnp.dot(x, router, preferred_element_type=jnp.float32), -1)
+    top_p, top_i = lax.top_k(probs, top_k)
+    if norm_topk:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    expert = top_i.reshape(-1) - first_expert
+    here = (expert >= 0) & (expert < held)
+    expert = jnp.where(here, expert, held)          # elsewhere: sorted last
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=held + 1)[:held].astype(jnp.int32)
+    rows = x[order // top_k]
+    gate = _grouped_matmul(rows, w_gate, sizes)
+    up = _grouped_matmul(rows, w_up, sizes)
+    y = _grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down,
+                        sizes)
+    weight = jnp.where(here, top_p.reshape(-1), 0.0)[order]
+    y = jnp.where(here[order][:, None], y * weight[:, None], 0.0)
+    out = y[jnp.argsort(order)].reshape(T, top_k, -1).sum(1)
+    return out.astype(x.dtype), sizes
 
 
 # ---------------------------------------------------------------------------

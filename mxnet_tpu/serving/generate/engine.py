@@ -17,6 +17,16 @@ accounting covers decode traffic:
   table, run ``TransformerLM.decode_step`` (single_query_attention inside),
   write the new K/V row, greedy-argmax the next token on device.
 
+A block may state ``block_length`` L > 1 and a ``mask_token_id`` (generation
+by diffusion over blocks, ``gluon.model_zoo.moe_lm``): the step is then L
+rows a sequence under the block's own mask, for lanes at any phase of their
+blocks. Each lane's flag says whether this forward *commits* — writes the
+block's K/V in place — or is a denoising step whose keys saw mask tokens and
+are dropped; what comes back per row is the arg-max id other than the mask
+token and its float32 softmax probability, never the logits. The pool's row
+is the block's ``kv_units`` (default ``units``) and its dtype the
+parameters'.
+
 Bitwise contract: every model op is per-row and masked lanes carry exactly
 zero softmax weight, so a row's output depends only on its own tokens and
 pages — not on batch composition, bucket size, physical page placement, or
@@ -46,13 +56,52 @@ def _now_us() -> int:
     return time.perf_counter_ns() // 1000
 
 
+def _step(block, plist, num_layers, page_size, param_datas, ids, positions,
+          tables, valid, k_pool, v_pool):
+    """One traced decode step: gather every lane's cached context, run the
+    block's ``decode_step`` on its L rows a lane (``ids``/``positions`` (B,)
+    for L = 1, else (B, L)), write the rows' K/V in place for the lanes
+    ``valid`` flags. Returns (logits, whatever the block returned after its
+    K/V, k_pool, v_pool)."""
+    import jax.numpy as jnp
+    from ...gluon.block import pure_apply
+    gk = gather_ctx(k_pool, tables)        # (layers, B, ctx, kv)
+    gv = gather_ctx(v_pool, tables)
+    inputs = (ids, positions)
+    for i in range(num_layers):
+        inputs = inputs + (gk[i], gv[i])
+    outs, _, _ = pure_apply(block, plist, param_datas, inputs, None,
+                            training=False, method="decode_step")
+    last = 1 + 2 * num_layers
+    ks = jnp.stack(outs[1:last:2], 0)      # (layers, B[, L], kv)
+    vs = jnp.stack(outs[2:last:2], 0)
+    k_pool, v_pool = write_step((k_pool, v_pool), (ks, vs), tables,
+                                positions, valid, page_size)
+    return outs[0], outs[last:], k_pool, v_pool
+
+
+def _candidates(logits, mask_id):
+    """Per row of (..., V) logits: (the arg-max id other than the mask
+    token, its softmax probability), both in float32 arithmetic."""
+    import jax.numpy as jnp
+    logits = logits.astype(jnp.float32)
+    logits = jnp.where(jnp.arange(logits.shape[-1]) == mask_id, -jnp.inf,
+                       logits)
+    top = logits.max(-1, keepdims=True)
+    conf = 1.0 / jnp.exp(logits - top).sum(-1)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), conf
+
+
 class DecodeEndpoint:
     """A named generative model with bucketed prefill/decode executables.
 
     ``block`` must expose the incremental-decode protocol of
     ``gluon.model_zoo.bert.TransformerLM``: ``num_layers``/``units``
     attributes, ``prefill_collect(tokens)`` and
-    ``decode_step(ids, positions, *kv_ctx)``.
+    ``decode_step(ids, positions, *kv_ctx)``; optionally ``kv_units``,
+    ``block_length`` and ``mask_token_id`` (module docstring), and after the
+    layers' K/V a ``decode_step`` may return the rows routed to each expert,
+    (layers, experts), which the step reduces to two numbers.
 
     Device work (``prefill``/``decode_step``/``warmup``/pool mutation)
     follows the serving single-dispatcher rule: one thread — the decode
@@ -81,6 +130,13 @@ class DecodeEndpoint:
             decode_buckets, self.max_batch_size)
         self.prefill_buckets = bucketing.seq_buckets(
             self.max_seq_len, ladder=prefill_buckets)
+        self.block_length = int(getattr(block, "block_length", 1))
+        self.mask_token_id = getattr(block, "mask_token_id", None)
+        if (self.block_length > 1) != (self.mask_token_id is not None):
+            raise MXNetError(
+                f"decode endpoint {name!r}: blocks of {self.block_length} "
+                f"positions with mask token {self.mask_token_id!r}; a model "
+                "generated by diffusion over blocks states both")
         max_len = getattr(block, "max_length", None)
         if max_len is not None and self.max_seq_len > int(max_len):
             raise MXNetError(
@@ -105,17 +161,26 @@ class DecodeEndpoint:
         self._decode_execs: Dict[int, object] = {}
         self._pf_jfn = None
         self._dec_jfn = None
+        self.last_step: Dict[str, object] = {}
         self._probe()
         self.pool = PagedKVPool(name, int(block.num_layers),
-                                int(block.units), self.max_seq_len,
+                                int(getattr(block, "kv_units", block.units)),
+                                self.max_seq_len,
                                 page_size=page_size, num_pages=num_pages,
                                 dtype=self._param_datas()[0].dtype,
                                 device=self.ctx.jax_device())
+        if self.max_seq_len % self.block_length \
+                or self.pool.page_size % self.block_length:
+            raise MXNetError(
+                f"decode endpoint {name!r}: block_length "
+                f"{self.block_length} must divide max_seq_len "
+                f"{self.max_seq_len} and the page size "
+                f"{self.pool.page_size} (a block lies in one page)")
 
     # ------------------------------------------------------------------
     def _probe(self):
-        """One eager prefill-bucket forward: triggers deferred parameter
-        init and validates the block's decode protocol."""
+        """Validates the block's decode protocol; one eager prefill-bucket
+        forward where a parameter's initialisation is still deferred."""
         from ... import autograd
         from ...ndarray.ndarray import NDArray
         for attr in ("num_layers", "units", "prefill_collect", "decode_step"):
@@ -124,11 +189,13 @@ class DecodeEndpoint:
                     f"decode endpoint {self.name!r}: block lacks the "
                     f"incremental-decode protocol member {attr!r} "
                     "(see gluon.model_zoo.bert.TransformerLM)")
-        dummy = NDArray(onp.zeros((1, self.prefill_buckets[0]), onp.int32),
-                        ctx=self.ctx)
-        with autograd._RecordingStateScope(False, False):
-            self.block(dummy)
         self._params = list(self.block.collect_params().values())
+        if any(p._data is None for p in self._params):
+            dummy = NDArray(
+                onp.zeros((1, self.prefill_buckets[0]), onp.int32),
+                ctx=self.ctx)
+            with autograd._RecordingStateScope(False, False):
+                self.block(dummy)
         from ...telemetry import memstats as _memstats
         _memstats.register(
             "serving", f"{self.name}.params", owner=self,
@@ -188,6 +255,7 @@ class DecodeEndpoint:
             block, plist = self.block, self._params
             page_size = int(_config.get("MXNET_KV_PAGE_SIZE")) \
                 if not hasattr(self, "pool") else self.pool.page_size
+            causal = self.mask_token_id is None
 
             def prefill(param_datas, tokens, length, table, k_pool, v_pool):
                 outs, _, _ = pure_apply(block, plist, param_datas, (tokens,),
@@ -199,8 +267,13 @@ class DecodeEndpoint:
                 k_pool, v_pool = write_prefill(
                     (k_pool, v_pool), (ks, vs), table[0], length[0],
                     page_size)
-                next_id = jnp.argmax(logits[0, length[0] - 1]) \
-                    .astype(jnp.int32)
+                if causal:
+                    next_id = jnp.argmax(logits[0, length[0] - 1]) \
+                        .astype(jnp.int32)
+                else:
+                    # a block's first tokens come from its first denoising
+                    # step: the head's product is never computed here
+                    next_id = jnp.zeros((), jnp.int32)
                 return next_id.reshape(1), k_pool, v_pool
 
             donate = (4, 5) if self._donate_pools() else ()
@@ -215,25 +288,22 @@ class DecodeEndpoint:
             block, plist = self.block, self._params
             page_size = self.pool.page_size
             num_layers = int(block.num_layers)
+            mask_id = self.mask_token_id
 
             def decode(param_datas, ids, positions, tables, valid,
                        k_pool, v_pool):
-                gk = gather_ctx(k_pool, tables)    # (layers, B, L, kv)
-                gv = gather_ctx(v_pool, tables)
-                inputs = (ids, positions)
-                for i in range(num_layers):
-                    inputs = inputs + (gk[i], gv[i])
-                outs, _, _ = pure_apply(block, plist, param_datas, inputs,
-                                        None, training=False,
-                                        method="decode_step")
-                logits = outs[0]                   # (B, V)
-                ks = jnp.stack(outs[1::2], 0)      # (layers, B, kv)
-                vs = jnp.stack(outs[2::2], 0)
-                k_pool, v_pool = write_step(
-                    (k_pool, v_pool), (ks, vs), tables, positions, valid,
-                    page_size)
-                next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return next_ids, k_pool, v_pool
+                logits, aux, k_pool, v_pool = _step(
+                    block, plist, num_layers, page_size, param_datas, ids,
+                    positions, tables, valid, k_pool, v_pool)
+                if mask_id is None:
+                    picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                else:
+                    picked = _candidates(logits, mask_id)
+                    if aux:     # rows routed to each expert, (layers, E):
+                        # the busiest's (mean over layers) and the mean
+                        load = aux[0].astype(jnp.float32)
+                        picked += (load.max(-1).mean(), load.mean())
+                return picked, k_pool, v_pool
 
             donate = (5, 6) if self._donate_pools() else ()
             self._dec_jfn = self._jit_decode(decode, donate)
@@ -321,12 +391,19 @@ class DecodeEndpoint:
         return self._compile(self._prefill_execs, seq_bucket,
                              self._prefill_fn(), arg_sds, "prefill")
 
+    def _rows_shape(self, batch: int):
+        """Shape of a step's ids and positions: a row a lane for one token a
+        step, ``block_length`` rows a lane otherwise."""
+        return (batch,) if self.block_length == 1 \
+            else (batch, self.block_length)
+
     def _get_decode(self, batch_bucket: int):
         import jax
         import jax.numpy as jnp
         P = self.pool.pages_per_seq
-        arg_sds = (jax.ShapeDtypeStruct((batch_bucket,), jnp.int32),
-                   jax.ShapeDtypeStruct((batch_bucket,), jnp.int32),
+        rows = self._rows_shape(batch_bucket)
+        arg_sds = (jax.ShapeDtypeStruct(rows, jnp.int32),
+                   jax.ShapeDtypeStruct(rows, jnp.int32),
                    jax.ShapeDtypeStruct((batch_bucket, P), jnp.int32),
                    jax.ShapeDtypeStruct((batch_bucket,), jnp.bool_)) \
             + self._pool_sds()
@@ -363,8 +440,8 @@ class DecodeEndpoint:
             if fresh:
                 n += 1
                 if execute:
-                    ids = onp.zeros((b,), onp.int32)
-                    pos = onp.zeros((b,), onp.int32)
+                    ids = onp.zeros(self._rows_shape(b), onp.int32)
+                    pos = onp.zeros(self._rows_shape(b), onp.int32)
                     tables = onp.zeros((b, P), onp.int32)
                     valid = onp.zeros((b,), bool)
                     t0 = _now_us()
@@ -404,38 +481,61 @@ class DecodeEndpoint:
         self.stats.record_prefill(dt)
         return out
 
-    def decode_step(self, rows: Sequence[Tuple[int, int, onp.ndarray]]
-                    ) -> Tuple[int, ...]:
+    def decode_step(self, rows: Sequence[tuple]):
         """One batched decode step. ``rows`` is ``(input_id, position,
         page_table)`` per running sequence; returns the next token id per
         row. Padding rows (bucket fill) carry zero tables and a False valid
-        mask — their writes land on scratch page 0."""
+        mask — their writes land on scratch page 0.
+
+        With ``block_length`` L > 1 a row is ``(ids, first position,
+        page_table, commit)``: the L ids of the sequence's current block,
+        and whether this forward writes the block's K/V (a lane that does
+        not is routed to the scratch page like a padding row). Returns
+        ``(ids (L,), confidences (L,))`` per row, and leaves on
+        ``last_step`` what the step's span and counters report."""
         n = len(rows)
+        L = self.block_length
         B = bucketing.bucket_for(n, self.decode_buckets)
         P = self.pool.pages_per_seq
         comp = self._get_decode(B)
         with _telemetry.span("decode.pack"):
-            ids = onp.zeros((B,), onp.int32)
-            pos = onp.zeros((B,), onp.int32)
+            ids = onp.zeros(self._rows_shape(B), onp.int32)
+            pos = onp.zeros(self._rows_shape(B), onp.int32)
             tables = onp.zeros((B, P), onp.int32)
             valid = onp.zeros((B,), bool)
-            for i, (tok, p, table) in enumerate(rows):
-                ids[i] = tok
-                pos[i] = p
-                tables[i] = table
-                valid[i] = True
+            lanes = onp.arange(L, dtype=onp.int32)
+            for i, row in enumerate(rows):
+                ids[i] = row[0]
+                pos[i] = row[1] if L == 1 else row[1] + lanes
+                tables[i] = row[2]
+                valid[i] = len(row) < 4 or row[3]
         t0 = _now_us()
         with _telemetry.span("decode.launch", kind="step", bucket=B):
-            next_ids, k, v = comp(self._param_datas(), ids, pos, tables,
-                                  valid, self.pool.k_pool, self.pool.v_pool)
+            picked, k, v = comp(self._param_datas(), ids, pos, tables,
+                                valid, self.pool.k_pool, self.pool.v_pool)
         with _telemetry.span("decode.fetch", kind="step"):
-            out = onp.asarray(next_ids)        # sync point
+            if L == 1:
+                out = onp.asarray(picked)      # sync point
+            else:
+                out = [onp.asarray(a) for a in picked]
         self.pool.update_arrays(k, v)
         dt = _now_us() - t0
         self._observe_cost(self.step_cost, "step", "decode_step",
                            B, dt, rows=n)
-        self.stats.record_step(dt, n, B)
-        return tuple(int(x) for x in out[:n])
+        commits = int(valid.sum())
+        self.last_step = {"commits": commits}
+        # the straggler a grouped expert product waits for against the rows
+        # an expert gets on average (every row the executable computes is
+        # routed, padding lanes too)
+        expert_load = tuple(float(a) for a in out[2:]) if L > 1 else ()
+        if expert_load:
+            self.last_step.update(zip(
+                ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
+        self.stats.record_step(dt, n, B, rows=n * L, commits=commits,
+                               expert_load=expert_load)
+        if L == 1:
+            return tuple(int(x) for x in out[:n])
+        return [(out[0][i], out[1][i]) for i in range(n)]
 
     def snapshot(self) -> Dict:
         return {

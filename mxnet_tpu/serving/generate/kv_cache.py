@@ -125,7 +125,7 @@ def write_prefill(pool, vals, table_row, length, page_size: int):
 
 
 def write_step(pool, vals, tables, positions, valid, page_size: int):
-    """Write one decode step's new K (or V) row per sequence, in place.
+    """Write one decode step's new K (or V) rows per sequence, in place.
 
     ``vals`` (num_layers, B, kv_dim); ``tables`` (B, P) int32;
     ``positions`` (B,) int32 — the lane each row's new token occupies;
@@ -134,7 +134,13 @@ def write_step(pool, vals, tables, positions, valid, page_size: int):
     unmasked). ``pool`` and ``vals`` may be matching tuples (K and V): one
     loop then carries both.
 
-    One ``dynamic_update_slice`` of a ``(num_layers, 1, 1, kv_dim)`` row per
+    A step of L rows a sequence passes ``vals`` (num_layers, B, L, kv_dim)
+    and ``positions`` (B, L): a block of consecutive positions that starts
+    on a multiple of L, where L divides ``page_size``, so that it lies in
+    one page and is one slice. ``valid`` is then the sequence's commit flag:
+    a denoising step's rows go to the scratch page too.
+
+    One ``dynamic_update_slice`` of a ``(num_layers, 1, L, kv_dim)`` slab per
     sequence, under a loop the pool passes through in its own layout. The
     advanced-index scatter ``pool.at[:, page, slot, :].set(vals)`` writes
     the same rows, but the TPU compiler gives its scatter a layout with the
@@ -145,16 +151,20 @@ def write_step(pool, vals, tables, positions, valid, page_size: int):
     import jax.numpy as jnp
     from jax import lax
     B = tables.shape[0]
-    page = tables[jnp.arange(B), positions // page_size]
+    if positions.ndim == 1:         # one row a sequence: a block of one
+        positions = positions[:, None]
+        vals = jax.tree.map(lambda v: v[:, :, None], vals)
+    first = positions[:, 0]
+    page = tables[jnp.arange(B), first // page_size]
     page = jnp.where(valid, page, 0)
-    slot = positions % page_size
+    slot = first % page_size
 
     def body(i, pools):
         start = (0, page[i], slot[i], 0)
 
         def put(p, v):
-            row = lax.dynamic_slice_in_dim(v, i, 1, 1)     # (layers, 1, kv)
-            return lax.dynamic_update_slice(p, row[:, :, None], start)
+            rows = lax.dynamic_slice_in_dim(v, i, 1, 1)    # (layers, 1, L, kv)
+            return lax.dynamic_update_slice(p, rows, start)
 
         return jax.tree.map(put, pools, vals)
 

@@ -28,6 +28,18 @@ Correctness invariants (the chaos scenario asserts all three):
 
 Backpressure is lossless: a full client stream pauses the sequence (state
 PAUSED, pages kept, not stepped); the stream's resume callback re-runs it.
+
+A step need not yield one token a sequence. An endpoint whose model is
+generated by diffusion over blocks (``block_length`` L > 1) steps L rows a
+sequence, and a pass places 0 to L tokens in it: a denoising step fixes the
+``L / denoising_steps`` still-masked positions of highest confidence, and
+when none is left the block's tokens go to the stream, in sequence order,
+each with the step it was placed at and the confidence it was placed with,
+and the next pass's forward *commits* the block's K/V. Lanes of one batch are at different steps of their blocks.
+The prompt's whole blocks are prefilled; its ragged tail fills the first
+positions of the first block, and positions past ``max_new_tokens`` in the
+last block stay masked. All of a block's state lives on the sequence, so a
+failover requeue resumes it mid-block.
 """
 from __future__ import annotations
 
@@ -56,6 +68,9 @@ _RUNNING, _DRAINING, _STOPPED = "running", "draining", "stopped"
 _S_WAITING, _S_RUNNING, _S_PAUSED = "waiting", "running", "paused"
 _S_DONE, _S_FAILED, _S_CANCELLED = "done", "failed", "cancelled"
 
+# _Seq.placed, beside the step numbers
+_GIVEN, _PAST = -1, -2
+
 # what a pass of the decode loop tells the loop to do next
 _EXIT, _AGAIN, _REST = "exit", "again", "rest"
 
@@ -75,11 +90,12 @@ class _Tenant:
 class _Seq:
     __slots__ = ("sid", "tenant", "prompt", "max_new", "eos_id", "stream",
                  "state", "emitted", "pos", "prefilled", "enqueue_us",
-                 "admitted", "last_token_us", "deadline", "trace_id")
+                 "admitted", "last_token_us", "deadline", "trace_id",
+                 "per_step", "block", "placed", "conf", "step")
 
     def __init__(self, sid: int, tenant: _Tenant, prompt: Sequence[int],
                  max_new: int, eos_id: Optional[int], stream: TokenStream,
-                 deadline=None):
+                 deadline=None, per_step: int = 1):
         self.sid = sid
         self.tenant = tenant
         self.prompt = list(prompt)
@@ -98,6 +114,15 @@ class _Seq:
         # decode.prefill share one id across the queue hop
         self.trace_id = (_telemetry.current_trace_id()
                          or _telemetry.new_trace_id())
+        # generation by diffusion over blocks: tokens placed a denoising
+        # step, the current block's ids, and for each of its positions the
+        # step it was placed at (_GIVEN: the prompt's tail; None: masked, to
+        # be placed; _PAST: beyond the budget, masked for good)
+        self.per_step = per_step
+        self.block: List[int] = []
+        self.placed: list = []
+        self.conf: list = []             # the confidence each was placed with
+        self.step = 0
 
 
 class DecodeScheduler:
@@ -215,7 +240,8 @@ class DecodeScheduler:
     def submit(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None,
                tenant: str = "default", eos_id: Optional[int] = None,
-               on_token=None, deadline=None) -> TokenStream:
+               on_token=None, deadline=None,
+               denoising_steps: Optional[int] = None) -> TokenStream:
         """Queue one generation; returns its :class:`TokenStream`.
 
         The prompt plus generation budget must fit the endpoint's
@@ -228,6 +254,10 @@ class DecodeScheduler:
         budget runs out (site ``decode_token``). Under brownout (level >= 1)
         ``max_new_tokens`` is clamped to MXNET_BROWNOUT_MAX_NEW_TOKENS —
         generations shorten before anyone is refused.
+
+        ``denoising_steps`` (endpoints generated by diffusion over blocks):
+        forwards spent on a block, each placing ``block_length /
+        denoising_steps`` tokens; default ``block_length``, one a step.
         """
         if deadline is not None:
             deadline.check("ingress")
@@ -241,6 +271,13 @@ class DecodeScheduler:
         if max_new_tokens < 1:
             raise MXNetError(f"max_new_tokens must be >= 1, "
                              f"got {max_new_tokens}")
+        L = self.engine.block_length
+        if denoising_steps is None:
+            denoising_steps = L
+        if denoising_steps < 1 or L % denoising_steps:
+            raise MXNetError(
+                f"denoising_steps must divide the endpoint's block_length "
+                f"{L}, got {denoising_steps}")
         total = len(prompt) + max_new_tokens
         if total > self.engine.max_seq_len:
             raise MXNetError(
@@ -260,7 +297,7 @@ class DecodeScheduler:
             stream = TokenStream(sid, self._stream_buffer,
                                  on_token=on_token, resume_cb=self._resume)
             seq = _Seq(sid, ten, prompt, int(max_new_tokens), eos_id, stream,
-                       deadline=deadline)
+                       deadline=deadline, per_step=L // denoising_steps)
             self._waiting.append(seq)
             self._by_sid[sid] = seq
             self._stats.seq_event("submitted")
@@ -340,29 +377,32 @@ class DecodeScheduler:
                                 f"sequence {s.sid} overran its deadline "
                                 f"after {len(s.emitted)} of {s.max_new} "
                                 "tokens"))
-                    rows = [s for s in self._active if s.state == _S_RUNNING]
-                    if not rows:
+                    seqs = [s for s in self._active if s.state == _S_RUNNING]
+                    if not seqs:
                         if not admits:
                             self._cond.wait(0.005)  # pool-blocked
                         return _REST
-                    batch = [(s, s.emitted[-1], s.pos,
-                              self.engine.pool.table(s.sid)) for s in rows]
-                sp.attrs["rows"] = it.attrs["rows"] = len(batch)
+                    # what each sends to the step, beside it (no per-lane
+                    # unpacking on this path: 64 lanes a pass)
+                    rows = [self._row_locked(s) for s in seqs]
+                sp.attrs["rows"] = it.attrs["rows"] = len(seqs)
             try:
                 _faults.check("decode")
                 with _telemetry.span(
-                        "decode.step", rows=len(batch),
+                        "decode.step",
+                        rows=len(seqs) * self.engine.block_length,
                         bucket=bucketing.bucket_for(
-                            len(batch), self.engine.decode_buckets)):
-                    toks = self.engine.decode_step(
-                        [(tok, pos, table) for _, tok, pos, table in batch])
+                            len(seqs), self.engine.decode_buckets),
+                        placed=sum(map(self._places, seqs, rows))) as sp:
+                    toks = self.engine.decode_step(rows)
+                    sp.attrs.update(self.engine.last_step)
             except FaultInjected as e:
                 _telemetry.event("decode_fault_absorbed", kind=e.kind,
                                  endpoint=self.engine.name)
                 return _REST            # transient: re-form and retry
             except Exception as e:
                 with self._cond:
-                    for s, _, _, _ in batch:
+                    for s in seqs:
                         self._fail_seq_locked(s, e)
                 return _REST
             # one decode step = one unit of real work funding the decode
@@ -374,30 +414,96 @@ class DecodeScheduler:
                         return _EXIT    # died-and-replaced mid-step: the
                                         # new generation already owns these
                                         # sequences; emitting now would dup
-                    live = [(s, tok) for (s, _, _, _), tok in zip(batch, toks)
+                    live = [(s, row[3], out) for s, row, out
+                            in zip(seqs, rows, toks)
                             # the others were retired concurrently (cancel)
                             if s.state in (_S_RUNNING, _S_PAUSED)]
-                    for s, tok in live:
-                        s.pos += 1
-                        self._emit_locked(s, tok)
+                    before = self._stats.counters["tokens"]
+                    self._stats.placed(sum(
+                        self._absorb_locked(s, commit, out)
+                        for s, commit, out in live))
                     self._stats.set_queue_depth(len(self._waiting))
                     more = self._runnable_locked()
-                sp.attrs["tokens"] = len(live)
+                    sp.attrs["tokens"] = \
+                        self._stats.counters["tokens"] - before
         return _AGAIN if more else _REST
+
+    # -- what a sequence sends to a step, and takes from it -----------------
+    def _row_locked(self, s: "_Seq"):    # mxlint: disable=CONC200
+        """``(ids, first position, page table, commit)`` for the engine: the
+        last token at its position, always committing, or the current block
+        and whether it is whole and this forward writes its K/V."""
+        table = self.engine.pool.table(s.sid)
+        if self.engine.block_length == 1:
+            return s.emitted[-1], s.pos, table, True
+        return list(s.block), s.pos, table, None not in s.placed
+
+    def _places(self, s: "_Seq", row) -> int:
+        """Tokens the step about to run fixes in ``s``, which sends it
+        ``row``."""
+        if self.engine.block_length == 1:
+            return 1
+        return 0 if row[3] else min(s.per_step, s.placed.count(None))
+
+    def _start_block_locked(self, s: "_Seq"):    # mxlint: disable=CONC200
+        """Lay out the block at ``s.pos``: what is known of it (the prompt's
+        ragged tail, in the first block only), then mask tokens."""
+        L = self.engine.block_length
+        end = len(s.prompt) + s.max_new
+        known = (s.prompt + s.emitted)[s.pos:s.pos + L]
+        s.block = known + [self.engine.mask_token_id] * (L - len(known))
+        s.placed = [_GIVEN] * len(known) + [
+            None if s.pos + i < end else _PAST for i in range(len(known), L)]
+        s.conf = [None] * L
+        s.step = 0
+
+    def _absorb_locked(self, s: "_Seq", commit: bool,    # mxlint: disable=CONC200
+                       out) -> int:
+        """Take one step's result into ``s``; returns the tokens it fixed.
+        One token a step: ``out`` is the next token. Blocks: ``out`` is
+        (ids, confidences) of the block's rows. A forward that committed
+        moves on to the next block; a denoising step places its
+        ``per_step`` most confident masked positions (ties to the lower
+        one), and once the block is whole its tokens are emitted."""
+        if self.engine.block_length == 1:
+            s.pos += 1
+            self._emit_locked(s, out)
+            return 1
+        if commit:
+            s.pos += self.engine.block_length
+            self._start_block_locked(s)
+            return 0
+        ids, conf = out
+        masked = [i for i, at in enumerate(s.placed) if at is None]
+        masked.sort(key=lambda i: (-conf[i], i))
+        chosen = masked[:s.per_step]
+        for i in chosen:
+            s.block[i], s.placed[i] = int(ids[i]), s.step
+            s.conf[i] = float(conf[i])
+        s.step += 1
+        if None not in s.placed:
+            for tok, at, sure in zip(s.block, s.placed, s.conf):
+                if at >= 0 and s.state in (_S_RUNNING, _S_PAUSED):
+                    self._emit_locked(s, tok, at, sure)
+        return len(chosen)
 
     def _prefill(self, seq: "_Seq", epoch: int) -> bool:
         """Prefill one admitted sequence and emit its first token, under a
         ``decode.prefill`` span of the request's own trace. False once this
-        generation is fenced out."""
-        n = len(seq.prompt)
+        generation is fenced out. Generation by blocks prefills the
+        prompt's whole blocks and emits nothing yet: the ragged tail opens
+        the first block."""
+        L = self.engine.block_length
+        n = len(seq.prompt) // L * L
         with _telemetry.span(
                 "decode.prefill", trace_id=seq.trace_id, sid=seq.sid,
-                prompt_len=n,
+                prompt_len=len(seq.prompt),
                 bucket=bucketing.bucket_for(n, self.engine.prefill_buckets),
                 queue_wait_us=_now_us() - seq.enqueue_us):
             try:
                 tok = self.engine.prefill(
-                    seq.prompt, self.engine.pool.table(seq.sid))
+                    seq.prompt[:n], self.engine.pool.table(seq.sid)) \
+                    if n else None
             except BaseException as e:
                 with self._cond:
                     self._fail_seq_locked(seq, e)
@@ -405,11 +511,15 @@ class DecodeScheduler:
                     raise               # WorkerKilled et al: thread dies
                 return True
             seq.prefilled = True
-            with _telemetry.span("decode.emit", tokens=1):
+            with _telemetry.span("decode.emit", tokens=int(L == 1)):
                 with self._cond:
                     if self._epoch != epoch:
                         return False
-                    self._emit_locked(seq, tok)
+                    if L == 1:
+                        self._emit_locked(seq, tok)
+                    else:
+                        seq.pos = n
+                        self._start_block_locked(seq)
         return True
 
     def _admit_locked(self) -> List[_Seq]:    # mxlint: disable=CONC200
@@ -463,7 +573,9 @@ class DecodeScheduler:
     # ------------------------------------------------------------------
     # emission / retirement (caller holds self._cond)
     # ------------------------------------------------------------------
-    def _emit_locked(self, seq: _Seq, tok: int):    # mxlint: disable=CONC200
+    def _emit_locked(self, seq: _Seq, tok: int,    # mxlint: disable=CONC200
+                     step: Optional[int] = None,
+                     confidence: Optional[float] = None):
         now = _now_us()
         seq.emitted.append(tok)
         self._stats.tokens(1)
@@ -473,7 +585,9 @@ class DecodeScheduler:
             self._stats.record_intertoken(seq.tenant.name,
                                           now - seq.last_token_us)
         seq.last_token_us = now
-        delivered = seq.stream.put(tok)
+        # one token a step has no step to record: ``put(tok)`` as ever
+        delivered = seq.stream.put(tok) if step is None \
+            else seq.stream.put(tok, step, confidence)
         if seq.stream.cancelled:
             self._retire_locked(seq, _S_CANCELLED, "cancelled")
             return

@@ -27,6 +27,30 @@ _STEPS = _telemetry.counter(
     "Batched decode steps executed (each advances every running sequence "
     "by one token).",
     labelnames=("endpoint",))
+_ROWS = _telemetry.counter(
+    "mxtpu_decode_rows_total",
+    "Rows of live sequences forwarded by decode steps: one a sequence a step "
+    "for a causal model, block_length a sequence for generation by diffusion "
+    "over blocks.",
+    labelnames=("endpoint",))
+_COMMITS = _telemetry.counter(
+    "mxtpu_decode_blocks_committed_total",
+    "Sequence-steps whose K/V were written to the pool: every row of a "
+    "causal step; for block diffusion the one forward that commits a "
+    "finished block (its denoising steps write nothing).",
+    labelnames=("endpoint",))
+_PLACED = _telemetry.counter(
+    "mxtpu_decode_tokens_placed_total",
+    "Tokens fixed in their sequences by decode steps (0 to block_length a "
+    "sequence a step); they reach the stream in sequence order, so this "
+    "runs ahead of mxtpu_decode_tokens_total by what is still held back.",
+    labelnames=("endpoint",))
+_EXPERT_LOAD = _telemetry.gauge(
+    "mxtpu_moe_expert_load_max_over_mean",
+    "Rows routed to the busiest expert over the mean rows an expert, at "
+    "the last decode step (mean over layers): the straggler a grouped "
+    "expert product waits for.",
+    labelnames=("endpoint",))
 _SEQS = _telemetry.counter(
     "mxtpu_decode_seqs_total",
     "Sequence lifecycle events: submitted / admitted / finished / "
@@ -92,6 +116,11 @@ class DecodeStats:
         self._lock = threading.Lock()
         self.counters: Dict[str, int] = {
             "tokens": 0, "steps": 0, "compiles": 0,
+            # a step is one forward of every running sequence; what it did
+            # is counted apart, so that nothing divides tokens by steps
+            "forwards": 0, "commits": 0, "rows": 0, "tokens_placed": 0,
+            "blocks_committed": 0,
+            "moe.expert_load_max": 0.0, "moe.expert_load_mean": 0.0,
             **{f"seq_{ev}": 0 for ev in _SEQ_EVENTS},
         }
         self.prefill = LatencyHistogram()
@@ -101,6 +130,10 @@ class DecodeStats:
         self.ttft = LatencyHistogram()
         self._m_tokens = _TOKENS.labels(name)
         self._m_steps = _STEPS.labels(name)
+        self._m_rows = _ROWS.labels(name)
+        self._m_commits = _COMMITS.labels(name)
+        self._m_placed = _PLACED.labels(name)
+        self._m_expert_load = _EXPERT_LOAD.labels(name)
         self._m_seqs = {ev: _SEQS.labels(name, ev) for ev in _SEQ_EVENTS}
         self._m_occupancy = _OCCUPANCY.labels(name)
         self._m_queue_depth = _QUEUE_DEPTH.labels(name)
@@ -121,13 +154,39 @@ class DecodeStats:
             self.counters["tokens"] += n
         self._m_tokens.inc(n)
 
-    def record_step(self, dur_us: float, rows: int, bucket: int):
+    def record_step(self, dur_us: float, seqs: int, bucket: int, *,
+                    rows: int = None, commits: int = None, expert_load=()):
+        """One step executable run over ``seqs`` sequences padded to
+        ``bucket``: ``rows`` forwarded (default one a sequence), ``commits``
+        of them writing their K/V (default all), and where the model routes
+        experts ``expert_load`` = (rows routed to the busiest expert, to an
+        expert on average) of the step, summed into ``moe.expert_load_max``
+        / ``_mean`` so that a window's ratio is a difference of sums."""
+        rows = seqs if rows is None else rows
+        commits = seqs if commits is None else commits
         with self._lock:
             self.counters["steps"] += 1
+            self.counters["forwards"] += 1
+            self.counters["commits"] += commits > 0
+            self.counters["rows"] += rows
+            self.counters["blocks_committed"] += commits
+            if expert_load:
+                self.counters["moe.expert_load_max"] += expert_load[0]
+                self.counters["moe.expert_load_mean"] += expert_load[1]
             self.step.record(dur_us)
         self._m_steps.inc()
+        self._m_rows.inc(rows)
+        self._m_commits.inc(commits)
         self._m_step.observe(dur_us)
-        self._m_occupancy.set(rows / bucket if bucket else 0.0)
+        self._m_occupancy.set(seqs / bucket if bucket else 0.0)
+        if expert_load and expert_load[1]:
+            self._m_expert_load.set(expert_load[0] / expert_load[1])
+
+    def placed(self, n: int):
+        """``n`` tokens fixed in their sequences by a step."""
+        with self._lock:
+            self.counters["tokens_placed"] += n
+        self._m_placed.inc(n)
 
     def record_prefill(self, dur_us: float):
         with self._lock:

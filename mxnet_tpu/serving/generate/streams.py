@@ -52,17 +52,29 @@ class TokenStream:
         self._on_token = on_token
         self._resume_cb = resume_cb
         self.tokens_delivered = 0
+        # for each token put, the step of its block at which the scheduler
+        # placed it (0 for a causal model: one token a step); with the
+        # tokens themselves this replays every state a block went through
+        self.steps: list = []
+        # and, where the scheduler placed it by confidence (generation by
+        # diffusion over blocks), the confidence that step held for it
+        self.confidences: list = []
 
     # ------------------------------------------------------------------
     # scheduler side
     # ------------------------------------------------------------------
-    def put(self, tok: int) -> bool:
-        """Append one token. Returns False when the buffer is now full —
-        the token is NOT lost; the scheduler should pause the sequence
-        until the resume callback fires."""
+    def put(self, tok: int, step: int = 0,
+            confidence: Optional[float] = None) -> bool:
+        """Append one token, placed at ``step`` of its block (with
+        ``confidence``, where it was placed by one). Returns False when the
+        buffer is now full — the token is NOT lost; the scheduler should
+        pause the sequence until the resume callback fires."""
         cb = self._on_token
         with self._cv:
             self._dq.append(tok)
+            self.steps.append(step)
+            if confidence is not None:
+                self.confidences.append(confidence)
             full = len(self._dq) >= self._maxsize
             self._cv.notify_all()
         if cb is not None:

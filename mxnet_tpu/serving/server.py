@@ -281,16 +281,20 @@ class InferenceServer:
     def generate(self, name: str, prompt,
                  max_new_tokens: Optional[int] = None,
                  tenant: str = "default", eos_id: Optional[int] = None,
-                 on_token=None):
+                 on_token=None, denoising_steps: Optional[int] = None):
         """Stream tokens from a registered generator: returns the
-        :class:`~.generate.TokenStream` for one queued sequence."""
+        :class:`~.generate.TokenStream` for one queued sequence.
+        ``denoising_steps`` (a model generated by diffusion over blocks):
+        forwards spent on each block, ``block_length / denoising_steps``
+        tokens placed by each."""
         with self._cond:
             sched = self._generators.get(name)
         if sched is None:
             raise MXNetError(f"unknown generator {name!r}; registered: "
                              f"{sorted(self._generators)}")
         return sched.submit(prompt, max_new_tokens=max_new_tokens,
-                            tenant=tenant, eos_id=eos_id, on_token=on_token)
+                            tenant=tenant, eos_id=eos_id, on_token=on_token,
+                            denoising_steps=denoising_steps)
 
     def endpoints(self):
         with self._cond:

@@ -1,0 +1,400 @@
+"""Generation by diffusion over blocks through the decode path
+(gluon.model_zoo.moe_lm.MoEDecoderLM behind DecodeEndpoint, PagedKVPool,
+DecodeScheduler and InferenceServer.generate) against the plain float32
+reference (chipbench/reference/sdar_moe.py) at a small size on the CPU:
+logits and confidences at every (block, step) through the cache, the
+generation rule token for token and step for step, the expert layer and its
+shares, and what a denoising step and a commit do to the pool."""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from chipbench.reference import sdar_moe as ref
+from mxnet_tpu import serving
+from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
+from mxnet_tpu.ops import nn as ops
+from mxnet_tpu.serving.generate import engine as engine_mod
+
+L, MASK, VOCAB = 4, 95, 96
+DIMS = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            rms_norm_eps=1e-6, rope_theta=1e6, num_experts=8,
+            num_experts_per_tok=2, norm_topk_prob=True, block_length=L,
+            mask_token_id=MASK)
+TOL = dict(rtol=2e-4, atol=2e-4)      # float32 both sides, other op order
+
+
+def build_lm(seed=3, **kw):
+    lm = MoEDecoderLM(num_layers=2, units=64, num_heads=4, num_kv_heads=2,
+                      head_dim=16, expert_hidden=32, num_experts=8,
+                      experts_per_token=2, vocab_size=VOCAB, block_length=L,
+                      mask_token_id=MASK, **kw)
+    # a wide head: confidences spread between positions
+    lm.initialize(mx.init.DeviceNormal(0.05, seed=seed,
+                                       scales={"head_weight": 10}))
+    lm.hybridize()
+    return lm
+
+
+def reference_params(lm):
+    """The system's weights, float32, as the reference names them."""
+    f32 = lambda p: jnp.asarray(p.data().data, jnp.float32)
+    return {"embed": f32(lm.embed_weight), "final_norm": f32(lm.final_norm),
+            "head": f32(lm.head_weight),
+            "layers": [{k: f32(v) for k, v in layer.items()}
+                       for layer in lm.layers]}
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return build_lm()
+
+
+@pytest.fixture(scope="module")
+def params(lm):
+    return reference_params(lm)
+
+
+@pytest.fixture(scope="module")
+def eng(lm):
+    return serving.DecodeEndpoint("blocks", lm, max_seq_len=64,
+                                  max_batch_size=4, num_pages=17)
+
+
+def prompt_of(n, seed):
+    return [int(t) for t in
+            onp.random.default_rng(seed).integers(0, MASK, n)]
+
+
+ROWS = 64
+_forward = jax.jit(lambda params, tokens, n: ref.forward(
+    params, tokens, jnp.arange(ROWS),
+    ref.block_mask(jnp.arange(ROWS), jnp.arange(ROWS), L)
+    & (jnp.arange(ROWS) < n)[None, :] | jnp.eye(ROWS, dtype=bool), DIMS))
+
+
+def reference_logits(params, tokens):
+    """The reference's full forward, compiled once: padded to ROWS rows that
+    no row of ``tokens`` sees."""
+    padded = onp.zeros(ROWS, onp.int32)
+    padded[:len(tokens)] = tokens
+    return onp.asarray(_forward(params, padded, len(tokens)))[:len(tokens)]
+
+
+def reference_generate(params, prompt, max_new, steps):
+    return ref.generate(params, prompt, max_new, DIMS, steps,
+                        logits_of=lambda t: reference_logits(params, t))
+
+
+# ---------------------------------------------------------------------------
+# (a) the model's full forward under the block mask
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("length", [4, 19, 32])
+def test_full_forward_matches_the_reference(lm, params, length):
+    toks = onp.asarray([prompt_of(length, s) for s in (1, 2)], onp.int32)
+    out = lm(mx.nd.array(toks, dtype="int32")).asnumpy()
+    for row, got in zip(toks, out):
+        onp.testing.assert_allclose(got, reference_logits(params, row), **TOL)
+
+
+def test_block_length_one_is_the_causal_mask():
+    lm = MoEDecoderLM(num_layers=2, units=64, num_heads=4, num_kv_heads=2,
+                      head_dim=16, expert_hidden=32, num_experts=8,
+                      experts_per_token=2, vocab_size=VOCAB)
+    lm.initialize(mx.init.DeviceNormal(0.05, seed=9))
+    lm.hybridize()
+    toks = onp.asarray([prompt_of(11, 5)], onp.int32)
+    pos = onp.arange(11)
+    want = ref.forward(reference_params(lm), toks[0], pos,
+                       ref.block_mask(pos, pos, 1), DIMS)
+    assert (ref.block_mask(pos, pos, 1) == onp.tril(onp.ones((11, 11)))).all()
+    onp.testing.assert_allclose(
+        lm(mx.nd.array(toks, dtype="int32")).asnumpy()[0], want, **TOL)
+
+
+# ---------------------------------------------------------------------------
+# (b) prefill, then block steps through the endpoint and the paged pool
+# ---------------------------------------------------------------------------
+def step_logits(eng):
+    """The endpoint's traced step, stopped before the arg-max: logits of
+    every row, nothing installed in the pool."""
+    fn = jax.jit(lambda *a: engine_mod._step(
+        eng.block, eng._params, eng.block.num_layers, eng.pool.page_size,
+        *a)[0])
+
+    def run(ids, start, table):
+        pos = start + onp.arange(L, dtype=onp.int32)
+        return onp.asarray(fn(
+            eng._param_datas(), onp.asarray([ids], onp.int32), pos[None],
+            table[None], onp.zeros((1,), bool), eng.pool.k_pool,
+            eng.pool.v_pool))[0]
+    return run
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+@pytest.mark.parametrize("prompt_len", [8, 14])     # whole blocks; a tail of 2
+def test_every_block_step_through_the_cache_matches_the_full_forward(
+        eng, params, steps, prompt_len):
+    prompt, max_new = prompt_of(prompt_len, 7 + steps), 10
+    want_toks, want_steps, _ = reference_generate(params, prompt, max_new,
+                                                  steps)
+    logits_of = step_logits(eng)
+    sid = 100 + 10 * steps + prompt_len
+    eng.pool.reserve(sid, prompt_len + max_new)
+    table = eng.pool.table(sid)
+    try:
+        start = prompt_len // L * L
+        eng.prefill(prompt[:start], table)
+        seq, end, got_toks, got_steps = list(prompt), prompt_len + max_new, \
+            [], []
+        while start < end:
+            block = seq[start:] + [MASK] * (start + L - len(seq))
+            masked = [prompt_len <= start + i < end for i in range(L)]
+            at = [None] * L
+            step = 0
+            while any(masked):
+                want = reference_logits(params, seq[:start] + block)[start:]
+                onp.testing.assert_allclose(
+                    logits_of(block, start, table), want, **TOL)
+                [(ids, conf)] = eng.decode_step(
+                    [(block, start, table, False)])
+                tok, ref_conf = ref.candidates(want, MASK)
+                onp.testing.assert_allclose(conf, ref_conf, rtol=2e-3)
+                assert [int(t) for t in ids] == [int(t) for t in tok]
+                for i in ref.place(conf, masked, L // steps):
+                    block[i], masked[i], at[i] = int(ids[i]), False, step
+                step += 1
+            got_toks += [t for t, a in zip(block, at) if a is not None]
+            got_steps += [a for a in at if a is not None]
+            eng.decode_step([(block, start, table, True)])      # commit
+            seq = seq[:start] + block
+            start += L
+    finally:
+        eng.pool.free(sid)
+    assert (got_toks, got_steps) == (want_toks, want_steps)
+
+
+# ---------------------------------------------------------------------------
+# (c) generation through the server, lanes at different phases
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def served(lm):
+    eng = serving.DecodeEndpoint("blocks_served", lm, max_seq_len=64,
+                                 max_batch_size=4, num_pages=17)
+    server = serving.InferenceServer()
+    server.register_generator(eng)
+    server.start()
+    yield server, eng
+    server.stop(drain=True)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_served_generation_equals_the_reference_rule(served, params, steps):
+    server, eng = served
+    was = eng.stats.snapshot()["counters"]
+    # three lanes whose blocks start and end out of step with each other
+    asks = [(prompt_of(9, 11), 9), (prompt_of(16, 12), 12),
+            (prompt_of(3, 13), 7)]
+    seen = [[] for _ in asks]
+    streams = [server.generate(eng.name, p, max_new_tokens=n,
+                               on_token=seen[i].append,
+                               denoising_steps=steps)
+               for i, (p, n) in enumerate(asks)]
+    answers = [s.result(timeout=120) for s in streams]
+    for (prompt, n), stream, answer, heard in zip(asks, streams, answers,
+                                                  seen):
+        want_toks, want_steps, want_sure = reference_generate(
+            params, prompt, n, steps)
+        assert answer == want_toks and len(answer) == n
+        assert heard == answer                  # in sequence order
+        assert stream.steps == want_steps
+        # with the confidence each was placed with
+        onp.testing.assert_allclose(stream.confidences, want_sure, rtol=2e-3)
+        assert MASK not in answer
+    counters = {k: v - was[k]
+                for k, v in eng.stats.snapshot()["counters"].items()}
+    assert counters["tokens"] == counters["tokens_placed"] == \
+        sum(n for _, n in asks)
+    assert counters["forwards"] == counters["steps"] > counters["commits"]
+    assert counters["rows"] >= L * counters["forwards"]
+    # every block but a sequence's last is committed, by one forward each
+    blocks = sum(-(-(len(p) % L + n) // L) - 1 for p, n in asks)
+    assert counters["blocks_committed"] == blocks
+    assert counters["moe.expert_load_max"] >= counters["moe.expert_load_mean"] > 0
+
+
+def test_block_length_one_is_served_as_the_causal_step():
+    """No mask token, one row a sequence a step: the same endpoint, pool and
+    scheduler, greedy decoding equal to the reference's arg-max continuation
+    (a lead under 1e-3 could go either way: none here)."""
+    lm = MoEDecoderLM(num_layers=2, units=64, num_heads=4, num_kv_heads=2,
+                      head_dim=16, expert_hidden=32, num_experts=8,
+                      experts_per_token=2, vocab_size=VOCAB)
+    lm.initialize(mx.init.DeviceNormal(0.05, seed=9,
+                                       scales={"head_weight": 10}))
+    lm.hybridize()
+    eng = serving.DecodeEndpoint("causal_moe", lm, max_seq_len=32,
+                                 max_batch_size=2, num_pages=5)
+    assert (eng.block_length, eng.mask_token_id) == (1, None)
+    server = serving.InferenceServer()
+    server.register_generator(eng)
+    server.start()
+    try:
+        prompt = prompt_of(7, 41)
+        stream = server.generate(eng.name, prompt, max_new_tokens=6)
+        answer = stream.result(timeout=120)
+    finally:
+        server.stop(drain=True)
+    params, seq = reference_params(lm), list(prompt)
+    for tok in answer:
+        pos = onp.arange(len(seq))
+        logits = onp.asarray(ref.forward(
+            params, onp.asarray(seq, onp.int32), pos,
+            ref.block_mask(pos, pos, 1), DIMS))[-1]
+        assert logits[tok] > onp.sort(logits)[-2] - 1e-3
+        assert logits[tok] >= logits.max() - 1e-3
+        seq.append(tok)
+    assert len(answer) == 6 and stream.steps == [0] * 6
+    assert stream.confidences == []      # placed by no confidence
+    counters = eng.stats.snapshot()["counters"]
+    # the first token comes from the prefill; every step commits its row
+    assert counters["forwards"] == counters["commits"] == 5
+    assert counters["rows"] == counters["blocks_committed"] == 5
+
+
+def test_denoising_steps_must_divide_the_block(eng):
+    sched = serving.generate.DecodeScheduler(eng)
+    with pytest.raises(mx.MXNetError, match="must divide"):
+        sched.submit([1, 2, 3], max_new_tokens=4, denoising_steps=3)
+
+
+def test_a_causal_endpoint_refuses_denoising_steps():
+    from mxnet_tpu.gluon.model_zoo.bert import TransformerLM
+    lm = TransformerLM(num_layers=1, units=16, hidden_size=32, num_heads=2,
+                       vocab_size=32, max_length=32)
+    lm.initialize()
+    eng = serving.DecodeEndpoint("causal", lm, max_seq_len=32,
+                                 max_batch_size=2, num_pages=5)
+    assert (eng.block_length, eng.mask_token_id) == (1, None)
+    assert eng.pool.kv_dim == 16
+    with pytest.raises(mx.MXNetError, match="must divide"):
+        serving.generate.DecodeScheduler(eng).submit(
+            [1, 2], max_new_tokens=2, denoising_steps=2)
+
+
+# ---------------------------------------------------------------------------
+# (d) the expert layer
+# ---------------------------------------------------------------------------
+def layer_weights(seed, experts=8, hidden=64, width=32):
+    rng = onp.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(0, 0.1, shape), jnp.float32)
+    return {"router": draw(hidden, experts),
+            "w_gate": draw(experts, hidden, width),
+            "w_up": draw(experts, hidden, width),
+            "w_down": draw(experts, width, hidden)}
+
+
+def test_no_row_is_dropped_when_all_rows_choose_one_expert():
+    p = layer_weights(1)
+    x = jnp.abs(jnp.asarray(onp.random.default_rng(2).normal(0, 1, (24, 64)),
+                            jnp.float32))
+    # positive rows and one router column far above the rest: every row's
+    # first choice is expert 5
+    p["router"] = p["router"].at[:, 5].set(1.0)
+    out, load = ops.moe_ffn(x, p["router"], p["w_gate"], p["w_up"],
+                            p["w_down"], top_k=2)
+    assert int(load[5]) == 24 and int(load.sum()) == 48
+    onp.testing.assert_allclose(out, ref.experts(x, p, DIMS), **TOL)
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_the_shares_of_the_experts_add_up_to_the_whole_layer(shares):
+    p = layer_weights(3)
+    x = jnp.asarray(onp.random.default_rng(4).normal(0, 1, (19, 64)),
+                    jnp.float32)
+    whole = ref.experts(x, p, DIMS)
+    held = 8 // shares
+    total, rows = 0.0, 0
+    for first in range(0, 8, held):
+        cut = slice(first, first + held)
+        part, load = ops.moe_ffn(
+            x, p["router"], p["w_gate"][cut], p["w_up"][cut],
+            p["w_down"][cut], top_k=2, first_expert=first)
+        share = {**p, **{k: p[k][cut] for k in ("w_gate", "w_up", "w_down")}}
+        onp.testing.assert_allclose(
+            part, ref.experts(x, share, DIMS, held=(first, held)), **TOL)
+        total, rows = total + part, rows + int(load.sum())
+    assert rows == 19 * 2                   # every (row, expert) pair, once
+    onp.testing.assert_allclose(total, whole, **TOL)
+
+
+def test_a_model_that_holds_a_share_of_the_experts(params):
+    """The model is told which experts it holds: two halves' layers differ
+    from the whole model's by exactly the other half's part."""
+    whole = build_lm()
+    x = onp.asarray([prompt_of(8, 21)], onp.int32)
+    halves = []
+    for first in (0, 4):
+        half = build_lm(held_experts=(first, 4))
+        for (name, p), src in zip(half.collect_params().items(),
+                                  whole.collect_params().values()):
+            src = src.data().data
+            p.set_data(mx.nd.array(
+                src[first:first + 4] if "experts" in name else src))
+        halves.append(half)
+    pos = onp.arange(8)
+    mask = ref.block_mask(pos, pos, L)
+    for first, half in zip((0, 4), halves):
+        p = reference_params(half)
+        want = ref.forward(p, x[0], pos, mask, DIMS, held=(first, 4))
+        onp.testing.assert_allclose(
+            half(mx.nd.array(x, dtype="int32")).asnumpy()[0], want, **TOL)
+
+
+# ---------------------------------------------------------------------------
+# (e) the pool: a denoising step writes nothing, a commit only its block
+# ---------------------------------------------------------------------------
+def test_a_denoising_step_leaves_the_pool_and_a_commit_writes_its_block(eng):
+    sid, prompt = 900, prompt_of(8, 31)
+    eng.pool.reserve(sid, 16)
+    table = eng.pool.table(sid)
+    try:
+        eng.prefill(prompt, table)
+        pools = lambda: (onp.asarray(eng.pool.k_pool),
+                         onp.asarray(eng.pool.v_pool))
+        before = pools()
+        block = [MASK] * L
+        eng.decode_step([(block, 8, table, False)])
+        for was, now in zip(before, pools()):
+            # page 0 is the scratch page the dropped rows are routed to
+            assert onp.array_equal(was[:, 1:], now[:, 1:])
+        eng.decode_step([([5, 6, 7, 8], 8, table, True)])
+        page, slot = int(table[8 // eng.pool.page_size]), 8 % eng.pool.page_size
+        for was, now in zip(before, pools()):
+            changed = onp.argwhere((was != now).any(-1))
+            assert {tuple(c[1:]) for c in changed if c[1] != 0} == \
+                {(page, slot + i) for i in range(L)}
+            assert len({c[0] for c in changed}) == eng.block.num_layers
+    finally:
+        eng.pool.free(sid)
+
+
+def test_the_pool_takes_its_row_and_dtype_from_the_model(eng):
+    assert eng.pool.kv_dim == 2 * 16 != eng.block.units
+    assert eng.pool.k_pool.dtype == jnp.float32
+    low = serving.DecodeEndpoint("blocks_bf16", build_lm(dtype="bfloat16"),
+                                 max_seq_len=32, max_batch_size=2,
+                                 num_pages=9)
+    assert low.pool.k_pool.dtype == jnp.bfloat16
+    assert low.pool.k_pool.shape == (2, 9, low.pool.page_size, 32)
+    [(ids, conf)] = low.decode_step([([MASK] * L, 0, low.pool.table(1),
+                                      False)])
+    assert conf.dtype == onp.float32 and ids.shape == (L,)
+    assert MASK not in ids
+
+
+def test_a_block_must_lie_in_one_page(lm):
+    with pytest.raises(mx.MXNetError, match="must divide"):
+        serving.DecodeEndpoint("odd", lm, max_seq_len=62, max_batch_size=2,
+                               num_pages=9)

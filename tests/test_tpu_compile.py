@@ -174,3 +174,27 @@ def test_pool_write_is_in_place(one_chip, program, other_temp):
     assert makers and "copy" not in makers, makers
     temp = comp.memory_analysis().temp_size_in_bytes
     assert temp < other_temp + (64 << 20), temp
+
+
+# the routed experts of the sdar_30b_a3b cell at its published widths: rows
+# of one lane's block, of the full step (64 lanes x 4) and of an S=1,024
+# prefill; 128 experts of 2048 x 768, 8 a row
+@pytest.mark.parametrize("rows", [4, 256, 1024])
+def test_moe_ffn_compiles_to_three_grouped_matmul_kernels(one_chip, rows,
+                                                          monkeypatch):
+    from mxnet_tpu.ops import nn as ops
+    # the op picks the Pallas kernel from the backend at trace time; here
+    # the backend is the CPU and the chip is only described
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    E, H, F = 128, 2048, 768
+    text = _compiled_text(
+        lambda x, r, g, u, d: ops.moe_ffn(x, r, g, u, d, top_k=8),
+        ((rows, H), jnp.bfloat16), ((H, E), jnp.bfloat16),
+        ((E, H, F), jnp.bfloat16), ((E, H, F), jnp.bfloat16),
+        ((E, F, H), jnp.bfloat16), sharding=one_chip)
+    kernels = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call", text)
+    grouped = [k for k in kernels if k.startswith("f32[")]
+    assert len(grouped) == 3
+    pairs = rows * 8
+    assert sorted(k.split("{")[0] for k in grouped) == sorted(
+        [f"f32[{pairs},{F}]"] * 2 + [f"f32[{pairs},{H}]"])
