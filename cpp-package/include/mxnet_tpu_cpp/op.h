@@ -2745,15 +2745,16 @@ inline Symbol bipartite_matching(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
-// block_attention: positional inputs (q, k, v, positions, k_ctx, v_ctx)
+// block_attention: positional inputs (q, k, v, positions, ctx_acc, ctx_max, ctx_sum)
 inline Symbol block_attention(
     const std::string& name,
     Symbol q,
     Symbol k,
     Symbol v,
     Symbol positions,
-    Symbol k_ctx = Symbol(),
-    Symbol v_ctx = Symbol(),
+    Symbol ctx_acc = Symbol(),
+    Symbol ctx_max = Symbol(),
+    Symbol ctx_sum = Symbol(),
     const std::string& heads = "null" /* raw JSON */,
     const std::string& kv_heads = "null" /* raw JSON */,
     int64_t block_length = 1,
@@ -2762,7 +2763,7 @@ inline Symbol block_attention(
   detail::Attr(&j, "heads", detail::Raw{heads});
   detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
   detail::Attr(&j, "block_length", static_cast<int64_t>(block_length));
-  std::vector<Symbol> ins{q, k, v, positions, k_ctx, v_ctx};
+  std::vector<Symbol> ins{q, k, v, positions, ctx_acc, ctx_max, ctx_sum};
   return Symbol::Create("block_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
 }
@@ -4982,6 +4983,30 @@ inline Symbol pad(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
+// paged_attention: positional inputs (q, k_pool, v_pool, tables, lengths, layer)
+inline Symbol paged_attention(
+    const std::string& name,
+    Symbol q,
+    Symbol k_pool,
+    Symbol v_pool,
+    Symbol tables,
+    Symbol lengths,
+    Symbol layer,
+    const std::string& heads = "null" /* raw JSON */,
+    const std::string& kv_heads = "null" /* raw JSON */,
+    const std::string& sm_scale = "null" /* raw JSON */,
+    const std::string& interpret = "null" /* raw JSON */,
+    const std::string& extra_attrs_json = "") {
+  std::string j;
+  detail::Attr(&j, "heads", detail::Raw{heads});
+  detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
+  detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
+  detail::Attr(&j, "interpret", detail::Raw{interpret});
+  std::vector<Symbol> ins{q, k_pool, v_pool, tables, lengths, layer};
+  return Symbol::Create("paged_attention", name, detail::Inputs(std::move(ins)),
+                        detail::Finish(std::move(j), extra_attrs_json));
+}
+
 // pick: positional inputs (x, indices)
 inline Symbol pick(
     const std::string& name,
@@ -5547,26 +5572,6 @@ inline Symbol sin(
   std::string j;
   std::vector<Symbol> ins{x};
   return Symbol::Create("sin", name, detail::Inputs(std::move(ins)),
-                        detail::Finish(std::move(j), extra_attrs_json));
-}
-
-// single_query_attention: positional inputs (q, k_ctx, v_ctx, k_new, v_new, lengths)
-inline Symbol single_query_attention(
-    const std::string& name,
-    Symbol q,
-    Symbol k_ctx,
-    Symbol v_ctx,
-    Symbol k_new,
-    Symbol v_new,
-    Symbol lengths,
-    int64_t heads = 1,
-    const std::string& sm_scale = "null" /* raw JSON */,
-    const std::string& extra_attrs_json = "") {
-  std::string j;
-  detail::Attr(&j, "heads", static_cast<int64_t>(heads));
-  detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
-  std::vector<Symbol> ins{q, k_ctx, v_ctx, k_new, v_new, lengths};
-  return Symbol::Create("single_query_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 

@@ -22,10 +22,10 @@ TARGET_DTYPE_OPS = [
     "_contrib_interleaved_matmul_selfatt_valatt",
     "_contrib_interleaved_matmul_encdec_qk",
     "_contrib_interleaved_matmul_encdec_valatt", "multi_head_attention",
-    "flash_attention", "single_query_attention", "Embedding",
+    "flash_attention", "Embedding",
     "_contrib_SparseEmbedding",
     # their softmax, router and accumulation are float32 inside
-    "block_attention", "moe_ffn",
+    "block_attention", "paged_attention", "moe_ffn",
 ]
 
 # numerically sensitive ops pinned to fp32

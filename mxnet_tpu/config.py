@@ -343,8 +343,9 @@ register("MXNET_FLIGHT_DIR", "", str,
          "recording but disables automatic bundle dumps; explicit "
          "flight.dump() still works. Also arms the unhandled-exception "
          "crash hooks at import when set.")
-register("MXNET_FLIGHT_SPANS", 8192, int,
-         "FlightRecorder: capacity of the finished-span ring buffer.")
+register("MXNET_FLIGHT_SPANS", 32768, int,
+         "FlightRecorder: capacity of the finished-span ring buffer (half a "
+         "minute of a decode loop at 15 ms a pass and 14 spans a pass).")
 register("MXNET_FLIGHT_EVENTS", 256, int,
          "FlightRecorder: capacity of the structured-event ring buffer "
          "(telemetry.event: breaker transitions, retries, failovers, "

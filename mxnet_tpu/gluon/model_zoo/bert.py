@@ -35,7 +35,8 @@ class SelfAttention(HybridBlock):
     then offers the two incremental-decode views the generative-serving
     engine compiles: ``forward_collect`` (prefill: full causal pass that also
     returns the per-position K/V for the cache) and ``attend_step`` (one
-    token against cached context via single_query_attention)."""
+    token against its cached context, read in the paged KV pool by
+    ``paged_attention``)."""
 
     def __init__(self, units, num_heads, dropout=0.0, causal=False, **kwargs):
         super().__init__(**kwargs)
@@ -69,16 +70,23 @@ class SelfAttention(HybridBlock):
                                      causal=self._causal)
         return self.drop(self.proj(out)), k, v
 
-    def attend_step(self, x, k_ctx, v_ctx, lengths):
+    def attend_step(self, x, lengths, k_pool, v_pool, tables, layer):
         """One decode step: ``x`` (B, H*D) is the current token's hidden
-        state, ``k_ctx``/``v_ctx`` (B, L, H*D) the cached context, and
-        ``lengths`` (B,) the number of cached positions per row (== the
-        current token's position). Returns (out, k_new, v_new) so the caller
-        can append this step's K/V to the cache."""
+        state, ``lengths`` (B,) the number of cached positions per row (==
+        the current token's position), ``k_pool``/``v_pool`` the whole paged
+        pools, of which this block reads ``layer`` through the rows' page
+        ``tables`` (B, P). The token attends to its cached context in place
+        (``paged_attention``) and to itself, the two parts merged under one
+        softmax. Returns (out, k_new, v_new) so the caller can append this
+        step's K/V to the cache."""
         F = _F()
         q, k, v = self._project(F, x)
-        out = F.single_query_attention(q, k_ctx, v_ctx, k, v, lengths,
-                                       heads=self._heads)
+        row = q.expand_dims(1)          # a block of one row a lane
+        ctx = F.paged_attention(row, k_pool, v_pool, tables, lengths, layer,
+                                heads=self._heads)
+        out = F.block_attention(
+            row, k.expand_dims(1), v.expand_dims(1), lengths.expand_dims(1),
+            *ctx, heads=self._heads, kv_heads=self._heads).reshape(x.shape)
         return self.drop(self.proj(out)), k, v
 
 
@@ -134,12 +142,13 @@ class TransformerEncoderLayer(HybridBlock):
         x = self.ln2(x + self.ffn(x))
         return x, k, v
 
-    def decode_step(self, x, k_ctx, v_ctx, lengths):
+    def decode_step(self, x, lengths, k_pool, v_pool, tables, layer):
         """Incremental view: one token (B, H*D) against cached context.
         Residual + post-LN structure is identical to ``forward`` — every op
         is per-row, which is what keeps batched decode bitwise equal to
         serial decode (see serving/generate/)."""
-        a, k, v = self.attention.attend_step(x, k_ctx, v_ctx, lengths)
+        a, k, v = self.attention.attend_step(x, lengths, k_pool, v_pool,
+                                             tables, layer)
         x = self.ln1(x + a)
         x = self.ln2(x + self.ffn(x))
         return x, k, v
@@ -275,10 +284,12 @@ class TransformerLM(HybridBlock):
     - ``prefill_collect(tokens)``: full causal pass that also returns every
       layer's (B, S, H*D) K/V — compiled per sequence-length bucket as the
       prefill executable.
-    - ``decode_step(ids, positions, *kv_ctx)``: one token per row against
-      cached context — compiled per batch bucket as the decode-step
-      executable. ``positions`` (B,) is both the position-embedding index
-      and the cached length (token t has t predecessors).
+    - ``decode_step(ids, positions, k_pool, v_pool, tables)``: one token per
+      row against its cached context, which every layer reads in the paged
+      KV pools through the rows' page tables — compiled per batch bucket as
+      the decode-step executable. ``positions`` (B,) is both the
+      position-embedding index and the cached length (token t has t
+      predecessors).
 
     Both incremental entry points are traced through ``pure_apply(...,
     method=...)`` by serving/generate/engine.py.
@@ -334,19 +345,19 @@ class TransformerLM(HybridBlock):
             .reshape(h.shape[0], h.shape[1], self.vocab_size)
         return (logits,) + tuple(kvs)
 
-    def decode_step(self, ids, positions, *kv_ctx):
-        """One decode step. ``ids``/``positions`` (B,) int32; ``kv_ctx`` is
-        ``(k_ctx_0, v_ctx_0, ...)`` per layer, each (B, L, H*D) gathered from
-        the KV pool. Returns (logits (B, V), k_new_0, v_new_0, ...) with
-        each new k/v (B, H*D) for the caller to scatter back into the
-        pool."""
+    def decode_step(self, ids, positions, k_pool, v_pool, tables):
+        """One decode step. ``ids``/``positions`` (B,) int32; ``k_pool``/
+        ``v_pool`` the whole paged pools (layers, pages, page_size, H*D) and
+        ``tables`` (B, P) int32 each row's pages. Returns (logits (B, V),
+        k_new_0, v_new_0, ...) with each new k/v (B, H*D) for the caller to
+        write into the pool: no layer reads this step's rows there."""
         F = _F()
         h = self.word_embed(ids) + self.position_embed(positions)
         h = self.embed_drop(self.embed_ln(h))
         kvs = []
         for i, layer in enumerate(self.encoder._layers):
-            h, k, v = layer.decode_step(h, kv_ctx[2 * i], kv_ctx[2 * i + 1],
-                                        positions)
+            h, k, v = layer.decode_step(h, positions, k_pool, v_pool, tables,
+                                        i)
             kvs.extend((k, v))
         embed_w = self._embed_w(h)
         logits = F.dot(h, embed_w.T)

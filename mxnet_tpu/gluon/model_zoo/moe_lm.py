@@ -19,12 +19,15 @@ to ``block_length`` rows a sequence:
 - ``forward(tokens)``: (B, S) -> (B, S, V) float32 logits.
 - ``prefill_collect(tokens)``: the same pass, returning every layer's
   (B, S, kv_units) keys and values for the cache.
-- ``decode_step(ids, positions, *kv_ctx)``: ``ids``/``positions`` (B, L), one
-  block per sequence against its cached context ((B,) for the causal step of
-  ``block_length`` 1, whose outputs then lack the L axis too); returns (logits (B, L, V),
-  k_0, v_0, ..., the rows routed to each expert (layers, E_held)). The
-  block's keys and values come back for the caller to write, or not: a
-  denoising step of block diffusion saw mask tokens and its keys are dropped.
+- ``decode_step(ids, positions, k_pool, v_pool, tables)``: ``ids``/
+  ``positions`` (B, L), one block per sequence against its cached context,
+  which every layer reads in the paged KV pools through the sequences' page
+  tables up to the block's first position (``ops/pallas/paged_attention``;
+  (B,) for the causal step of ``block_length`` 1, whose outputs then lack the
+  L axis too); returns (logits (B, L, V), k_0, v_0, ..., the rows routed to
+  each expert (layers, E_held)). The block's keys and values come back for
+  the caller to write, or not: a denoising step of block diffusion saw mask
+  tokens and its keys are dropped.
 
 What the endpoint learns from the block: ``kv_units`` (the pool's row),
 ``block_length``, ``mask_token_id`` (None: causal, one token a step).
@@ -96,9 +99,12 @@ class MoEDecoderLM(HybridBlock):
             self.head_weight = get("head_weight", (H, vocab_size))
 
     # ------------------------------------------------------------------
-    def _layer(self, i, x, positions, k_ctx=None, v_ctx=None):
-        """One block over x (B, S, H): (y, k, v, rows per held expert)."""
+    def _layer(self, i, x, positions, cache=None):
+        """One block over x (B, S, H): (y, k, v, rows per held expert).
+        ``cache`` = (k_pool, v_pool, tables): the rows also attend to their
+        sequence's cached positions before ``positions[:, 0]``."""
         from ...ops import nn as ops
+        from ...ops.pallas.paged_attention import paged_attention
         p = {name: w.data().data for name, w in self.layers[i].items()}
         B, S, H = x.shape
         h = ops.rms_norm(x, p["ln1"], eps=self.rms_eps)
@@ -111,10 +117,13 @@ class MoEDecoderLM(HybridBlock):
         k = ops.rotary_embedding(k, positions, theta=self.rope_theta)
         k = k.reshape(B, S, self.kv_units)
         v = h @ p["wv"]
+        q = q.reshape(B, S, -1)
+        ctx = () if cache is None else paged_attention(
+            q, *cache, positions[:, 0], i, heads=self.num_heads,
+            kv_heads=self.num_kv_heads)
         att = ops.block_attention(
-            q.reshape(B, S, -1), k, v, positions, k_ctx, v_ctx,
-            heads=self.num_heads, kv_heads=self.num_kv_heads,
-            block_length=self.block_length)
+            q, k, v, positions, *ctx, heads=self.num_heads,
+            kv_heads=self.num_kv_heads, block_length=self.block_length)
         x = x + att @ p["wo"]
         h = ops.rms_norm(x, p["ln2"], eps=self.rms_eps)
         y, load = ops.moe_ffn(
@@ -123,15 +132,14 @@ class MoEDecoderLM(HybridBlock):
             norm_topk=self.norm_topk, first_expert=self.held_experts[0])
         return x + y.reshape(B, S, H), k, v, load
 
-    def _run(self, ids, positions, kv_ctx=()):
+    def _run(self, ids, positions, cache=None):
         """(logits (B, S, V) float32, [k, v per layer], loads (layers, E))."""
         import jax.numpy as jnp
         from ...ops import nn as ops
         x = self.embed_weight.data().data[ids]
         kvs, loads = [], []
         for i in range(self.num_layers):
-            ctx = kv_ctx[2 * i:2 * i + 2]
-            x, k, v, load = self._layer(i, x, positions, *ctx)
+            x, k, v, load = self._layer(i, x, positions, cache)
             kvs += [k, v]
             loads.append(load)
         x = ops.rms_norm(x, self.final_norm.data().data, eps=self.rms_eps)
@@ -160,14 +168,15 @@ class MoEDecoderLM(HybridBlock):
         logits, kvs, _ = self._whole(tokens)
         return (logits,) + tuple(kvs)
 
-    def decode_step(self, ids, positions, *kv_ctx):
+    def decode_step(self, ids, positions, k_pool, v_pool, tables):
         import jax.numpy as jnp
-        ids, positions, *kv_ctx = self._raw(ids, positions, *kv_ctx)
+        ids, positions, *cache = self._raw(ids, positions, k_pool, v_pool,
+                                           tables)
         causal = ids.ndim == 1      # one row a sequence, as TransformerLM's
         if causal:
             ids, positions = ids[:, None], positions[:, None]
         logits, kvs, loads = self._run(ids.astype(jnp.int32),
-                                       positions.astype(jnp.int32), kv_ctx)
+                                       positions.astype(jnp.int32), cache)
         if causal:
             logits, kvs = logits[:, 0], [a[:, 0] for a in kvs]
         return (logits,) + tuple(kvs) + (loads,)

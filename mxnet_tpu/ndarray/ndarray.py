@@ -665,6 +665,13 @@ class NDArray:
 
 
 def _single_device_of(arr):
+    import jax
+    if isinstance(arr, jax.core.Tracer):
+        # a traced value lies on no device. Asking raises, and the error
+        # composes a message that walks the whole trace so far: every op of
+        # a traced model wraps its outputs here, and that walk was half of
+        # a decode step's tracing time
+        return None
     try:
         devs = arr.devices()
         if len(devs) == 1:

@@ -11,7 +11,6 @@ is a lax.scan (compiled once, no per-step dispatch — the cuDNN-fused-RNN analo
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -763,8 +762,8 @@ def rotary_embedding(x, positions, *, theta=10000.0):
 
 
 @register("block_attention", jit=True)
-def block_attention(q, k, v, positions, k_ctx=None, v_ctx=None, *, heads,
-                    kv_heads, block_length=1):
+def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
+                    ctx_sum=None, *, heads, kv_heads, block_length=1):
     """Attention of rows to their own blocks and to everything before them.
 
     ``q`` (B, S, heads*D), ``k``/``v`` (B, S, kv_heads*D): the rows' own
@@ -772,10 +771,14 @@ def block_attention(q, k, v, positions, k_ctx=None, v_ctx=None, *, heads,
     head ``h // (heads / kv_heads)``. A row at position i sees a row at
     position j iff ``j // block_length <= i // block_length``: both
     directions inside a block, causal between blocks (``block_length`` 1 is
-    the causal mask). ``k_ctx``/``v_ctx`` (B, C, kv_heads*D), if given, are a
-    cache whose lane j holds position j; a row sees the lanes of blocks
-    strictly before its own (its own block's rows are in ``k``/``v``), so
-    whatever lies in later lanes is never read unmasked. Scores, the softmax
+    the causal mask).
+
+    ``ctx_acc`` (B, S, heads, D), ``ctx_max``, ``ctx_sum`` (B, S, heads), if
+    given, are a second part every row also attends to, already reduced to
+    its unnormalised output, the maximum of its scores and the sum of their
+    exponentials under that maximum, all float32: a decode step's cached
+    context, as ``ops/pallas/paged_attention.py`` returns it. The two parts
+    are merged flash-style under their common maximum. Scores, the softmax
     over both parts and the accumulation are float32; the probabilities are
     cast to ``q``'s dtype for the product with the values."""
     B, S, _ = q.shape
@@ -783,26 +786,24 @@ def block_attention(q, k, v, positions, k_ctx=None, v_ctx=None, *, heads,
     D = q.shape[-1] // heads
     qh = q.reshape(B, S, kv_heads, G, D)
     blk = positions // block_length                        # (B, S)
-    parts = [(k, v, blk[:, None, :] <= blk[:, :, None])]   # (B, q, k)
-    if k_ctx is not None:
-        lane = jnp.arange(k_ctx.shape[1], dtype=positions.dtype)
-        parts.append((k_ctx, v_ctx,
-                      lane[None, None, :] // block_length < blk[:, :, None]))
-    scores = []
-    for kk, _, mask in parts:
-        kh = kk.reshape(B, -1, kv_heads, D)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qh, kh,
-                       preferred_element_type=jnp.float32) / math.sqrt(D)
-        scores.append(jnp.where(mask[:, None, None], s, _MASKED))
-    top = functools.reduce(jnp.maximum, [s.max(-1) for s in scores])
-    out, denom = 0.0, 0.0
-    for s, (_, vv, _) in zip(scores, parts):
-        e = jnp.exp(s - top[..., None])
-        denom = denom + e.sum(-1)
-        vh = vv.reshape(B, -1, kv_heads, D)
-        out = out + jnp.einsum("bhgqk,bkhd->bqhgd", e.astype(q.dtype), vh,
-                               preferred_element_type=jnp.float32)
-    out = out / denom.transpose(0, 3, 1, 2)[..., None]
+    mask = blk[:, None, :] <= blk[:, :, None]              # (B, q, k)
+    s = jnp.einsum("bqhgd,bkhd->bqhgk", qh, k.reshape(B, S, kv_heads, D),
+                   preferred_element_type=jnp.float32) / math.sqrt(D)
+    s = jnp.where(mask[:, :, None, None], s, _MASKED)
+    top = s.max(-1)                                        # (B, q, h, g)
+    if ctx_acc is not None:
+        ctx_max = ctx_max.reshape(top.shape)
+        top = jnp.maximum(top, ctx_max)
+    e = jnp.exp(s - top[..., None])
+    denom = e.sum(-1)
+    out = jnp.einsum("bqhgk,bkhd->bqhgd", e.astype(q.dtype),
+                     v.reshape(B, S, kv_heads, D),
+                     preferred_element_type=jnp.float32)
+    if ctx_acc is not None:
+        w = jnp.exp(ctx_max - top)
+        denom = denom + ctx_sum.reshape(top.shape) * w
+        out = out + ctx_acc.reshape(out.shape) * w[..., None]
+    out = out / denom[..., None]
     return out.reshape(B, S, heads * D).astype(q.dtype)
 
 

@@ -6,3 +6,4 @@ XLA-composite fallback for CPU/interpret execution so the test suite runs on
 the virtual CPU mesh.
 """
 from . import flash_attention  # noqa: F401
+from . import paged_attention  # noqa: F401

@@ -574,53 +574,6 @@ def _dense_attention(q, k, v, sm_scale, causal):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-@register("single_query_attention", jit=True)
-def single_query_attention(q, k_ctx, v_ctx, k_new, v_new, lengths, *,
-                           heads=1, sm_scale=None):
-    """One autoregressive decode step of attention against a KV cache.
-
-    The single-query specialization of the bottom-right causal convention
-    documented on :func:`_dense_attention`: with exactly one query row — the
-    LAST position of the sequence — causality degenerates to a per-row
-    length mask, so no (S x S) mask is materialized at all.
-
-    ``q``/``k_new``/``v_new`` are the current step's projections, shape
-    ``(B, heads*D)``; ``k_ctx``/``v_ctx`` are the cached context gathered
-    from the KV pool, shape ``(B, L, heads*D)`` where lane ``j`` holds
-    position ``j`` (lanes at and beyond the sequence length hold stale pool
-    contents). The new key/value pair is inserted at lane ``lengths[b]`` and
-    lanes ``> lengths[b]`` are masked with ``_NEG_INF``, which underflows to
-    an exactly-zero softmax weight in f32 — stale lane contents therefore
-    never perturb real rows, the property the batched-vs-serial bitwise
-    decode oracle rests on. Numerics mirror ``_dense_attention`` exactly:
-    f32 score einsum, ``jax.nn.softmax``, f32-accumulated output einsum,
-    cast back to the input dtype."""
-    B, units = q.shape
-    L = k_ctx.shape[1]
-    heads = int(heads)
-    D = units // heads
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(D)
-    lengths = lengths.astype(jnp.int32)
-    lane = jnp.arange(L, dtype=jnp.int32)
-    sel = (lane[None, :] == lengths[:, None])[..., None]       # (B, L, 1)
-    k = jnp.where(sel, k_new[:, None, :], k_ctx)
-    v = jnp.where(sel, v_new[:, None, :], v_ctx)
-    # (B, U) -> (B, H, 1, D) / (B, L, U) -> (B, H, L, D), the
-    # multi_head_attention layout
-    qh = q.reshape(B, 1, heads, D).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, L, heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, L, heads, D).transpose(0, 2, 1, 3)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                   preferred_element_type=jnp.float32) * float(sm_scale)
-    valid = lane[None, :] <= lengths[:, None]                  # (B, L)
-    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, vh,
-                     preferred_element_type=jnp.float32).astype(q.dtype)
-    return out.transpose(0, 2, 1, 3).reshape(B, units)
-
-
 @register("flash_attention", jit=True)
 def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     block_q=None, block_k=None, interpret=None):

@@ -9,8 +9,9 @@ Four pieces (one module each):
 
 - :class:`PagedKVPool` (kv_cache.py): preallocated on-device K/V block
   pools with per-sequence page tables. Page 0 is a scratch page for masked
-  writes/gathers; pools ride as executable *arguments*, so the compiled
-  programs are independent of pool contents.
+  writes; pools ride as executable *arguments*, so the compiled programs
+  are independent of pool contents, and a decode step attends to them in
+  place (``ops/pallas/paged_attention``).
 - :class:`DecodeEndpoint` (engine.py): one generative model (the
   ``TransformerLM`` incremental-decode protocol) with two AOT executable
   families per bucket — prefill (by sequence length, ``seq_buckets``) and
@@ -27,8 +28,8 @@ Four pieces (one module each):
 Numerics contract (tier-1 tested): batched continuous decode is BITWISE
 equal to one-sequence-at-a-time greedy decode — including sequences joining
 and retiring mid-batch and KV pages being freed and reallocated between
-sequences. Every model op is per-row; masked attention lanes carry exactly
-zero softmax weight (``_NEG_INF`` underflow), so stale page contents, batch
+sequences. Every model op is per-row; positions past a row's length are not
+read or carry exactly zero softmax weight, so stale page contents, batch
 composition, bucket padding and physical page placement are all invisible
 to a row's output.
 
@@ -46,12 +47,11 @@ Or through the server facade: ``server.register_generator(eng)`` then
 from __future__ import annotations
 
 from .engine import DecodeEndpoint
-from .kv_cache import PagedKVPool, gather_ctx, write_prefill, write_step
+from .kv_cache import PagedKVPool, write_prefill, write_step
 from .scheduler import DecodeScheduler
 from .stats import DecodeStats
 from .streams import TokenStream
 from ..errors import KVPoolExhausted
 
 __all__ = ["DecodeEndpoint", "DecodeScheduler", "TokenStream", "PagedKVPool",
-           "DecodeStats", "KVPoolExhausted", "gather_ctx", "write_prefill",
-           "write_step"]
+           "DecodeStats", "KVPoolExhausted", "write_prefill", "write_step"]

@@ -13,9 +13,14 @@ accounting covers decode traffic:
   traced via ``pure_apply(..., method=...)``), writing every layer's K/V
   into the sequence's pages and returning the first generated token.
 - **decode-step**, bucketed by batch size (pow2 ladder): one token for every
-  running sequence — gather each row's cached context through its page
-  table, run ``TransformerLM.decode_step`` (single_query_attention inside),
-  write the new K/V row, greedy-argmax the next token on device.
+  running sequence — run ``TransformerLM.decode_step`` on the pools and the
+  rows' page tables, write the new K/V row, greedy-argmax the next token on
+  device. Every layer attends to its rows' cached context where it lies:
+  ``ops/pallas/paged_attention`` reads each lane's live pages once, in the
+  pool, up to the lane's own length, and the step's own rows, which are
+  written only after the last layer, are a second, dense part of the same
+  softmax. Nothing of the pool's or of all lanes' size is gathered, copied
+  or rewritten in a step.
 
 A block may state ``block_length`` L > 1 and a ``mask_token_id`` (generation
 by diffusion over blocks, ``gluon.model_zoo.moe_lm``): the step is then L
@@ -46,7 +51,7 @@ from ... import telemetry as _telemetry
 from ...base import Context, MXNetError, current_context
 from .. import bucketing
 from ..router import StepCostEWMA
-from .kv_cache import PagedKVPool, gather_ctx, write_prefill, write_step
+from .kv_cache import PagedKVPool, write_prefill, write_step
 from .stats import DecodeStats
 
 __all__ = ["DecodeEndpoint"]
@@ -58,19 +63,15 @@ def _now_us() -> int:
 
 def _step(block, plist, num_layers, page_size, param_datas, ids, positions,
           tables, valid, k_pool, v_pool):
-    """One traced decode step: gather every lane's cached context, run the
-    block's ``decode_step`` on its L rows a lane (``ids``/``positions`` (B,)
-    for L = 1, else (B, L)), write the rows' K/V in place for the lanes
-    ``valid`` flags. Returns (logits, whatever the block returned after its
-    K/V, k_pool, v_pool)."""
+    """One traced decode step: run the block's ``decode_step`` on its L rows
+    a lane (``ids``/``positions`` (B,) for L = 1, else (B, L)) against the
+    pools as they stand, read through ``tables``; then write the rows' K/V
+    in place for the lanes ``valid`` flags. Returns (logits, whatever the
+    block returned after its K/V, k_pool, v_pool)."""
     import jax.numpy as jnp
     from ...gluon.block import pure_apply
-    gk = gather_ctx(k_pool, tables)        # (layers, B, ctx, kv)
-    gv = gather_ctx(v_pool, tables)
-    inputs = (ids, positions)
-    for i in range(num_layers):
-        inputs = inputs + (gk[i], gv[i])
-    outs, _, _ = pure_apply(block, plist, param_datas, inputs, None,
+    outs, _, _ = pure_apply(block, plist, param_datas,
+                            (ids, positions, k_pool, v_pool, tables), None,
                             training=False, method="decode_step")
     last = 1 + 2 * num_layers
     ks = jnp.stack(outs[1:last:2], 0)      # (layers, B[, L], kv)
@@ -98,7 +99,8 @@ class DecodeEndpoint:
     ``block`` must expose the incremental-decode protocol of
     ``gluon.model_zoo.bert.TransformerLM``: ``num_layers``/``units``
     attributes, ``prefill_collect(tokens)`` and
-    ``decode_step(ids, positions, *kv_ctx)``; optionally ``kv_units``,
+    ``decode_step(ids, positions, k_pool, v_pool, tables)``; optionally
+    ``kv_units``,
     ``block_length`` and ``mask_token_id`` (module docstring), and after the
     layers' K/V a ``decode_step`` may return the rows routed to each expert,
     (layers, experts), which the step reduces to two numbers.
@@ -504,11 +506,13 @@ class DecodeEndpoint:
             tables = onp.zeros((B, P), onp.int32)
             valid = onp.zeros((B,), bool)
             lanes = onp.arange(L, dtype=onp.int32)
+            ctx_live = 0
             for i, row in enumerate(rows):
                 ids[i] = row[0]
                 pos[i] = row[1] if L == 1 else row[1] + lanes
                 tables[i] = row[2]
                 valid[i] = len(row) < 4 or row[3]
+                ctx_live += row[1]      # the cached positions it attends to
         t0 = _now_us()
         with _telemetry.span("decode.launch", kind="step", bucket=B):
             picked, k, v = comp(self._param_datas(), ids, pos, tables,
@@ -523,7 +527,9 @@ class DecodeEndpoint:
         self._observe_cost(self.step_cost, "step", "decode_step",
                            B, dt, rows=n)
         commits = int(valid.sum())
-        self.last_step = {"commits": commits}
+        ctx = (int(ctx_live), n * self.max_seq_len)
+        self.last_step = {"commits": commits, "ctx_live": ctx[0],
+                          "ctx_capacity": ctx[1]}
         # the straggler a grouped expert product waits for against the rows
         # an expert gets on average (every row the executable computes is
         # routed, padding lanes too)
@@ -532,7 +538,7 @@ class DecodeEndpoint:
             self.last_step.update(zip(
                 ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
         self.stats.record_step(dt, n, B, rows=n * L, commits=commits,
-                               expert_load=expert_load)
+                               expert_load=expert_load, ctx=ctx)
         if L == 1:
             return tuple(int(x) for x in out[:n])
         return [(out[0][i], out[1][i]) for i in range(n)]

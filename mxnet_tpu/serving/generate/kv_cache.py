@@ -13,16 +13,18 @@ of which sequence owns which page.
 Layout: ``(num_layers, num_pages, page_size, kv_dim)`` per pool (one for K,
 one for V). **Page 0 is reserved as a scratch page** and never allocated:
 a step's writes for padded/invalid rows are routed to it, and padded
-page-table entries gather from it. Whatever garbage accumulates there is
+page-table entries name it. A decode step reads the pool where it lies
+(``ops/pallas/paged_attention``): each lane's pages through its table, up to
+the lane's own length and no further, so page 0 and pages past the length
+are never fetched, and what a lane's last page holds past its length is
 masked to an exactly-zero softmax weight before it can touch a real row
-(``_NEG_INF`` underflow — see ops/pallas/flash_attention.py
-``single_query_attention``), which is the property the batched-vs-serial
-bitwise decode oracle rests on.
+(``_MASKED`` underflow), which is the property the batched-vs-serial bitwise
+decode oracle rests on.
 
 Host-side management (alloc/free/defrag, counters, the memstats holder) is
-in :class:`PagedKVPool`; the jit-side write/gather helpers
-(:func:`write_prefill`, :func:`write_step`, :func:`gather_ctx`) are pure
-functions traced into the compiled executables. The writes are
+in :class:`PagedKVPool`; the jit-side write helpers (:func:`write_prefill`,
+:func:`write_step`) are pure functions traced into the compiled executables;
+the read is the attention kernel's. The writes are
 ``dynamic_update_slice`` under a loop, not an advanced-index scatter: the
 TPU compiler performs them in the pool's own layout, so with the pools
 donated a step or a prefill touches only the rows it writes. The scatter
@@ -43,8 +45,7 @@ from ...base import MXNetError
 from ...resilience import faults as _faults
 from ..errors import KVPoolExhausted
 
-__all__ = ["PagedKVPool", "KVPoolExhausted", "write_prefill", "write_step",
-           "gather_ctx"]
+__all__ = ["PagedKVPool", "KVPoolExhausted", "write_prefill", "write_step"]
 
 _POOL_PAGES = _telemetry.gauge(
     "mxtpu_kv_pool_pages",
@@ -169,16 +170,6 @@ def write_step(pool, vals, tables, positions, valid, page_size: int):
         return jax.tree.map(put, pools, vals)
 
     return lax.fori_loop(0, B, body, pool)
-
-
-def gather_ctx(pool, tables):
-    """Gather each sequence's cached context: (num_layers, num_pages,
-    page_size, kv_dim) x (B, P) -> (num_layers, B, P*page_size, kv_dim),
-    lane j = position j. Padding table entries gather scratch page 0 —
-    masked by the attention length mask before use."""
-    g = pool[:, tables]                      # (L, B, P, page, kv)
-    L, B = g.shape[0], g.shape[1]
-    return g.reshape(L, B, g.shape[2] * g.shape[3], g.shape[4])
 
 
 # ---------------------------------------------------------------------------
@@ -365,8 +356,8 @@ class PagedKVPool:
 
         Page-granular allocation never *functionally* fragments (any free
         page serves any reservation), so this is an optional compaction that
-        keeps the high-numbered region of the pool untouched — gathers stay
-        cache-local and the tail could be released to a resize. The move is
+        keeps the high-numbered region of the pool untouched — the tail
+        could be released to a resize. The move is
         a single gather+scatter copy (no arithmetic), so decode output
         stays bitwise identical across a compaction. Worker-thread only.
         Returns the number of pages moved."""

@@ -120,6 +120,9 @@ class DecodeStats:
             # is counted apart, so that nothing divides tokens by steps
             "forwards": 0, "commits": 0, "rows": 0, "tokens_placed": 0,
             "blocks_committed": 0,
+            # cached positions the steps' lanes attended to, of those their
+            # lanes could hold (lanes x max_seq_len)
+            "ctx_live": 0, "ctx_capacity": 0,
             "moe.expert_load_max": 0.0, "moe.expert_load_mean": 0.0,
             **{f"seq_{ev}": 0 for ev in _SEQ_EVENTS},
         }
@@ -155,13 +158,17 @@ class DecodeStats:
         self._m_tokens.inc(n)
 
     def record_step(self, dur_us: float, seqs: int, bucket: int, *,
-                    rows: int = None, commits: int = None, expert_load=()):
+                    rows: int = None, commits: int = None, expert_load=(),
+                    ctx=(0, 0)):
         """One step executable run over ``seqs`` sequences padded to
         ``bucket``: ``rows`` forwarded (default one a sequence), ``commits``
         of them writing their K/V (default all), and where the model routes
         experts ``expert_load`` = (rows routed to the busiest expert, to an
         expert on average) of the step, summed into ``moe.expert_load_max``
-        / ``_mean`` so that a window's ratio is a difference of sums."""
+        / ``_mean`` so that a window's ratio is a difference of sums.
+        ``ctx`` = (cached positions the sequences attended to, positions
+        their lanes can hold): what the step's attention read of what a
+        gather of all lanes would have."""
         rows = seqs if rows is None else rows
         commits = seqs if commits is None else commits
         with self._lock:
@@ -170,6 +177,8 @@ class DecodeStats:
             self.counters["commits"] += commits > 0
             self.counters["rows"] += rows
             self.counters["blocks_committed"] += commits
+            self.counters["ctx_live"] += ctx[0]
+            self.counters["ctx_capacity"] += ctx[1]
             if expert_load:
                 self.counters["moe.expert_load_max"] += expert_load[0]
                 self.counters["moe.expert_load_mean"] += expert_load[1]
@@ -229,6 +238,8 @@ class DecodeStats:
         with self._lock:
             return {
                 "counters": dict(self.counters),
+                "ctx_live_share": self.counters["ctx_live"]
+                / max(1, self.counters["ctx_capacity"]),
                 "prefill": self.prefill.snapshot(),
                 "step": self.step.snapshot(),
                 "intertoken": self.intertoken.snapshot(),

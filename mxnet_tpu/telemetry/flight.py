@@ -115,7 +115,7 @@ class FlightRecorder:
                  keep: Optional[int] = None,
                  min_interval_s: Optional[float] = None):
         spans = span_capacity if span_capacity is not None else \
-            int(_cfg("MXNET_FLIGHT_SPANS", 8192))
+            int(_cfg("MXNET_FLIGHT_SPANS", 32768))
         events = event_capacity if event_capacity is not None else \
             int(_cfg("MXNET_FLIGHT_EVENTS", 256))
         requests = request_capacity if request_capacity is not None else \

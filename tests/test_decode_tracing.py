@@ -152,6 +152,39 @@ def test_stats_count_queue_wait_and_ttft_once_per_request(engine):
         assert f'{family}_count{{endpoint="tlm_traced"}}' in text
 
 
+def test_a_step_counts_the_context_its_lanes_attend_to(engine):
+    """``decode.step`` carries ``ctx_live``, the cached positions its lanes
+    attended to, and ``ctx_capacity``, what their lanes could hold; the
+    endpoint's stats sum both. A request of p prompt tokens and b new ones
+    takes b - 1 steps, at p, p + 1, ... cached positions."""
+    before = dict(engine.stats.counters)
+    _, _, _, spans, snap, _ = _generate(engine, PROMPTS, BUDGETS)
+    steps = [e["attrs"] for e in spans if e["name"] == "decode.step"]
+    live = sum(len(p) + j for p, b in zip(PROMPTS, BUDGETS)
+               for j in range(b - 1))
+    assert sum(a["ctx_live"] for a in steps) == live
+    assert all(a["ctx_capacity"] == a["rows"] * engine.max_seq_len
+               and 0 < a["ctx_live"] < a["ctx_capacity"] for a in steps)
+    capacity = sum(b - 1 for b in BUDGETS) * engine.max_seq_len
+    assert snap["counters"]["ctx_live"] - before["ctx_live"] == live
+    assert snap["counters"]["ctx_capacity"] - before["ctx_capacity"] \
+        == capacity
+    assert snap["ctx_live_share"] == pytest.approx(
+        snap["counters"]["ctx_live"] / snap["counters"]["ctx_capacity"])
+
+
+def test_the_live_share_is_one_when_every_lane_is_full():
+    from mxnet_tpu.serving.generate import DecodeStats
+    stats = DecodeStats("tlm_full")
+    assert stats.snapshot()["ctx_live_share"] == 0.0    # no step yet
+    for lanes in (4, 3):
+        stats.record_step(100.0, lanes, 4, ctx=(lanes * 64, lanes * 64))
+    snap = stats.snapshot()
+    assert snap["counters"]["ctx_live"] == 7 * 64
+    assert snap["counters"]["ctx_capacity"] == 7 * 64
+    assert snap["ctx_live_share"] == 1.0
+
+
 def test_failover_leaves_no_span_open_and_emits_nothing_twice(engine):
     clean = _generate(engine, PROMPTS, BUDGETS)[0]
     before = engine.stats.snapshot()

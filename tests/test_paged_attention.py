@@ -1,0 +1,168 @@
+"""The paged-attention kernel under the Pallas interpreter (tier-1,
+JAX_PLATFORMS=cpu): the code the chip compiles, at both decode families'
+shapes cut to small pools — float32 rows of fused heads of 64 with one row a
+lane (few rows: one matmul over the whole row, the queries block-diagonal),
+and bfloat16 rows of grouped heads of 128 with a block of four (many rows: a
+matmul a KV head)."""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+from mxnet_tpu.ops.nn import block_attention
+from mxnet_tpu.ops.pallas.paged_attention import paged_attention
+
+PAGE, P = 16, 4                       # positions a page, pages a lane
+LAYERS, PAGES, LAYER = 2, 40, 1
+LENGTHS = [0, 1, PAGE - 1, PAGE, PAGE + 1, PAGE * P]
+GARBAGE = 1e30
+
+# (dtype, L rows a lane, query heads, KV heads, head width, tolerance)
+FAMILIES = [
+    pytest.param(jnp.float32, 1, 4, 4, 64, 2e-5, id="fused_heads_f32"),
+    pytest.param(jnp.bfloat16, 4, 24, 3, 128, 2e-2, id="grouped_heads_bf16")]
+
+
+def _case(dtype, L, heads, kv_heads, D, lengths, seed=0):
+    rng = onp.random.default_rng(seed)
+    B = len(lengths)
+    shape = (LAYERS, PAGES, PAGE, kv_heads * D)
+    k_pool = jnp.asarray(rng.normal(size=shape), dtype)
+    v_pool = jnp.asarray(rng.normal(size=shape), dtype)
+    tables = rng.permutation(onp.arange(1, PAGES))[:B * P].reshape(B, P)
+    q = jnp.asarray(rng.normal(size=(B, L, heads * D)), dtype)
+    return (q, k_pool, v_pool, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _op(heads, kv_heads, interpret):
+    return lambda *args: paged_attention(
+        *args, LAYER, heads=heads, kv_heads=kv_heads, interpret=interpret)
+
+
+def _f32(a):
+    return a.astype(jnp.float32)
+
+
+def _by_head(rows, kv_heads):
+    """(B, C, kv_heads*D) rows, or a pool's pages through (B, P) tables, as
+    float32 (B, C, kv_heads, D)."""
+    return _f32(rows).reshape(rows.shape[0], -1, kv_heads,
+                              rows.shape[-1] // kv_heads)
+
+
+def _reference(q, k_pool, v_pool, tables, lengths, heads, kv_heads):
+    """(acc, m, l) in float32 at ``highest`` over the same pages, dense."""
+    B, L, _ = q.shape
+    D = k_pool.shape[-1] // kv_heads
+    k = _by_head(k_pool[LAYER][tables], kv_heads)
+    v = _by_head(v_pool[LAYER][tables], kv_heads)
+    qh = _f32(q).reshape(B, L, kv_heads, heads // kv_heads, D)
+    s = jnp.einsum("blhgd,bchd->blhgc", qh, k,
+                   precision="highest") / onp.sqrt(D)
+    seen = (jnp.arange(k.shape[1])[None] < lengths[:, None])
+    seen = seen[:, None, None, None]
+    s = jnp.where(seen, s, -1e30)
+    m = s.max(-1)
+    p = jnp.where(seen, jnp.exp(s - m[..., None]), 0.0)
+    acc = jnp.einsum("blhgc,bchd->blhgd", p, v, precision="highest")
+    return (acc.reshape(B, L, heads, D), m.reshape(B, L, heads),
+            p.sum(-1).reshape(B, L, heads))
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernel", "plain_expression"])
+@pytest.mark.parametrize("dtype,L,heads,kv_heads,D,tol", FAMILIES)
+def test_context_part_matches_the_dense_reference(dtype, L, heads, kv_heads,
+                                                  D, tol, interpret):
+    args = _case(dtype, L, heads, kv_heads, D, LENGTHS)
+    acc, m, l = _op(heads, kv_heads, interpret)(*args)
+    want_acc, want_m, want_l = _reference(*args, heads, kv_heads)
+    assert acc.dtype == m.dtype == l.dtype == jnp.float32
+    # a lane of length 0: nothing seen, and nothing to merge
+    assert float(jnp.abs(acc[0]).max()) == 0.0 and float(l[0].max()) == 0.0
+    assert float(m[0].max()) == onp.float32(-1e30)
+    onp.testing.assert_allclose(m[1:], want_m[1:], atol=tol * 10, rtol=tol)
+    onp.testing.assert_allclose(l[1:], want_l[1:], rtol=tol * 5)
+    onp.testing.assert_allclose(acc[1:] / l[1:, ..., None],
+                                want_acc[1:] / want_l[1:, ..., None],
+                                atol=tol)
+
+
+@pytest.mark.parametrize("dtype,L,heads,kv_heads,D,tol", FAMILIES)
+def test_what_lies_past_a_length_changes_no_bit(dtype, L, heads, kv_heads, D,
+                                                tol):
+    """The scratch page, the tail of a lane's last page and every page past
+    it hold large finite garbage: their weight is exactly zero or they are
+    never read, so the result is bitwise what zeros there give."""
+    q, k_pool, v_pool, tables, lengths = _case(dtype, L, heads, kv_heads, D,
+                                               LENGTHS)
+    tables = tables.at[0].set(0)            # a padding lane: the scratch page
+    pos = jnp.arange(P * PAGE).reshape(P, PAGE)
+
+    def fill(pool, value):
+        pool = pool.at[:, 0].set(value)
+        for b, n in enumerate(LENGTHS):
+            rows = jnp.where((pos >= n)[None, :, :, None], value,
+                             pool[:, tables[b]])
+            pool = pool.at[:, tables[b]].set(rows.astype(pool.dtype))
+        return pool
+
+    outs = [_op(heads, kv_heads, True)(
+        q, fill(k_pool, value), fill(v_pool, -value), tables, lengths)
+        for value in (0.0, GARBAGE)]
+    for clean, dirty in zip(*outs):
+        assert onp.array_equal(onp.asarray(clean), onp.asarray(dirty))
+
+
+@pytest.mark.parametrize("dtype,L,heads,kv_heads,D,tol", FAMILIES)
+def test_a_lane_depends_on_nothing_but_itself(dtype, L, heads, kv_heads, D,
+                                              tol):
+    """One lane's result, bitwise, alone in a bucket of one and among other
+    lanes with other tables and lengths in buckets of four and eight."""
+    q, k_pool, v_pool, tables, lengths = _case(
+        dtype, L, heads, kv_heads, D, [PAGE * 2 + 3] * 8)
+    op = _op(heads, kv_heads, True)
+    alone = op(q[:1], k_pool, v_pool, tables[:1], lengths[:1])
+    rng = onp.random.default_rng(1)
+    for bucket, at in ((4, 2), (8, 7)):
+        order = onp.concatenate([rng.permutation(onp.arange(1, bucket))[:at],
+                                 [0], onp.arange(at + 1, bucket)])
+        others = jnp.asarray(rng.integers(0, PAGE * P + 1, bucket),
+                             jnp.int32).at[at].set(lengths[0])
+        among = op(q[order], k_pool, v_pool, tables[order], others)
+        for one, many in zip(alone, among):
+            assert onp.array_equal(onp.asarray(one[0]), onp.asarray(many[at]))
+
+
+@pytest.mark.parametrize("dtype,L,heads,kv_heads,D,tol", FAMILIES)
+def test_the_two_parts_merge_into_one_softmax(dtype, L, heads, kv_heads, D,
+                                              tol):
+    """The context part from the kernel and the step's own rows through
+    ``block_attention``: what dense attention over context + block gives."""
+    lengths = [n - n % L for n in LENGTHS]
+    q, k_pool, v_pool, tables, lens = _case(dtype, L, heads, kv_heads, D,
+                                            lengths)
+    rng = onp.random.default_rng(2)
+    B = len(lengths)
+    k = jnp.asarray(rng.normal(size=(B, L, kv_heads * D)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, L, kv_heads * D)), dtype)
+    positions = lens[:, None] + jnp.arange(L, dtype=jnp.int32)[None]
+    ctx = _op(heads, kv_heads, True)(q, k_pool, v_pool, tables, lens)
+    got = block_attention(q, k, v, positions, *ctx, heads=heads,
+                          kv_heads=kv_heads, block_length=L)
+    kc = jnp.concatenate([_by_head(k_pool[LAYER][tables], kv_heads),
+                          _by_head(k, kv_heads)], 1)
+    vc = jnp.concatenate([_by_head(v_pool[LAYER][tables], kv_heads),
+                          _by_head(v, kv_heads)], 1)
+    C = P * PAGE
+    seen = jnp.concatenate([jnp.arange(C)[None] < lens[:, None],
+                            jnp.ones((B, L), bool)], 1)
+    qh = _f32(q).reshape(B, L, kv_heads, heads // kv_heads, D)
+    s = jnp.einsum("blhgd,bchd->blhgc", qh, kc,
+                   precision="highest") / onp.sqrt(D)
+    s = jnp.where(seen[:, None, None, None], s, -jnp.inf)
+    want = jnp.einsum("blhgc,bchd->blhgd", jax.nn.softmax(s, -1), vc,
+                      precision="highest").reshape(B, L, heads * D)
+    assert got.dtype == dtype
+    onp.testing.assert_allclose(_f32(got), want, atol=tol)

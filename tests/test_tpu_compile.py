@@ -19,11 +19,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu.ops.pallas.flash_attention import (flash_attention,
-                                                  single_query_attention)
+from mxnet_tpu.ops.nn import block_attention
+from mxnet_tpu.ops.pallas.flash_attention import flash_attention
 from mxnet_tpu.ops.pallas.fused_conv1x1 import conv1x1_bn_act
-from mxnet_tpu.serving.generate.kv_cache import (gather_ctx, write_prefill,
-                                                 write_step)
+from mxnet_tpu.ops.pallas.paged_attention import paged_attention
+from mxnet_tpu.serving.generate.kv_cache import write_prefill, write_step
 
 
 @pytest.fixture(scope="module")
@@ -93,26 +93,9 @@ def test_conv1x1_bn_act_compiles(one_chip, m, k, n):
     assert text.count("tpu_custom_call") == 1
 
 
-def test_single_query_attention_is_plain_xla(one_chip):
-    """The decode step's attention at BERT-base widths over a 512-lane
-    context: fused XLA, no kernel of ours, nothing left unfused but the
-    softmax's pieces."""
-    B, L, units, heads = 8, 512, 768, 12
-    f32 = jnp.float32
-    text = _compiled_text(
-        lambda q, kc, vc, kn, vn, lens: single_query_attention(
-            q, kc, vc, kn, vn, lens, heads=heads),
-        ((B, units), f32), ((B, L, units), f32), ((B, L, units), f32),
-        ((B, units), f32), ((B, units), f32), ((B,), jnp.int32),
-        sharding=one_chip)
-    assert "tpu_custom_call" not in text
-    assert "fusion(" in text and "exponential(" in text
-
-
 # cell gpt1.decode_chat's KV pools: 12 layers, 2049 pages of 16 positions,
 # 768 wide, float32 — 1.21 GB each, 32 pages a sequence
 POOL = (12, 2049, 16, 768)
-POOL_HLO = "f32[%d,%d,%d,%d]" % POOL
 PAGE, PAGES_PER_SEQ = 16, 32
 I32 = jnp.int32
 
@@ -133,30 +116,16 @@ def _prefill_alone(S):
         ((PAGES_PER_SEQ,), I32), ((), I32)]
 
 
-def _decode_shaped(B):
-    """The decode step's cache traffic without its model: gather both pools
-    through the tables, reduce over the gathered lanes, write the result."""
-    def fn(k_pool, v_pool, tables, positions, valid):
-        row = (gather_ctx(k_pool, tables) * gather_ctx(v_pool, tables)).sum(2)
-        return write_step((k_pool, v_pool), (row, -row), tables, positions,
-                          valid, PAGE)
-    return fn, [((B, PAGES_PER_SEQ), I32), ((B,), I32), ((B,), jnp.bool_)]
-
-
-# (program, the scratch it needs besides the write's): the decode-shaped
-# program holds both gathered contexts, (12, 64, 512, 768) float32 each
 POOL_WRITES = [
-    pytest.param(_step_alone(64), 0, id="write_step_b64"),
-    pytest.param(_step_alone(8), 0, id="write_step_b8"),
-    pytest.param(_prefill_alone(16), 0, id="write_prefill_s16"),
-    pytest.param(_prefill_alone(128), 0, id="write_prefill_s128"),
-    pytest.param(_prefill_alone(512), 0, id="write_prefill_s512"),
-    pytest.param(_decode_shaped(64), 2 * 12 * 64 * 512 * 768 * 4,
-                 id="decode_shaped_b64")]
+    pytest.param(_step_alone(64), id="write_step_b64"),
+    pytest.param(_step_alone(8), id="write_step_b8"),
+    pytest.param(_prefill_alone(16), id="write_prefill_s16"),
+    pytest.param(_prefill_alone(128), id="write_prefill_s128"),
+    pytest.param(_prefill_alone(512), id="write_prefill_s512")]
 
 
-@pytest.mark.parametrize("program,other_temp", POOL_WRITES)
-def test_pool_write_is_in_place(one_chip, program, other_temp):
+@pytest.mark.parametrize("program", POOL_WRITES)
+def test_pool_write_is_in_place(one_chip, program):
     """With the pools donated, the compiled program makes no pool-sized
     copy and the write takes under 64 MB of scratch (the advanced-index
     scatter compiled to a relayout copy before and after it, per pool, and
@@ -164,16 +133,82 @@ def test_pool_write_is_in_place(one_chip, program, other_temp):
     StableHLO, does not show that: the copies come from the TPU compiler's
     layout assignment, after lowering."""
     fn, rest = program
+    _holds_the_pools_in_place(one_chip, fn, POOL, jnp.float32, rest)
+
+
+def _holds_the_pools_in_place(one_chip, fn, pool, dtype, rest):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in [(POOL, jnp.float32)] * 2 + rest]
+            for s, d in [(pool, dtype)] * 2 + rest]
     comp = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
     text = comp.as_text()
     assert "input_output_alias" in text.splitlines()[0]
+    hlo = ("f32" if dtype == jnp.float32 else "bf16") + "[%d,%d,%d,%d]" % pool
     makers = set(re.findall(
-        r"= " + re.escape(POOL_HLO) + r"\{[^}]*\} ([\w-]+)\(", text))
+        r"= " + re.escape(hlo) + r"\{[^}]*\} ([\w-]+)\(", text))
     assert makers and "copy" not in makers, makers
     temp = comp.memory_analysis().temp_size_in_bytes
-    assert temp < other_temp + (64 << 20), temp
+    assert temp < 64 << 20, temp
+    return text
+
+
+# the decode cells' steps: (lanes, rows a lane, query heads, KV heads, the
+# pools, pages a lane, dtype). gpt1.decode_chat and gpt1.decode_long at their
+# largest and a small bucket; sdar_30b_a3b.gen256_s2: 6 layers, 4097 pages,
+# rows of 4 KV heads of 128 in bfloat16 under 32 query heads, blocks of 4
+DECODE = [
+    pytest.param(64, 1, 12, 12, POOL, 32, jnp.float32, id="gpt1_b64"),
+    pytest.param(8, 1, 12, 12, POOL, 32, jnp.float32, id="gpt1_b8"),
+    pytest.param(64, 4, 32, 4, (6, 4097, 16, 512), 64, jnp.bfloat16,
+                 id="sdar_b64")]
+
+
+@pytest.mark.parametrize("B,L,heads,kv_heads,pool,P,dtype", DECODE)
+def test_paged_attention_compiles_to_one_kernel(one_chip, B, L, heads,
+                                                kv_heads, pool, P, dtype):
+    """Under Mosaic, at the cells' shapes, on the whole pools: one kernel,
+    and nothing of a pool's size beside it (no slice of the layer)."""
+    D = pool[3] // kv_heads
+    comp = jax.jit(lambda q, k, v, tables, lengths: paged_attention(
+        q, k, v, tables, lengths, pool[0] - 1, heads=heads,
+        kv_heads=kv_heads, interpret=False)).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+                ((B, L, heads * D), dtype), (pool, dtype), (pool, dtype),
+                ((B, P), I32), ((B,), I32))]).compile()
+    assert comp.as_text().count("tpu_custom_call") == 1
+    assert comp.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("B,L,heads,kv_heads,pool,P,dtype",
+                         [DECODE[0], DECODE[2]])
+def test_decode_step_reads_and_writes_the_pools_in_place(
+        one_chip, B, L, heads, kv_heads, pool, P, dtype):
+    """The decode step's cache traffic without its model: every layer
+    attends through the page table, to the context in the pools and to the
+    step's own rows, then the rows are written. With the pools donated the
+    program holds no pool-sized copy and under 64 MB of scratch: nothing of
+    the size of all lanes' context (2 x 1.21 GB when it was gathered)."""
+    layers = pool[0]
+
+    def fn(k_pool, v_pool, x, tables, positions, valid):
+        ks, vs = [], []
+        for i in range(layers):
+            ctx = paged_attention(x, k_pool, v_pool, tables, positions[:, 0],
+                                  i, heads=heads, kv_heads=kv_heads,
+                                  interpret=False)
+            k, v = x[..., :pool[3]] * 2, x[..., :pool[3]] * 3
+            x = block_attention(x, k, v, positions, *ctx, heads=heads,
+                                kv_heads=kv_heads, block_length=L)
+            ks.append(k)
+            vs.append(v)
+        return (x,) + write_step((k_pool, v_pool),
+                                 (jnp.stack(ks), jnp.stack(vs)), tables,
+                                 positions, valid, PAGE)
+
+    D = pool[3] // kv_heads
+    text = _holds_the_pools_in_place(one_chip, fn, pool, dtype, [
+        ((B, L, heads * D), dtype), ((B, P), I32), ((B, L), I32),
+        ((B,), jnp.bool_)])
+    assert text.count("tpu_custom_call") == layers
 
 
 # the routed experts of the sdar_30b_a3b cell at its published widths: rows
