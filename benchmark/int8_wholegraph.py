@@ -172,11 +172,19 @@ def int8_forward(qp, amax, x_q, sx_in):
     return yf @ jnp.asarray(wfc).T + jnp.asarray(bfc)
 
 
-from _timing import time_chained as _time_chained
-
-
-def _time(fn, args):
-    return _time_chained(fn, args, fetch=lambda o: float(o[0, 0]))
+def _time(fn, args, reps=3, chain=40):
+    """Median seconds per call. A window's closing fetch costs a fixed round
+    trip, so it is amortized over ``chain`` queued calls and closed by ONE
+    value fetch (a ready-flag sync alone returned early, round 3)."""
+    float(fn(*args)[0, 0])
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(chain):
+            out = fn(*args)
+        float(out[0, 0])
+        ts.append((time.perf_counter() - t0) / chain)
+    return statistics.median(ts)
 
 
 def main():

@@ -1,10 +1,10 @@
 """The quickest proof that mxnet_tpu still starts on the chip.
 
 Drives the three main paths once through the entry points a user would call,
-at the full width of the models bench.py and the serving stack are built for,
-on ONE TPU chip in ONE process:
+at the full width of the models the benchmark and the serving stack are built
+for, on ONE TPU chip in ONE process:
 
-  1. train/resnet50   ParallelTrainStep as bench.py builds it (b32, bf16)
+  1. train/resnet50   ParallelTrainStep as a training job builds it (b32, bf16)
   2. train/bert_base  the same for BERT-base pretraining, at seq 128 (dense
                       attention, the path of record) and at seq 512 (where the
                       Pallas flash-attention kernel must be in the program)
@@ -193,8 +193,8 @@ def train_resnet(dev, seed, model="resnet50_v1", classes=1000, img=224,
 # phase 2
 # ---------------------------------------------------------------------------
 def _bert_pretrain(seed, batch, seq, vocab, **widths):
-    """BERT pretraining model and one seeded batch, as bench.py's bench_bert
-    makes them. Returns (model, x, y, extras)."""
+    """BERT pretraining model and one seeded batch. Returns (model, x, y,
+    extras)."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo import bert
 
@@ -215,7 +215,7 @@ def _bert_pretrain(seed, batch, seq, vocab, **widths):
 
 
 def _bert_step(model, mesh, dtype, masked=False):
-    """The step bench.py builds. ``masked`` gives the reference's variant:
+    """The training step. ``masked`` gives the reference's variant:
     an all-valid mask sends attention down the masked XLA composite, a
     second implementation that never enters flash_attention."""
     import mxnet_tpu as mx

@@ -15,7 +15,7 @@ using mxnet_tpu_cpp::Executor;
 using mxnet_tpu_cpp::Symbol;
 namespace op = mxnet_tpu_cpp::op;
 
-int main() {
+static int Run() {
   Symbol data = Symbol::Variable("data");
   Symbol label = Symbol::Variable("softmax_label");
 
@@ -87,4 +87,10 @@ int main() {
   std::printf("cpp-op-surface OK: probs_row0_sum=%f w2_gnorm=%f\n",
               rowsum, gnorm);
   return 0;
+}
+
+int main() {
+  int rc = Run();             // every handle is freed when Run returns
+  mxnet_tpu_cpp::Shutdown();
+  return rc;
 }

@@ -50,7 +50,7 @@ void MakeBatch(std::mt19937* rng, std::vector<float>* x,
 
 }  // namespace
 
-int main() {
+static int Run() {
   // ---- network: 784 -> 128 relu -> 64 relu -> 10 softmax ----
   Symbol data = Symbol::Variable("data");
   Symbol label = Symbol::Variable("softmax_label");
@@ -145,4 +145,10 @@ int main() {
   double acc = static_cast<double>(correct) / total;
   std::printf("cpp-train accuracy: %.4f (%d/%d)\n", acc, correct, total);
   return acc > 0.95 ? 0 : 1;
+}
+
+int main() {
+  int rc = Run();             // every handle is freed when Run returns
+  mxnet_tpu_cpp::Shutdown();
+  return rc;
 }

@@ -154,6 +154,12 @@ class Optimizer {
   std::shared_ptr<void> h_;
 };
 
+// Call once at the end of main, after every Symbol, Executor and Optimizer
+// has gone out of scope (the reference's examples end with
+// MXNotifyShutdown()): the embedded runtime stops before exit() runs static
+// destructors under its threads.
+inline void Shutdown() { MXTrShutdown(); }
+
 }  // namespace mxnet_tpu_cpp
 
 #endif  // MXNET_TPU_CPP_TRAIN_HPP_

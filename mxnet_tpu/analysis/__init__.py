@@ -87,8 +87,8 @@ DEFAULT_SCAN_SET = ("mxnet_tpu", "tools/chaos_check.py",
                     "tools/metrics_dump.py", "tools/mxlint.py")
 
 #: what ``mxlint --ir`` scans when given no corpus directories: the
-#: committed fixture ledgers — the costmodel corpus (records only, no
-#: retained texts: exercises the missing-text tolerance) and the hlolint
+#: committed fixture ledgers — a serving run's records (no retained
+#: texts: exercises the missing-text tolerance) and the hlolint
 #: clean corpus (retained texts that must stay silent)
 DEFAULT_IR_SCAN_SET = ("tests/fixtures/costmodel/ledger",
                        "tests/fixtures/hlolint/clean")

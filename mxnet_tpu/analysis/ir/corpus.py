@@ -9,8 +9,9 @@ retained text for that fingerprint — and runs the ``scope = "ir"``
 checkers over them.
 
 The join is deliberately tolerant in both directions: a record without a
-retained text still checks the record-level rules (the committed costmodel
-fixture predates text retention and must keep scanning clean), and a bare
+retained text still checks the record-level rules (the committed
+``tests/fixtures/costmodel/ledger`` predates text retention and must keep
+scanning clean), and a bare
 ``.mlir`` file without a record still checks the text-level rules (so a
 module pasted into a fixture directory is lintable on its own). What is
 *not* tolerated is a lying content address: a ``module-<fp>.mlir`` whose

@@ -1,8 +1,7 @@
 """Text-level StableHLO module parser — no MLIR dependency.
 
 The compile ledger canonicalizes and sha256-fingerprints every lowered
-module (PR 10) and the cost observatory already regex-parses op histograms
-out of the same text (PR 17); this module is that seam grown into a real
+module (PR 10); this module is that seam grown into a real
 parser: the canonicalizer (hardened here — nested ``loc(...)``, string
 attributes, ``#loc`` reference lines), tensor-type decoding, entry-function
 argument attributes (``tf.aliasing_output`` / ``jax.buffer_donor`` — the

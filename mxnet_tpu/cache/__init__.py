@@ -23,7 +23,7 @@ _COMPILE_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for an entry point
-    (bench.py, benchmark/*.py, chip_smoke.py) before its first compile.
+    (chipbench, chip_smoke.py, benchmark/*.py) before its first compile.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already writes there and
     nothing is set in code; otherwise the cache lives in ``.jax_cache`` at
     the root of the checkout. Returns the directory in use. Tests never

@@ -401,8 +401,8 @@ register("MXNET_COMPILE_LEDGER_KEEP", 64, int,
 register("MXNET_COMPILE_LEDGER_TEXT_MAX_BYTES", 32 << 20, int,
          "Compile ledger: byte budget for retained canonicalized module "
          "texts (module-<fingerprint>.mlir beside the ledger records — the "
-         "offline corpus mxlint --ir and autotune feature extraction "
-         "read). Content-addressed dedup means each distinct program is "
+         "offline corpus mxlint --ir reads). "
+         "Content-addressed dedup means each distinct program is "
          "stored once; when the directory's retained texts would exceed "
          "the budget, new texts are skipped (counted in "
          "mxtpu_compile_text_retained_total{outcome=over_budget}). "
@@ -538,37 +538,3 @@ register("MXNET_GOODPUT_PEAK_GBS", 0.0, float,
          "Goodput ledger: peak device memory bandwidth (bytes/s) for the "
          "roofline fraction of the bytes-accessed rate. 0 reports "
          "achieved rates only.")
-register("MXNET_COSTMODEL_PATH", "", str,
-         "Cost model: path of the trained artifact JSON "
-         "(tools/autotune.py --train writes one). When set, the model is "
-         "loaded lazily (sha256 + schema verified, mtime-cached) and its "
-         "predictions price every cold StepCostEWMA bucket and the "
-         "autoscaler's warm-up lead. Empty (the default) disables the "
-         "prior entirely — all scheduling behaves exactly pre-model.")
-register("MXNET_COSTMODEL_PRIOR", True, bool,
-         "Cost model: master switch for the learned prior. False keeps "
-         "the artifact loadable (for /costz and offline tools) but makes "
-         "every EWMA fall back to the legacy row-ratio pricing.")
-register("MXNET_COSTMODEL_BLEND_N", 5, int,
-         "Cost model: observations per bucket over which a prior-priced "
-         "estimate blends linearly into the measured EWMA. After this "
-         "many observations the prior's weight is exactly zero — measured "
-         "always wins. 0 disables blending (prior prices only "
-         "never-observed buckets).")
-register("MXNET_COSTMODEL_STEP_RECORDS", True, bool,
-         "Cost model: append rate-limited kind=\"step\" records (measured "
-         "step wall per trigger key) into the compile-ledger JSONL files "
-         "— the training corpus for the step_us target. Power-of-two "
-         "observation counts are logged (plus one per 256 steady-state), "
-         "so a million-step serve costs ~4k lines. Only active when "
-         "MXNET_COMPILE_LEDGER_DIR is set.")
-register("MXNET_COSTMODEL_DRIFT_BAND", 4.0, float,
-         "Cost model: residual drift band. A measured/predicted ratio "
-         "outside [1/band, band] counts toward the drift streak; sustained "
-         "excursions fire the cost_model_drift flight event (stale-model "
-         "alarm).")
-register("MXNET_COSTMODEL_DRIFT_SUSTAIN_N", 8, int,
-         "Cost model: consecutive out-of-band residuals (per site) before "
-         "cost_model_drift fires. The detector latches per episode — one "
-         "event per sustained excursion, re-armed when a residual returns "
-         "in-band.")

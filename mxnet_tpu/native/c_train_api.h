@@ -48,6 +48,11 @@ int MXTrOptimizerUpdate(void* opt, void* exec, const char* arg_name,
 
 void MXTrBufFree(char* buf);
 
+// finalize the interpreter this library started (no-op if the host started
+// it). Call once before the process exits, after the last handle is freed
+// (parity: MXNotifyShutdown at the end of the reference's C++ examples).
+int MXTrShutdown();
+
 #ifdef __cplusplus
 }  // extern "C"
 #endif

@@ -36,6 +36,8 @@ void TrCapturePyError() {
   TrSetError(msg);
 }
 
+bool g_tr_owns_python = false;  // this library started the interpreter
+
 bool TrEnsurePython() {
   if (!Py_IsInitialized()) {
     // hosts that dlopen this library (perl XS, dlopen-based bindings) load
@@ -46,6 +48,7 @@ bool TrEnsurePython() {
              PY_MAJOR_VERSION, PY_MINOR_VERSION);
     dlopen(soname, RTLD_NOW | RTLD_GLOBAL);
     Py_InitializeEx(0);
+    g_tr_owns_python = true;
     PyEval_SaveThread();  // entry points re-acquire via PyGILState_Ensure
   }
   return true;
@@ -144,7 +147,7 @@ int MXTrSymbolCreate(const char* op_name, const char* name, void** inputs,
 }
 
 int MXTrSymbolFree(void* sym) {
-  if (!sym) return 0;
+  if (!sym || !Py_IsInitialized()) return 0;  // after MXTrShutdown: gone
   Gil gil;
   Py_DECREF(static_cast<PyObject*>(sym));
   return 0;
@@ -312,5 +315,17 @@ int MXTrOptimizerUpdate(void* opt, void* exec, const char* arg_name,
 }
 
 void MXTrBufFree(char* buf) { std::free(buf); }
+
+int MXTrShutdown() {
+  // exit() runs the runtime's static destructors while the interpreter this
+  // library started still has threads alive: a host that returned from main
+  // without this call segfaulted there now and then (2 of 10 runs on a
+  // loaded machine), after its work was done. Finalizing first is what
+  // `python` itself does. An interpreter the host started is the host's.
+  if (!g_tr_owns_python || !Py_IsInitialized()) return 0;
+  g_tr_owns_python = false;
+  PyGILState_Ensure();
+  return Py_FinalizeEx() < 0 ? -1 : 0;
+}
 
 }  // extern "C"

@@ -29,7 +29,7 @@ from ..registry import register
 # ~10-25% faster than jax.experimental.pallas.ops.tpu.flash_attention at
 # the same shapes; both clamp to S for short sequences. At very long
 # context the optimum shifts up: S>=16384 runs ~30% faster fwd and ~12%
-# faster bwd at 1024/1024 (r5 sweep, benchmark/flash_bwd_sweep.py) —
+# faster bwd at 1024/1024 (r5 sweep of block sizes on the chip) —
 # resolved adaptively in flash_attention() when the caller does not
 # override the blocks.
 DEFAULT_BLOCK_Q = 512
@@ -379,8 +379,7 @@ def _pallas_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
 
     B, H, S, D = q.shape
     # backward-specific block sizes (the bwd kernels' working set is ~3x the
-    # forward's per tile, so its optimum differs; r5 sweep in
-    # benchmark/flash_bwd_sweep.py)
+    # forward's per tile, so its optimum differs; r5 sweep on the chip)
     block_q = int(_config.get("MXNET_FLASH_BWD_BLOCK_Q") or block_q)
     block_k = int(_config.get("MXNET_FLASH_BWD_BLOCK_K") or block_k)
     bq = min(block_q, S)

@@ -68,7 +68,7 @@ libinfo_features = feature_list
 
 
 # ---------------------------------------------------------------------------
-# where a measurement runs (bench.py, benchmark/*.py, chip_smoke.py)
+# where a measurement runs (chipbench, chip_smoke.py, benchmark/*.py)
 # ---------------------------------------------------------------------------
 DEVICE_ROW_KEYS = ("platform", "device_kind", "device_count")
 

@@ -39,7 +39,7 @@ tenant's breaker, restarts the worker generation). See RESILIENCE.md's
     ep = serving.ModelEndpoint("resnet50", net, input_shapes=(3, 224, 224),
                                dtype="bfloat16", max_batch_size=32)
     server = serving.InferenceServer(batch_timeout_ms=2.0, max_queue=256)
-    server.register(ep, slo_ms=50.0)   # warms buckets + seeds the cost model
+    server.register(ep, slo_ms=50.0)   # warms buckets + seeds the step costs
     server.start()
 
     out = server.predict("resnet50", img)           # blocking

@@ -212,8 +212,8 @@ class ServingPool:
         raise last_exc
 
     def _predicted_step_us(self, name: str) -> float:
-        """Cost-model / EWMA predicted device time of this endpoint's next
-        batch (the Router's scheduling estimate) — the hedge delay's prior
+        """Measured-EWMA device time of this endpoint's next batch (the
+        Router's scheduling estimate) — the hedge delay's starting point
         for workloads the latency ring has not warmed yet. 0.0 when
         unknowable."""
         try:
@@ -457,36 +457,10 @@ class Autoscaler:
     # ------------------------------------------------------------------
     # signals + decision
     # ------------------------------------------------------------------
-    def predicted_warmup_s(self) -> float:
-        """Cost-model predicted compile wall (seconds) for warming one
-        fresh replica: the sum of every tenant endpoint's
-        ``predicted_warmup_s()`` on the first replica in rotation (all
-        replicas serve the same endpoint set). 0.0 without an active
-        model — scale-up timing is then exactly the pre-model behavior."""
-        try:
-            replicas = self.pool._rotation()
-            if not replicas:
-                return 0.0
-            srv = replicas[0].server
-            with srv._cond:
-                tenants = list(srv._router.tenants())
-        except Exception:
-            return 0.0
-        total = 0.0
-        for t in tenants:
-            fn = getattr(t.endpoint, "predicted_warmup_s", None)
-            if fn is None:
-                continue
-            try:
-                total += float(fn() or 0.0)
-            except Exception:
-                pass
-        return total
-
     def signals(self) -> dict:
         """One poll's worth of evidence: the worst fast-window burn rate and
         the active-alert count across SLO objectives, plus the pool's queue
-        pressure and the cost model's predicted replica warm-up time."""
+        pressure."""
         max_fast = 0.0
         alerts = 0
         for st in self._monitor.check_all():
@@ -495,29 +469,18 @@ class Autoscaler:
         return {"max_fast_burn": round(max_fast, 3),
                 "alerts_active": alerts,
                 "queue_pressure": round(self.pool.queue_pressure(), 4),
-                "predicted_warmup_s": round(self.predicted_warmup_s(), 3),
                 "replicas": self.pool.size()}
 
     def _decide(self, sig: dict, now: float) -> Optional[str]:
         """Pure-ish decision core: updates hysteresis counters, returns
         'up' / 'down' / None. Cooldown and min/max bounds are enforced
-        here so every caller of tick() gets the same discipline.
-
-        The predicted warm-up signal buys lead time: every full poll
-        period of predicted compile wall a new replica will spend warming
-        shaves one poll off the scale-up hysteresis (never below one) —
-        an expensive-to-warm fleet commits earlier, because the capacity
-        it is buying arrives later."""
+        here so every caller of tick() gets the same discipline."""
         over = (sig["alerts_active"] > 0
                 or sig["max_fast_burn"] >= self._monitor.burn_threshold
                 or sig["queue_pressure"] >= self.queue_high)
         idle = (sig["alerts_active"] == 0
                 and sig["max_fast_burn"] < 1.0
                 and sig["queue_pressure"] <= self.queue_low)
-        up_need = self.up_n
-        lead = float(sig.get("predicted_warmup_s", 0.0) or 0.0)
-        if lead > 0.0:
-            up_need = max(1, up_need - int(lead // max(self.poll_s, 1e-9)))
         with self._lock:
             self._over_polls = self._over_polls + 1 if over else 0
             self._idle_polls = self._idle_polls + 1 if idle else 0
@@ -525,7 +488,7 @@ class Autoscaler:
                            and now - self._last_action_ts < self.cooldown_s)
             if in_cooldown:
                 return None
-            if over and self._over_polls >= up_need \
+            if over and self._over_polls >= self.up_n \
                     and sig["replicas"] < self.max_replicas:
                 self._over_polls = 0
                 self._last_action_ts = now

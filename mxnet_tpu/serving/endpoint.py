@@ -78,10 +78,6 @@ class ModelEndpoint:
     #: replica attracts ~4x a single-chip one's share
     capacity = 1
 
-    #: cost-model site label: the stream step observations and priors are
-    #: keyed by in the ledger, metrics and residual drift detection
-    cost_site = "serving_step"
-
     def __init__(self, name: str, block, input_shapes, dtype="float32",
                  max_batch_size: int = 32,
                  buckets: Optional[Sequence[int]] = None,
@@ -111,14 +107,8 @@ class ModelEndpoint:
         self.np_dtypes = tuple(onp.dtype(d) for d in self._jnp_dtypes)
 
         self.stats = EndpointStats(name)
-        # per-bucket step-time model: measured EWMA, with the learned cost
-        # model (when MXNET_COSTMODEL_PATH is active) pricing never-seen
-        # buckets through the prior hook — sharded subclasses inherit this
-        # with their mesh-labeled _compile_key, so the prior is per-slice
-        from ..telemetry import costmodel as _costmodel
-        self.step_cost = StepCostEWMA(
-            name=name,
-            prior=_costmodel.make_prior(self.cost_site, self._compile_key))
+        # per-bucket step time: the measured mean, seeded by warmup
+        self.step_cost = StepCostEWMA(name=name)
         self._lock = threading.Lock()
         self._execs: Dict[int, object] = {}   # bucket -> compiled executable
         self._jfn = None
@@ -352,42 +342,8 @@ class ModelEndpoint:
                     ins = self._warmup_inputs(b)
                     t0 = _now_us()
                     jax.block_until_ready(comp(self._param_datas(), *ins))
-                    self._observe_step(b, _now_us() - t0)
+                    self.step_cost.observe(b, _now_us() - t0)
         return n
-
-    def predicted_warmup_s(self, fresh: bool = True) -> float:
-        """Cost-model predicted cold-compile wall (seconds) to warm every
-        bucket — the autoscaler's scale-up lead time for a replica that
-        starts with an empty executable cache. ``fresh=False`` prices only
-        the buckets this instance has not compiled yet. 0.0 without an
-        active model (the autoscaler then behaves exactly as before)."""
-        try:
-            from ..telemetry import costmodel as _costmodel
-            total = 0.0
-            for b in self.buckets:
-                if not fresh and b in self._execs:
-                    continue
-                v = _costmodel.predict_compile_s(self._compile_key(b),
-                                                 site="serving_bucket")
-                if v:
-                    total += float(v)
-            return total
-        except Exception:
-            return 0.0
-
-    def _observe_step(self, bucket: int, us: float,
-                      rows: Optional[int] = None):
-        """Feed one measured device step: the scheduler's EWMA always, and
-        the cost observatory (rate-limited kind="step" ledger record +
-        predicted-vs-measured residual) when telemetry is live."""
-        self.step_cost.observe(bucket, us)
-        try:
-            from ..telemetry import costmodel as _costmodel
-            _costmodel.on_step_observed(
-                self.cost_site, self._compile_key(bucket), bucket, us,
-                rows=rows, prior_us=self.step_cost.prior(bucket))
-        except Exception:
-            pass
 
     def _warmup_inputs(self, bucket: int):
         """Zero inputs for one warmup execution of ``bucket``."""
@@ -454,7 +410,7 @@ class ModelEndpoint:
             t0 = _now_us()
             outs = comp(self._param_datas(), *device_inputs)
             jax.block_until_ready(outs)
-            self._observe_step(bucket, _now_us() - t0, rows=rows)
+            self.step_cost.observe(bucket, _now_us() - t0)
         self.stats.bump("batches")
         self.stats.bump("real_rows", rows)
         self.stats.bump("padded_rows", bucket - rows)

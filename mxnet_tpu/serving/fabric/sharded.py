@@ -164,10 +164,8 @@ class ShardedEndpoint(ModelEndpoint):
         return f"{self._platform()}:{_mesh_label(self._dmesh)}"
 
     def _compile_key(self, bucket: int) -> Dict[str, object]:
-        # the mesh label rides into the compile ledger AND the cost-model
-        # prior: a cold bucket on a 4-chip slice is priced by predictions
-        # trained on that topology, so fabric admission (step_cost.estimate
-        # behind ServingPool's capacity-weighted routing) is per-slice
+        # the mesh label rides into the compile ledger and the executable
+        # cache's trigger key: one slice topology, one set of executables
         key = super()._compile_key(bucket)
         key["mesh"] = _mesh_label(self._dmesh)
         return key
@@ -278,8 +276,8 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
         return f"{self._platform()}:{_mesh_label(self._dmesh)}"
 
     def _cost_key(self, kind: str, bucket: int) -> Dict[str, object]:
-        # mirror the dense twin: slice topology reaches the ledger and the
-        # cost-model prior, so decode admission prices per-slice
+        # mirror the dense twin: slice topology reaches the ledger and
+        # the executable cache's trigger key
         key = super()._cost_key(kind, bucket)
         key["mesh"] = _mesh_label(self._dmesh)
         return key

@@ -146,18 +146,11 @@ class DecodeEndpoint:
                 f"position-embedding table ({max_len})")
 
         self.stats = DecodeStats(name)
-        # per-bucket cost models (us): measured EWMA with the learned cost
-        # model as the cold-bucket prior. The key closures read self lazily
-        # — the KV pool (whose dtype the key carries) is built below.
-        from ...telemetry import costmodel as _costmodel
+        # per-bucket measured means (us), seeded by warmup
         self.step_cost = StepCostEWMA(      # per decode batch bucket
-            name=f"{name}.decode",
-            prior=_costmodel.make_prior(
-                "decode_step", lambda b: self._cost_key("step", b)))
+            name=f"{name}.decode")
         self.prefill_cost = StepCostEWMA(   # per prefill seq bucket
-            name=f"{name}.prefill",
-            prior=_costmodel.make_prior(
-                "decode_prefill", lambda b: self._cost_key("prefill", b)))
+            name=f"{name}.prefill")
         self._lock = threading.Lock()
         self._prefill_execs: Dict[int, object] = {}
         self._decode_execs: Dict[int, object] = {}
@@ -328,24 +321,11 @@ class DecodeEndpoint:
         return self.pool.k_pool.dtype
 
     def _cost_key(self, kind: str, bucket: int) -> Dict[str, object]:
-        """The compile-ledger / cost-model trigger key for one (kind,
-        bucket) executable — also what the cold-bucket prior featurizes."""
+        """The compile-ledger / executable-cache trigger key for one
+        (kind, bucket) executable."""
         return {"endpoint": self.name, "kind": kind, "bucket": bucket,
                 "dtype": str(self.pool_dtype),
                 "device": self._device_label()}
-
-    def _observe_cost(self, ewma, kind: str, site: str, bucket: int,
-                      us: float, rows: Optional[int] = None):
-        """Feed one measured wall: the scheduling EWMA always, plus the
-        cost observatory (step ledger record + residual vs the prior)."""
-        ewma.observe(bucket, us)
-        try:
-            from ...telemetry import costmodel as _costmodel
-            _costmodel.on_step_observed(site, self._cost_key(kind, bucket),
-                                        bucket, us, rows=rows,
-                                        prior_us=ewma.prior(bucket))
-        except Exception:
-            pass
 
     def _compile(self, cache, bucket, jfn, arg_sds, kind):
         comp = cache.get(bucket)
@@ -434,8 +414,7 @@ class DecodeEndpoint:
                                self.pool.k_pool, self.pool.v_pool)
                     jax.block_until_ready(out)
                     self.pool.update_arrays(out[1], out[2])
-                    self._observe_cost(self.prefill_cost, "prefill",
-                                       "decode_prefill", b, _now_us() - t0)
+                    self.prefill_cost.observe(b, _now_us() - t0)
         for b in self.decode_buckets:
             fresh = b not in self._decode_execs
             comp = self._get_decode(b)
@@ -451,8 +430,7 @@ class DecodeEndpoint:
                                self.pool.k_pool, self.pool.v_pool)
                     jax.block_until_ready(out)
                     self.pool.update_arrays(out[1], out[2])
-                    self._observe_cost(self.step_cost, "step",
-                                       "decode_step", b, _now_us() - t0)
+                    self.step_cost.observe(b, _now_us() - t0)
         return n
 
     # ------------------------------------------------------------------
@@ -478,8 +456,7 @@ class DecodeEndpoint:
             out = int(onp.asarray(next_id)[0])     # sync point
         self.pool.update_arrays(k, v)
         dt = _now_us() - t0
-        self._observe_cost(self.prefill_cost, "prefill", "decode_prefill",
-                           S, dt, rows=n)
+        self.prefill_cost.observe(S, dt)
         self.stats.record_prefill(dt)
         return out
 
@@ -524,8 +501,7 @@ class DecodeEndpoint:
                 out = [onp.asarray(a) for a in picked]
         self.pool.update_arrays(k, v)
         dt = _now_us() - t0
-        self._observe_cost(self.step_cost, "step", "decode_step",
-                           B, dt, rows=n)
+        self.step_cost.observe(B, dt)
         commits = int(valid.sum())
         ctx = (int(ctx_live), n * self.max_seq_len)
         self.last_step = {"commits": commits, "ctx_live": ctx[0],

@@ -38,7 +38,7 @@ thread (outside the server lock) and read during selection (under it).
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from . import bucketing
 from .batcher import EndpointQueue
@@ -48,17 +48,9 @@ __all__ = ["StepCostEWMA", "Tenant", "Router"]
 
 _EST_G = REGISTRY.gauge(
     "mxtpu_step_cost_est_us",
-    "Live per-(endpoint, bucket) step-cost estimate: the cost-model prior "
-    "while a bucket is cold, the measured EWMA once observed.",
+    "Live per-(endpoint, bucket) step-cost estimate: the measured EWMA, "
+    "seeded by warm-up's one execution of every bucket.",
     labelnames=("endpoint", "bucket"))
-
-
-def _cfg(name, default):
-    try:
-        from .. import config
-        return config.get(name, default)
-    except Exception:
-        return default
 
 
 class StepCostEWMA:
@@ -69,35 +61,16 @@ class StepCostEWMA:
     ``estimate(bucket)`` falls back to the nearest observed bucket scaled by
     the row ratio — a crude linear-in-rows model that is only used until the
     real bucket has been observed once.
-
-    With a ``prior`` hook (``bucket -> predicted_us | None``, the learned
-    cost model via ``telemetry.costmodel.make_prior``), never-seen buckets
-    are priced by prediction instead of row-ratio, and a just-seen bucket
-    blends linearly from prior to measured over ``blend_n`` observations
-    (``MXNET_COSTMODEL_BLEND_N`` when unset) — measured always wins once the
-    bucket is warm, so scheduling with a prior converges to exactly the
-    no-prior behavior. The prior is consulted once per bucket and cached;
-    it runs *outside* the internal lock (it may take the ledger ring lock).
     ``name`` labels the live ``mxtpu_step_cost_est_us`` gauge; anonymous
     instances export nothing.
     """
 
-    def __init__(self, alpha: float = 0.25, name: Optional[str] = None,
-                 prior: Optional[Callable[[int], Optional[float]]] = None,
-                 blend_n: Optional[int] = None):
+    def __init__(self, alpha: float = 0.25, name: Optional[str] = None):
         self.alpha = float(alpha)
         self.name = name
-        self._prior_fn = prior
-        self._blend_n_pinned = blend_n
         self._lock = threading.Lock()
         self._est: Dict[int, float] = {}
         self._n: Dict[int, int] = {}
-        self._prior_cache: Dict[int, Optional[float]] = {}
-
-    def _blend_n(self) -> int:
-        if self._blend_n_pinned is not None:
-            return max(0, int(self._blend_n_pinned))
-        return max(0, int(_cfg("MXNET_COSTMODEL_BLEND_N", 5)))
 
     def _gauge(self, bucket: int, value: float):
         if self.name is None:
@@ -109,30 +82,6 @@ class StepCostEWMA:
         except Exception:
             pass
 
-    def _prior_for(self, bucket: int) -> Optional[float]:
-        """Cached prior for a bucket; computed outside ``_lock``."""
-        if self._prior_fn is None:
-            return None
-        with self._lock:
-            if bucket in self._prior_cache:
-                return self._prior_cache[bucket]
-            measured = bucket in self._est
-        try:
-            v = self._prior_fn(bucket)
-        except Exception:
-            v = None
-        if v is not None and (v <= 0 or v != v):
-            v = None
-        with self._lock:
-            self._prior_cache[bucket] = v
-        if v is not None and not measured:
-            self._gauge(bucket, v)
-        return v
-
-    def prior(self, bucket: int) -> Optional[float]:
-        """The (cached) model prior for ``bucket``, or None without one."""
-        return self._prior_for(bucket)
-
     def observe(self, bucket: int, step_us: float):
         with self._lock:
             prev = self._est.get(bucket)
@@ -143,53 +92,29 @@ class StepCostEWMA:
         self._gauge(bucket, est)
 
     def estimate(self, bucket: int) -> float:
-        """Estimated step microseconds for ``bucket``. Cold bucket with a
-        prior: the prediction. Warming bucket (< blend_n observations):
-        linear blend prior -> measured. Otherwise: the measured EWMA, with
-        the legacy nearest-bucket row-ratio (or 0.0 on a fully empty
-        table) when no prior exists."""
+        """Estimated step microseconds for ``bucket``: the measured EWMA,
+        else the nearest observed bucket scaled by the row ratio, else 0.0
+        on an empty table."""
         with self._lock:
             got = self._est.get(bucket)
-            n = self._n.get(bucket, 0)
-        blend_n = self._blend_n() if self._prior_fn is not None else 0
-        prior = None
-        if self._prior_fn is not None and (got is None or n < blend_n):
-            prior = self._prior_for(bucket)
-        if got is None:
-            if prior is not None:
-                return prior
-            with self._lock:
-                if not self._est:
-                    return 0.0
-                nearest = min(self._est, key=lambda b: abs(b - bucket))
-                return self._est[nearest] * (bucket / nearest)
-        if prior is not None and n < blend_n:
-            w = (blend_n - n) / float(blend_n)
-            return w * prior + (1.0 - w) * got
-        return got
+            if got is not None:
+                return got
+            if not self._est:
+                return 0.0
+            nearest = min(self._est, key=lambda b: abs(b - bucket))
+            return self._est[nearest] * (bucket / nearest)
 
     def snapshot(self) -> Dict[int, float]:
         with self._lock:
             return dict(self._est)
 
     def snapshot_detail(self) -> Dict[str, object]:
-        """Measured + prior + blend state per bucket, for /statusz and
-        /costz (``snapshot()`` keeps its legacy measured-only shape)."""
+        """Measured mean and observation count per bucket, for /statusz
+        (``snapshot()`` keeps its measured-only shape)."""
         with self._lock:
-            buckets = sorted(set(self._est) | set(self._prior_cache))
-            detail = {
-                int(b): {
-                    "measured_us": self._est.get(b),
-                    "n": self._n.get(b, 0),
-                    "prior_us": self._prior_cache.get(b),
-                }
-                for b in buckets
-            }
-        blend_n = self._blend_n() if self._prior_fn is not None else 0
-        for b, info in detail.items():
-            info["est_us"] = self.estimate(b)
-        return {"buckets": detail, "prior": self._prior_fn is not None,
-                "blend_n": blend_n}
+            detail = {int(b): {"measured_us": us, "n": self._n.get(b, 0)}
+                      for b, us in sorted(self._est.items())}
+        return {"buckets": detail}
 
 
 class Tenant:

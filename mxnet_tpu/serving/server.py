@@ -513,8 +513,7 @@ class InferenceServer:
                 "slo_ms": t.slo_us / 1000.0 if t.slo_us else None,
                 "slo_target": t.slo_target,
                 "weights_epoch": t.endpoint.weights_epoch,
-                # predicted-vs-measured step pricing, live: measured EWMA,
-                # cost-model prior and blend progress per bucket
+                # live step pricing: measured EWMA and count per bucket
                 "step_cost": t.endpoint.step_cost.snapshot_detail(),
             }
         worst = max((b.state() for b in breakers),

@@ -92,8 +92,8 @@ _config.register("MXNET_HEDGE_BUDGET_RATIO", 0.05, float,
                  "latches the hedge_budget_exhausted flight trigger. <= 0 "
                  "disables hedging.")
 _config.register("MXNET_HEDGE_DELAY_FACTOR", 2.0, float,
-                 "Tail hedging: multiplier on the cost model / EWMA "
-                 "predicted step cost when computing the adaptive hedge "
+                 "Tail hedging: multiplier on the measured (EWMA) step "
+                 "cost when computing the adaptive hedge "
                  "delay (hedge fires only after max(observed p95 latency, "
                  "predicted_step * factor)).")
 _config.register("MXNET_HEDGE_DELAY_MIN_MS", 10.0, float,
@@ -380,9 +380,8 @@ class HedgePolicy:
     The delay is adaptive: ``max(observed p95 of recent end-to-end pool
     latencies, predicted_step_us * MXNET_HEDGE_DELAY_FACTOR)``, floored at
     ``MXNET_HEDGE_DELAY_MIN_MS`` — a hedge should fire only when the primary
-    is *already late* relative to what this workload usually costs, which is
-    exactly the signal the learned cost model prices for cold buckets and
-    the latency ring measures for warm ones.
+    is *already late* relative to what this workload usually costs: the
+    step's measured mean until the latency ring has warmed, the ring after.
     """
 
     _RING = 256

@@ -43,7 +43,7 @@ from .reporter import (PeriodicReporter, periodic_logger, dump,
 from .debug_server import DebugServer
 from .slo import SLOMonitor
 from . import flight, debug_server, slo
-from . import compile_ledger, costmodel, memstats, perf_sentinel
+from . import compile_ledger, memstats, perf_sentinel
 from . import fleet, goodput
 
 __all__ = [
@@ -56,7 +56,7 @@ __all__ = [
     "FlightRecorder", "event", "flight",
     "DebugServer", "debug_server",
     "SLOMonitor", "slo",
-    "compile_ledger", "costmodel", "memstats", "perf_sentinel", "fleet",
+    "compile_ledger", "memstats", "perf_sentinel", "fleet",
     "goodput",
     "counter", "gauge", "histogram", "snapshot", "snapshot_json",
     "prometheus_text", "lint_names",

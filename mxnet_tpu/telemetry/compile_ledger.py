@@ -38,8 +38,8 @@ Two growths ride the same seam (hlolint, see STATIC_ANALYSIS.md):
     retained beside the records as ``module-<fingerprint>.mlir`` (deduped
     by content address, byte-bounded by
     MXNET_COMPILE_LEDGER_TEXT_MAX_BYTES, atomic tmp+rename writes) so
-    ``mxlint --ir`` and autotune feature extraction run offline against
-    the very programs the fleet compiled;
+    ``mxlint --ir`` runs offline against the very programs the fleet
+    compiled;
   - an opt-in live guard (MXNET_IR_GUARD=warn|raise) checks each compile
     against the guarded IR rules — donation silently dropped by XLA
     (IR1000), weights baked in as constants (IR1001) — emitting
@@ -52,7 +52,6 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import re
 import threading
 import time
 import warnings
@@ -64,7 +63,7 @@ from ..analysis.ir.guard import IRGuardError, live_findings as _ir_findings
 from ..analysis.ir import parser as _irparser
 
 __all__ = ["CompileRecord", "IRGuardError", "fingerprint_text",
-           "op_histogram", "lower_and_compile", "record", "recent",
+           "lower_and_compile", "record", "recent",
            "summary", "instrument_eager_jit", "eager_active", "ledger_dir",
            "read_ledger", "reset"]
 
@@ -108,7 +107,6 @@ _RING: deque = deque(maxlen=_RING_CAP)
 _SEEN: Dict[str, float] = {}        # fingerprint -> first-seen compile secs
 _SCANNED: Dict[str, int] = {}       # ledger file path -> bytes consumed
 _SCANNED_DIR: Optional[str] = None  # ledger dir the offsets belong to
-_OP_RE = re.compile(r"\b(?:stablehlo|mhlo|chlo)\.([a-z0-9_]+)\b")
 _LAST_ERRORS: Dict[str, str] = {}   # where -> last swallowed error
 
 
@@ -161,23 +159,6 @@ def fingerprint_text(text: str) -> str:
     location-free text the result is byte-identical to the original
     regex pass, so existing content addresses stay valid."""
     return _irparser.fingerprint(text)
-
-
-def op_histogram(text: str, cap: int = 64) -> Dict[str, int]:
-    """Opcode histogram of a StableHLO module text: ``{op_name: count}``
-    over the ``stablehlo.*`` / ``mhlo.*`` mnemonics. This is the paper's
-    program featurization (op counts over the canonicalized program), and
-    it is captured at compile time because the ledger stores only the
-    sha256 *fingerprint* of the text — the histogram cannot be recovered
-    later. Capped to the ``cap`` most frequent ops to bound record size."""
-    hist: Dict[str, int] = {}
-    for m in _OP_RE.finditer(text):
-        op = m.group(1)
-        hist[op] = hist.get(op, 0) + 1
-    if len(hist) > cap:
-        keep = sorted(hist.items(), key=lambda kv: (-kv[1], kv[0]))[:cap]
-        hist = dict(keep)
-    return hist
 
 
 def _cost_analysis(compiled) -> Dict[str, float]:
@@ -384,17 +365,14 @@ def _run_ir_guard(site: str, key: Optional[Dict], text: Optional[str],
 def record(site: str, fingerprint: Optional[str], lower_s: float,
            compile_s: float, key: Optional[Dict[str, Any]] = None,
            compiled=None, cache_hit: bool = False,
-           ops: Optional[Dict[str, int]] = None,
            donation: Optional[Dict[str, int]] = None) -> CompileRecord:
     """Emit one CompileRecord (ring + metrics + JSONL). Never raises.
 
     ``cache_hit=True`` marks an executable answered by the persistent cache
     (``compile_s`` is then the deserialize time): such records are never
     duplicates and never charge ``mxtpu_compile_duplicate_waste_seconds_total``
-    — nothing was re-spent, the fleet's copy was reused. ``ops`` is the
-    optional :func:`op_histogram` of the lowered module — the cost model's
-    program features. ``donation`` is the optional
-    ``{"requested": n, "aliased": m}`` summary: how many arguments the
+    — nothing was re-spent, the fleet's copy was reused. ``donation`` is the
+    optional ``{"requested": n, "aliased": m}`` summary: how many arguments the
     caller asked to donate vs how many aliases actually survived lowering —
     the durable evidence hlolint's IR1000 reads (the lowered text itself
     carries *no trace* of a dropped donation)."""
@@ -405,8 +383,6 @@ def record(site: str, fingerprint: Optional[str], lower_s: float,
         key={str(k): v for k, v in (key or {}).items()},
         duplicate=False, cache_hit=bool(cache_hit),
     )
-    if ops:
-        rec["ops"] = {str(k): int(v) for k, v in ops.items()}
     if donation:
         rec["donation"] = {str(k): int(v) for k, v in donation.items()}
     if compiled is not None:
@@ -461,12 +437,10 @@ def lower_and_compile(jfn, args, *, site: str,
     lowered = jfn.lower(*args, **(kwargs or {}))
     t1 = time.perf_counter()
     fp = None
-    ops = None
     text = None
     try:
         text = lowered.as_text()
         fp = fingerprint_text(text)
-        ops = op_histogram(text)
     except Exception as e:
         _note("fingerprint", e)
     compiled = None
@@ -498,8 +472,7 @@ def lower_and_compile(jfn, args, *, site: str,
         _note("donation", e)
     try:
         record(site, fp, lower_s=t1 - t0, compile_s=t3 - t2, key=key,
-               compiled=compiled, cache_hit=cache_hit, ops=ops,
-               donation=donation)
+               compiled=compiled, cache_hit=cache_hit, donation=donation)
     except Exception as e:
         _note("record", e)
     d = ledger_dir()

@@ -16,9 +16,6 @@ production RPC server grows eventually:
               bundle right now
   /compilez   compile-ledger view: totals per site, duplicate-fingerprint
               waste, recent records ranked by compile seconds
-  /costz      learned cost model: active artifact version + holdout
-              metrics, top feature importances, per-site residual drift
-              state, per-endpoint predicted-vs-measured bucket tables
   /memz       HBM attribution: device memory_stats() (refreshed on demand)
               reconciled against the registered holder table
   /fleetz     fleet plane (JSON): merged per-replica metrics (local registry
@@ -421,78 +418,6 @@ def compilez(top_n: int = 20) -> str:
     return "\n".join(lines) + "\n"
 
 
-def costz(top_n: int = 12) -> str:
-    """Cost-observatory page: the active model artifact (version, holdout
-    metrics, top feature importances per target), the residual drift state
-    per site, and each attached endpoint's predicted-vs-measured bucket
-    table (prior, measured EWMA, blended estimate)."""
-    from . import costmodel as _costmodel
-    snap = _costmodel.snapshot()
-    lines = [f"costz  ts={time.strftime('%Y-%m-%d %H:%M:%S')} "
-             f"path={snap.get('path') or '(unset)'} "
-             f"prior_enabled={snap.get('prior_enabled')}"]
-    lines.append("")
-    info = snap.get("model")
-    if info is None:
-        why = snap.get("error")
-        lines.append("model: none active"
-                     + (f" (load error: {why})" if why else ""))
-    else:
-        lines.append(f"model: version={info['version']} "
-                     f"schema={info['schema']} "
-                     f"n_samples={info.get('n_samples')} "
-                     f"source={info.get('source') or '-'}")
-        m = _costmodel.active_model()
-        for target, met in sorted((info.get("targets") or {}).items()):
-            lines.append(
-                f"  {target}: n_train={met.get('n_train')} "
-                f"n_holdout={met.get('n_holdout')} "
-                f"holdout_mape={met.get('holdout_mape', '-')} "
-                f"row_ratio_mape={met.get('row_ratio_mape', '-')}")
-            if m is not None:
-                imp = ", ".join(f"{n}={w:+.3f}"
-                                for n, w in m.importances(target, top_n))
-                lines.append(f"    importances: {imp}")
-    res = snap.get("residuals") or {}
-    if res:
-        lines.append("")
-        lines.append("== residual drift (measured / predicted) ==")
-        for site, st in sorted(res.items()):
-            lines.append(
-                f"  {site}: band={st['band']} sustain_n={st['sustain_n']} "
-                f"streak={st['streak']} latched={st['latched']} "
-                f"fired={st['fired']}")
-            for b, info_b in sorted(st.get("buckets", {}).items(),
-                                    key=lambda kv: int(kv[0])):
-                lines.append(
-                    f"    bucket {b}: predicted_us="
-                    f"{info_b.get('predicted_us', '-')} "
-                    f"measured_us={info_b.get('measured_us', '-')} "
-                    f"ratio={info_b.get('ratio', '-')} "
-                    f"n={info_b.get('n', 0):.0f}")
-    for srv in attached_servers():
-        try:
-            h = srv.health()
-        except Exception:
-            continue
-        for name, ep in sorted((h.get("endpoints") or {}).items()):
-            sc = ep.get("step_cost")
-            if not sc:
-                continue
-            lines.append("")
-            lines.append(f"== {name} step cost (blend_n={sc['blend_n']} "
-                         f"prior={sc['prior']}) ==")
-            for b, info_b in sorted(sc.get("buckets", {}).items()):
-                meas = info_b.get("measured_us")
-                prior = info_b.get("prior_us")
-                lines.append(
-                    f"  bucket {b}: est_us={info_b.get('est_us', 0):.1f} "
-                    f"measured_us={'-' if meas is None else f'{meas:.1f}'} "
-                    f"prior_us={'-' if prior is None else f'{prior:.1f}'} "
-                    f"n={info_b.get('n', 0)}")
-    return "\n".join(lines) + "\n"
-
-
 def memz() -> str:
     """HBM-attribution page. Refreshes the device-memory gauges on demand
     (the page IS the scrape) before reconciling the holder table."""
@@ -598,8 +523,6 @@ class _Handler(BaseHTTPRequestHandler):
                            ctype="application/json")
             elif page == "/compilez":
                 self._send(200, compilez())
-            elif page == "/costz":
-                self._send(200, costz())
             elif page == "/memz":
                 self._send(200, memz())
             elif page == "/fleetz":
@@ -609,7 +532,7 @@ class _Handler(BaseHTTPRequestHandler):
                 self._send(200, "mxnet_tpu debug server\n"
                                 "pages: /metricsz[?json=1] /healthz "
                                 "/statusz /tracez /flightz[?dump=1] "
-                                "/compilez /costz /memz /fleetz\n")
+                                "/compilez /memz /fleetz\n")
             else:
                 self._send(404, f"no such page: {page}\n")
                 return

@@ -1,8 +1,8 @@
 """Perf-regression sentinel: EWMA drift detection over step latencies.
 
-The perf gate (tools/perf_gate.py) enforces budgets at release time; this
-module watches the *running* fleet. Every train-step and serving-step
-latency observation feeds a per-stream :class:`DriftDetector`: a slow EWMA
+The benchmark (``BENCHMARK.json``, ``chipbench``) judges a change before it
+lands; this module watches the *running* fleet. Every train-step and
+serving-step latency observation feeds a per-stream :class:`DriftDetector`: a slow EWMA
 tracks the baseline, a fast EWMA tracks "now", and when the fast track sits
 above ``baseline * MXNET_PERF_REGRESSION_RATIO`` for
 ``MXNET_PERF_SUSTAIN_N`` consecutive observations the sentinel emits a

@@ -195,7 +195,9 @@ def test_pool_serves_from_rotation_bitwise(pool3):
     xs = onp.random.RandomState(1).randn(8, 6).astype("float32")
     outs = [pool.predict(name, xs[i], timeout=60).asnumpy()
             for i in range(8)]
-    direct = nets[0](nd.array(xs)).asnumpy()
+    # one blocking predict at a time: every row was served alone, in bucket
+    # 1, so the direct forward runs at batch 1 too
+    direct = [nets[0](nd.array(xs[i:i + 1])).asnumpy()[0] for i in range(8)]
     assert all(onp.array_equal(o, direct[i]) for i, o in enumerate(outs)), \
         "every replica serves bitwise-identical outputs"
 

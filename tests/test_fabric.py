@@ -129,11 +129,15 @@ def test_sharded_buckets_must_divide_by_shard():
 # ---------------------------------------------------------------------------
 def test_sharded_dense_bitwise_through_batcher():
     ref_net, sh_net = _twin(21)
+    # both sides serve every batch in bucket 8, so the same rows are
+    # compared at the same batch size; at bucket 4 over dp=4 each device
+    # would hold one row, which XLA:CPU computes with a matrix-vector
+    # kernel that rounds one ulp away from the reference's batch of 4
     ref = serving.ModelEndpoint("fab_ref", ref_net, input_shapes=[(8,)],
-                                max_batch_size=8)
+                                max_batch_size=8, buckets=[8])
     sl = plan_slices([4])[0]
     ep = ShardedEndpoint("fab_sh", sh_net, input_shapes=[(8,)],
-                         max_batch_size=8, slice_spec=sl)
+                         max_batch_size=8, buckets=[8], slice_spec=sl)
     srv_ref = serving.InferenceServer(batch_timeout_ms=1.0)
     srv_sh = serving.InferenceServer(batch_timeout_ms=1.0)
     try:

@@ -407,7 +407,7 @@ net.initialize(mx.init.Xavier())
 net(nd.array(onp.zeros((2, 6), "float32")))
 srv = serving.InferenceServer(batch_timeout_ms=1.0)
 srv.register(serving.ModelEndpoint("t_fleet_ep", net, input_shapes=(6,),
-                                   max_batch_size=4))
+                                   max_batch_size=4, buckets=(4,)))
 srv.start()
 x = onp.ones((1, 6), "float32")
 srv.submit("t_fleet_ep", x).result(timeout=60)
@@ -437,8 +437,10 @@ def test_fleet_acceptance_journey_and_report(tmp_path, capsys):
         srv = serving.InferenceServer(batch_timeout_ms=20.0, max_queue=64)
         net = _mlp(11)
         nets[rid] = net
+        # one bucket, so the oracle below knows the batch size of every
+        # served row whatever the batcher coalesced
         srv.register(serving.ModelEndpoint(
-            name, net, input_shapes=(6,), max_batch_size=4))
+            name, net, input_shapes=(6,), max_batch_size=4, buckets=(4,)))
         return srv
 
     try:
@@ -460,7 +462,9 @@ def test_fleet_acceptance_journey_and_report(tmp_path, capsys):
             xs = onp.random.RandomState(3).randn(12, 6).astype("float32")
             futs = [pool.submit(name, xs[i]) for i in range(12)]
             outs = [f.result(timeout=60).asnumpy() for f in futs]
-            direct = nets[0](nd.array(xs)).asnumpy()
+            direct = onp.concatenate(
+                [nets[0](nd.array(xs[i:i + 4])).asnumpy()
+                 for i in range(0, 12, 4)])    # at the served bucket
             assert all(onp.array_equal(o, direct[i])
                        for i, o in enumerate(outs))
         finally:

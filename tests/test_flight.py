@@ -327,8 +327,10 @@ def test_healthz_reflects_attached_server_state():
 # ---------------------------------------------------------------------------
 def test_concurrent_scrapes_do_not_perturb_serving():
     net = _small_net(seed=11)
+    # one bucket, so the oracle below runs at the batch size that served
+    # (the same program at another batch size is one ulp away on XLA:CPU)
     ep = serving.ModelEndpoint("t_scrape", net, input_shapes=(3, 8, 8),
-                               max_batch_size=8)
+                               max_batch_size=8, buckets=(8,))
     srv = serving.InferenceServer(batch_timeout_ms=2.0, max_queue=256)
     srv.register(ep, slo_ms=60_000.0)
     srv.start()
@@ -376,7 +378,8 @@ def test_concurrent_scrapes_do_not_perturb_serving():
     assert statuses and all(s == 200 for s in statuses)
     net.hybridize()
     for i, x in enumerate(xs):
-        direct = net(nd.array(x[None])).asnumpy()[0]
+        direct = net(nd.array(
+            serving.bucketing.pad_rows(x[None], 8))).asnumpy()[0]
         assert onp.array_equal(direct, results[i].asnumpy()), \
             f"client {i}: serving output changed under scrape load"
 

@@ -9,8 +9,7 @@ and seeded from another process's JSONL), the memstats holder registry
 (sizers, weakref pruning, reconciliation residuals), the oom flight trigger
 with ranked holder breakdown rendered by tools/flight_inspect.py, the EWMA
 drift sentinel (fires on sustained regression, never on spikes), the
-/compilez and /memz debug pages, tools/compile_report.py, and the
-tools/perf_gate.py budget gate (pure logic + the --check --smoke CI mode).
+/compilez and /memz debug pages, and tools/compile_report.py.
 """
 import gc
 import io
@@ -475,7 +474,7 @@ def test_compilez_and_memz_pages():
 
 
 # ---------------------------------------------------------------------------
-# tools: compile_report + perf_gate
+# tools: compile_report
 # ---------------------------------------------------------------------------
 
 def test_compile_report_rollup_and_render(tmp_path):
@@ -488,8 +487,14 @@ def test_compile_report_rollup_and_render(tmp_path):
         compile_ledger.lower_and_compile(jfn, (aval,), site="train_step")
     finally:
         config.set("MXNET_COMPILE_LEDGER_DIR", "")
+    # a line an older build wrote for a measured step: no compile in it
+    with open(tmp_path / f"ledger-{os.getpid()}.jsonl", "a") as f:
+        f.write(json.dumps({"kind": "step", "site": "serving_step",
+                            "key": {"endpoint": "r", "bucket": 6},
+                            "step_us": 1500.0}) + "\n")
     cr = _import_tool("compile_report")
     records = compile_ledger.read_ledger(str(tmp_path))
+    assert len(records) == 3
     agg = cr.rollup(records)
     assert agg["records"] == 2 and agg["distinct_fingerprints"] == 1
     assert agg["duplicate_fingerprints"] == 1 and agg["dup_waste_s"] > 0
@@ -500,61 +505,3 @@ def test_compile_report_rollup_and_render(tmp_path):
         rc = cr.main([str(tmp_path), "--json"])
     assert rc == 0
     assert json.loads(buf.getvalue())["records"] == 2
-
-
-def test_perf_gate_budget_compare_units():
-    pg = _import_tool("perf_gate")
-    budgets = {"schema": 1, "env": {}, "metrics": {
-        "tput": {"budget": 100.0, "tolerance": 0.2, "direction": "min",
-                 "source": "bench"},
-        "lat": {"budget": 50.0, "tolerance": 0.5, "direction": "max",
-                "source": "loadgen"},
-    }}
-    assert pg.validate_budgets(budgets) == []
-    res = {r["metric"]: r for r in pg.gate(budgets, {"tput": 85.0,
-                                                     "lat": 74.0})}
-    assert res["tput"]["ok"] and res["tput"]["bound"] == 80.0
-    assert res["lat"]["ok"] and res["lat"]["bound"] == 75.0
-    res = {r["metric"]: r for r in pg.gate(budgets, {"tput": 79.0,
-                                                     "lat": 76.0})}
-    assert not res["tput"]["ok"] and not res["lat"]["ok"]
-    # missing measurement is a failure, not a silent pass
-    res = {r["metric"]: r for r in pg.gate(budgets, {"tput": 100.0})}
-    assert not res["lat"]["ok"] and res["lat"]["error"] == "not measured"
-
-
-def test_perf_gate_schema_validation():
-    pg = _import_tool("perf_gate")
-    assert pg.validate_budgets([]) == ["budgets root must be an object"]
-    errs = pg.validate_budgets({"schema": 1, "metrics": {
-        "m": {"budget": -1, "tolerance": 2, "direction": "up",
-              "source": "vibes"}}})
-    assert len(errs) == 4
-    assert pg.validate_budgets({"schema": 1, "metrics": {}}) \
-        == ["metrics must be a non-empty object"]
-
-
-def test_perf_gate_smoke_mode_passes():
-    """Satellite: the fast CI mode validates the committed budgets file and
-    the gate logic without running any benchmark."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(TOOLS, "perf_gate.py"),
-         "--check", "--smoke"],
-        capture_output=True, text=True, cwd=REPO)
-    assert out.returncode == 0, out.stdout + out.stderr
-    tail = json.loads(out.stdout.strip().splitlines()[-1])
-    assert tail == {"perf_gate": "smoke", "metrics": tail["metrics"],
-                    "ok": True}
-    assert tail["metrics"] >= 5
-
-
-def test_perf_gate_committed_budgets_valid():
-    pg = _import_tool("perf_gate")
-    with open(os.path.join(REPO, "PERF_BUDGETS.json")) as f:
-        budgets = json.load(f)
-    assert pg.validate_budgets(budgets) == []
-    # the canonical env pins every knob the measured sources read
-    assert budgets["env"]["JAX_PLATFORMS"] == "cpu"
-    sources = {m["source"] for m in budgets["metrics"].values()}
-    assert sources == {"bench", "loadgen", "eager", "restart", "fabric",
-                       "tailguard"}

@@ -200,6 +200,75 @@ def test_step_cost_ewma_estimates_and_fallback():
     # unobserved bucket: nearest observed, scaled by row ratio
     assert m.estimate(4) == pytest.approx(750.0)
     assert m.snapshot() == {8: 1500.0}
+    # what /statusz shows of it: the measured mean and its count, no more
+    assert m.snapshot_detail() == {
+        "buckets": {8: {"measured_us": 1500.0, "n": 2}}}
+
+
+def _decode_lm(block_length):
+    from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
+    kw = {} if block_length == 1 else {"block_length": block_length,
+                                       "mask_token_id": 96}
+    lm = MoEDecoderLM(num_layers=1, units=32, num_heads=2, num_kv_heads=1,
+                      head_dim=16, expert_hidden=16, num_experts=4,
+                      experts_per_token=2, vocab_size=97, **kw)
+    lm.initialize(mx.init.DeviceNormal(0.05, seed=5))
+    lm.hybridize()
+    return lm
+
+
+def _cold_endpoint(kind):
+    """(endpoint, [(one of its EWMAs, the ladder it prices)])."""
+    if kind == "dense":
+        ep = serving.ModelEndpoint("t_warm_dense", _mlp(seed=47),
+                                   input_shapes=(16,), max_batch_size=8)
+        return ep, [(ep.step_cost, ep.buckets)]
+    ep = serving.DecodeEndpoint(
+        f"t_warm_{kind}", _decode_lm(4 if kind == "decode_blocks" else 1),
+        max_seq_len=32, max_batch_size=4, num_pages=9)
+    return ep, [(ep.step_cost, ep.decode_buckets),
+                (ep.prefill_cost, ep.prefill_buckets)]
+
+
+@pytest.mark.parametrize("kind", ["dense", "decode_causal", "decode_blocks"])
+def test_warmup_observes_every_bucket_of_the_ladder(kind):
+    """The fact the schedulers' prices rest on: after ``warmup()`` no bucket
+    is cold, so ``estimate`` never has to guess from a neighbour."""
+    ep, ladders = _cold_endpoint(kind)
+    try:
+        assert all(ewma.snapshot() == {} for ewma, _ in ladders)
+        ep.warmup()
+        for ewma, ladder in ladders:
+            detail = ewma.snapshot_detail()["buckets"]
+            assert sorted(detail) == sorted(ladder)
+            assert all(d["n"] == 1 and d["measured_us"] > 0
+                       for d in detail.values())
+            assert all(ewma.estimate(b) == detail[b]["measured_us"]
+                       for b in ladder)
+    finally:
+        serving.unregister(ep.name)
+
+
+def test_compile_trigger_keys_keep_their_fields():
+    """``cache/executable_cache.py:build_key`` folds these dicts into the
+    persistent key: a field dropped, added or renamed misses every stored
+    executable, and every restart compiles again."""
+    dense = serving.ModelEndpoint("t_key_dense", _mlp(seed=48),
+                                  input_shapes=(16,), max_batch_size=2)
+    dec = serving.DecodeEndpoint("t_key_dec", _decode_lm(1), max_seq_len=32,
+                                 max_batch_size=2, num_pages=5)
+    try:
+        assert dense._compile_key(2) == {
+            "endpoint": "t_key_dense", "bucket": 2, "dtype": "float32",
+            "device": dense._device_label()}
+        for kind, bucket in (("step", 2), ("prefill", 16)):
+            assert dec._cost_key(kind, bucket) == {
+                "endpoint": "t_key_dec", "kind": kind, "bucket": bucket,
+                "dtype": str(dec.pool_dtype),
+                "device": dec._device_label()}
+    finally:
+        serving.unregister("t_key_dense")
+        serving.unregister("t_key_dec")
 
 
 # ---------------------------------------------------------------------------
@@ -239,8 +308,10 @@ def test_pipelined_concurrent_clients_bitwise_vs_direct():
     """Pipelined + concurrent: outputs still bitwise-equal the hybridized
     direct forward while the prep thread overlaps device steps."""
     net = _mlp(seed=43)
+    # one bucket: the direct forward below runs at the batch size every
+    # row was served at (another batch size is one ulp away on XLA:CPU)
     ep = serving.ModelEndpoint("t_pipe_conc", net, input_shapes=(16,),
-                               max_batch_size=8)
+                               max_batch_size=8, buckets=(8,))
     srv = serving.InferenceServer(batch_timeout_ms=3.0, max_queue=128,
                                   pipeline=True)
     srv.register(ep)
@@ -263,7 +334,7 @@ def test_pipelined_concurrent_clients_bitwise_vs_direct():
         serving.unregister("t_pipe_conc")
     net.hybridize()
     for i, x in enumerate(xs):
-        direct = net(nd.array(x[None])).asnumpy()[0]
+        direct = net(nd.array(bucketing.pad_rows(x[None], 8))).asnumpy()[0]
         assert onp.array_equal(results[i].asnumpy(), direct), f"client {i}"
 
 

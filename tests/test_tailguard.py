@@ -232,8 +232,10 @@ def test_hedged_pool_bitwise_and_accounting():
         net = _mlp(seed=7)            # same seed: replicas serve bitwise-
         nets[rid] = net               # identical outputs, so hedging is safe
         srv = serving.InferenceServer(batch_timeout_ms=1.0, max_queue=256)
+        # one bucket: under load the batcher coalesces the burst into
+        # batches of any size, and the oracle must know the size served
         srv.register(serving.ModelEndpoint(
-            svc, net, input_shapes=(8,), max_batch_size=4))
+            svc, net, input_shapes=(8,), max_batch_size=4, buckets=(4,)))
         return srv
 
     xs = onp.random.RandomState(11).randn(12, 8).astype("float32")
@@ -257,7 +259,8 @@ def test_hedged_pool_bitwise_and_accounting():
         pool.stop(drain=True)
         serving.unregister(svc)
 
-    direct = nets[0](nd.array(xs)).asnumpy()
+    direct = onp.concatenate([nets[0](nd.array(xs[i:i + 4])).asnumpy()
+                              for i in range(0, len(xs), 4)])
     assert all(onp.array_equal(o, direct[i]) for i, o in enumerate(outs))
     hedges = delta["mxtpu_hedge_requests_total"]
     assert hedges >= 1

@@ -11,8 +11,6 @@ recompile storm raises:
     python tools/compile_report.py            # $MXNET_COMPILE_LEDGER_DIR
     python tools/compile_report.py DIR --top 30
     python tools/compile_report.py DIR --json # machine-readable rollup
-    python tools/compile_report.py DIR --features [--format csv|jsonl]
-                                              # featurized cost-model corpus
 
   * where did the wall time go — top-N records by lower+compile seconds;
   * what was wasted — fingerprints compiled more than once, ranked by the
@@ -22,12 +20,9 @@ recompile storm raises:
     where the backend's cost_analysis() reported them (low flops/byte =
     memory-bound, the program to fuse first).
 
-``--features`` instead exports the cost model's featurized training corpus
-(``telemetry.costmodel.export_rows``) as CSV (default) or JSONL — the exact
-matrix ``tools/autotune.py --train`` fits, reproducible outside the process
-that trained it. ``kind="step"`` records (measured step wall, written by
-the cost observatory) are excluded from the compile rollup and included in
-the feature export as ``step_us`` target rows.
+A ledger written by an older build may hold ``kind="step"`` lines (measured
+step wall, no compile; hlolint's committed corpus of records does): they
+are left out of the rollup.
 """
 import argparse
 import json
@@ -43,8 +38,8 @@ def _fmt_s(v):
 
 def rollup(records):
     """Aggregate a record list into the report dict (also the --json body).
-    Cost-model ``kind="step"`` records carry no compile wall and are
-    excluded up front."""
+    An older ledger's ``kind="step"`` records carry no compile wall and
+    are excluded up front."""
     records = [r for r in records if r.get("kind") != "step"]
     sites = {}
     by_fp = {}
@@ -151,32 +146,6 @@ def render(records, top=20):
     return "\n".join(lines)
 
 
-def export_features(records, fmt="csv", out=""):
-    """Write the featurized corpus (one row per trainable sample, target +
-    meta columns first, then the sorted feature union) as CSV or JSONL."""
-    import csv
-    from mxnet_tpu.telemetry import costmodel
-    cols, rows = costmodel.export_rows(records)
-    if not rows:
-        raise SystemExit("no trainable samples in this ledger "
-                         "(no step records and no non-cache-hit compiles)")
-    fh = open(out, "w", encoding="utf-8", newline="") if out else sys.stdout
-    try:
-        if fmt == "jsonl":
-            for row in rows:
-                fh.write(json.dumps(row, sort_keys=True) + "\n")
-        else:
-            w = csv.DictWriter(fh, fieldnames=cols)
-            w.writeheader()
-            w.writerows(rows)
-    finally:
-        if out:
-            fh.close()
-    if out:
-        print(f"wrote {len(rows)} samples x {len(cols)} columns to {out}")
-    return 0
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Render a mxnet_tpu compile-ledger directory "
@@ -188,13 +157,6 @@ def main(argv=None):
                     help="rows in the ranked tables (default 20)")
     ap.add_argument("--json", action="store_true",
                     help="emit the machine-readable rollup instead")
-    ap.add_argument("--features", action="store_true",
-                    help="export the featurized cost-model training corpus "
-                         "instead of the compile report")
-    ap.add_argument("--format", choices=("csv", "jsonl"), default="csv",
-                    help="--features output format (default csv)")
-    ap.add_argument("--out", default="",
-                    help="--features destination file (default stdout)")
     args = ap.parse_args(argv)
 
     from mxnet_tpu.telemetry import compile_ledger
@@ -205,8 +167,6 @@ def main(argv=None):
     records = compile_ledger.read_ledger(d)
     if not records:
         raise SystemExit(f"no ledger-*.jsonl records under {d}")
-    if args.features:
-        return export_features(records, args.format, args.out)
     if args.json:
         print(json.dumps(rollup(records), indent=1, sort_keys=True))
         return 0
