@@ -93,6 +93,39 @@ def _candidates(logits, mask_id):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), conf
 
 
+class _Launched:
+    """An executable call whose result is still on the chip, from
+    ``launch_prefill`` / ``launch_step`` to its ``finish_*``: the bucket it
+    ran at, when it was launched (the cost EWMAs observe launch to result
+    in hand, whatever the host did between), the span it was launched
+    under, which its ``decode.fetch`` hangs under too (the benchmark's
+    readers pair a launch with the fetch of the same parent), and what the
+    counters report of it."""
+
+    __slots__ = ("bucket", "t0", "under", "result", "overlapped", "lanes",
+                 "commits", "ctx_live")
+
+    def __init__(self, bucket: int, *, overlapped: bool = False,
+                 lanes: int = 1, commits: int = 0, ctx_live: int = 0):
+        self.bucket = bucket
+        self.t0 = _now_us()
+        self.under = _telemetry.current_span()
+        self.result = None
+        self.overlapped = overlapped    # a prefill's: a step was in flight
+        self.lanes = lanes
+        self.commits = commits
+        self.ctx_live = ctx_live
+
+    def send_home(self):
+        """Start the result's copy to the host now, behind its call on the
+        chip: the fetch then waits for the chip alone. Asked for at the
+        fetch, each array is a round trip of its own once the chip is done
+        (0.6 ms on a v5e; a block step returns four)."""
+        for a in (self.result if isinstance(self.result, tuple)
+                  else (self.result,)):
+            a.copy_to_host_async()
+
+
 class DecodeEndpoint:
     """A named generative model with bucketed prefill/decode executables.
 
@@ -157,6 +190,7 @@ class DecodeEndpoint:
         self._pf_jfn = None
         self._dec_jfn = None
         self.last_step: Dict[str, object] = {}
+        self._step_in_flight = False    # between launch_step and finish_step
         self._probe()
         self.pool = PagedKVPool(name, int(block.num_layers),
                                 int(getattr(block, "kv_units", block.units)),
@@ -440,6 +474,13 @@ class DecodeEndpoint:
         """Run one prompt through its sequence-length bucket's prefill
         executable; the sequence's pages fill with K/V and the first
         generated token comes back."""
+        return self.finish_prefill(self.launch_prefill(prompt, table))
+
+    def launch_prefill(self, prompt: Sequence[int],
+                       table: onp.ndarray) -> "_Launched":
+        """The first half of :meth:`prefill`: pack and launch, nothing
+        waited for. The pool is the call's output from here on (see
+        :meth:`launch_step`); :meth:`finish_prefill` takes the handle."""
         n = len(prompt)
         S = bucketing.bucket_for(n, self.prefill_buckets)
         comp = self._get_prefill(S)
@@ -447,17 +488,24 @@ class DecodeEndpoint:
             toks = onp.zeros((1, S), onp.int32)
             toks[0, :n] = prompt
             length = onp.asarray([n], onp.int32)
-        t0 = _now_us()
+        call = _Launched(S, overlapped=self._step_in_flight)
         with _telemetry.span("decode.launch", kind="prefill", bucket=S):
-            next_id, k, v = comp(self._param_datas(), toks, length,
-                                 table.reshape(1, -1), self.pool.k_pool,
-                                 self.pool.v_pool)
-        with _telemetry.span("decode.fetch", kind="prefill"):
-            out = int(onp.asarray(next_id)[0])     # sync point
+            call.result, k, v = comp(
+                self._param_datas(), toks, length, table.reshape(1, -1),
+                self.pool.k_pool, self.pool.v_pool)
         self.pool.update_arrays(k, v)
-        dt = _now_us() - t0
-        self.prefill_cost.observe(S, dt)
-        self.stats.record_prefill(dt)
+        call.send_home()
+        return call
+
+    def finish_prefill(self, call: "_Launched") -> int:
+        """The second half: wait for the first generated token."""
+        with _telemetry.span("decode.fetch", parent=call.under,
+                             kind="prefill"):
+            out = int(onp.asarray(call.result)[0])     # sync point
+            call.result = None      # the device buffer goes here, in a span
+        dt = _now_us() - call.t0
+        self.prefill_cost.observe(call.bucket, dt)
+        self.stats.record_prefill(dt, call.overlapped)
         return out
 
     def decode_step(self, rows: Sequence[tuple]):
@@ -472,6 +520,15 @@ class DecodeEndpoint:
         not is routed to the scratch page like a padding row). Returns
         ``(ids (L,), confidences (L,))`` per row, and leaves on
         ``last_step`` what the step's span and counters report."""
+        return self.finish_step(self.launch_step(rows))
+
+    def launch_step(self, rows: Sequence[tuple]) -> "_Launched":
+        """The first half of :meth:`decode_step`: pack and launch, nothing
+        waited for. The pools the call returned are installed at once: they
+        are futures of the step's writes, and whatever is launched before
+        :meth:`finish_step` (a prefill, in the step's shadow) must read and
+        donate those, not the arrays this call consumed. The chip runs the
+        calls in the order of their launches."""
         n = len(rows)
         L = self.block_length
         B = bucketing.bucket_for(n, self.decode_buckets)
@@ -490,22 +547,37 @@ class DecodeEndpoint:
                 tables[i] = row[2]
                 valid[i] = len(row) < 4 or row[3]
                 ctx_live += row[1]      # the cached positions it attends to
-        t0 = _now_us()
+        call = _Launched(B, lanes=n, commits=int(valid.sum()),
+                         ctx_live=int(ctx_live))
         with _telemetry.span("decode.launch", kind="step", bucket=B):
-            picked, k, v = comp(self._param_datas(), ids, pos, tables,
-                                valid, self.pool.k_pool, self.pool.v_pool)
-        with _telemetry.span("decode.fetch", kind="step"):
-            if L == 1:
-                out = onp.asarray(picked)      # sync point
-            else:
-                out = [onp.asarray(a) for a in picked]
+            call.result, k, v = comp(
+                self._param_datas(), ids, pos, tables, valid,
+                self.pool.k_pool, self.pool.v_pool)
         self.pool.update_arrays(k, v)
-        dt = _now_us() - t0
-        self.step_cost.observe(B, dt)
-        commits = int(valid.sum())
-        ctx = (int(ctx_live), n * self.max_seq_len)
-        self.last_step = {"commits": commits, "ctx_live": ctx[0],
-                          "ctx_capacity": ctx[1]}
+        call.send_home()
+        self._step_in_flight = True
+        return call
+
+    def finish_step(self, call: "_Launched"):
+        """The second half: wait for the step's result; what
+        :meth:`decode_step` returns."""
+        n, L = call.lanes, self.block_length
+        try:
+            with _telemetry.span("decode.fetch", parent=call.under,
+                                 kind="step") as sp:
+                if L == 1:
+                    out = onp.asarray(call.result)      # sync point
+                else:
+                    out = [onp.asarray(a) for a in call.result]
+                call.result = None  # the device buffers go here, in a span
+        finally:
+            self._step_in_flight = False
+        dt = _now_us() - call.t0
+        self.step_cost.observe(call.bucket, dt)
+        ctx = (call.ctx_live, n * self.max_seq_len)
+        self.last_step = {"commits": call.commits, "ctx_live": ctx[0],
+                          "ctx_capacity": ctx[1],
+                          "fetch_wait_us": sp.dur_us}
         # the straggler a grouped expert product waits for against the rows
         # an expert gets on average (every row the executable computes is
         # routed, padding lanes too)
@@ -513,8 +585,9 @@ class DecodeEndpoint:
         if expert_load:
             self.last_step.update(zip(
                 ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
-        self.stats.record_step(dt, n, B, rows=n * L, commits=commits,
-                               expert_load=expert_load, ctx=ctx)
+        self.stats.record_step(dt, n, call.bucket, rows=n * L,
+                               commits=call.commits, expert_load=expert_load,
+                               ctx=ctx, fetch_wait_us=sp.dur_us)
         if L == 1:
             return tuple(int(x) for x in out[:n])
         return [(out[0][i], out[1][i]) for i in range(n)]

@@ -29,6 +29,16 @@ Correctness invariants (the chaos scenario asserts all three):
 Backpressure is lossless: a full client stream pauses the sequence (state
 PAUSED, pages kept, not stepped); the stream's resume callback re-runs it.
 
+A pass launches before it waits. The worker launches the step of the lanes
+that are running, and while the chip runs it admits waiting sequences and
+launches their prefills behind it; only then does it fetch the step's
+tokens, emit them, and fetch and emit the prefills' first tokens
+(``_iteration``). The chip runs the calls in the order of their launches,
+each on the pools the one before it returned, so the tokens are those of a
+pass that waited after every launch; a token still reaches its stream in
+the pass that fetched it, and a sequence admitted in a pass joins the next
+pass's step.
+
 A step need not yield one token a sequence. An endpoint whose model is
 generated by diffusion over blocks (``block_length`` L > 1) steps L rows a
 sequence, and a pass places 0 to L tokens in it: a denoising step fixes the
@@ -343,28 +353,26 @@ class DecodeScheduler:
             s.state == _S_RUNNING for s in self._active)
 
     def _iteration(self, epoch: int) -> str:
-        """One working pass under a ``decode.iteration`` span: admit,
-        prefill what was admitted, build the batch, step, emit. Tells the
-        loop what comes next: ``_EXIT`` once this generation is fenced out
-        or the scheduler stopped, ``_AGAIN`` while its last look under the
-        lock saw more to run (the next pass then starts with ``decode.admit``
-        taking the lock, so that waiting for it is inside a span), else
-        ``_REST``."""
+        """One working pass under a ``decode.iteration`` span, in the order
+        that keeps the chip and this thread busy at once: build the batch of
+        the lanes that are running now and *launch* its step; in the step's
+        shadow, while the chip runs it, admit into the free slots and launch
+        the prefill of each admitted sequence (:meth:`_admit_and_launch`:
+        they queue behind the step on the chip); finish the step and emit
+        its tokens; finish the prefills, which ran while the step's tokens
+        were emitted, and emit their first tokens. A sequence admitted in a
+        pass joins the next pass's step. With nothing running (start, drain,
+        all paused) the pass admits and prefills with no step in flight.
+
+        Tells the loop what comes next: ``_EXIT`` once this generation is
+        fenced out or the scheduler stopped (every lock take checks the
+        fence), ``_AGAIN`` while its last look under the lock saw more to
+        run (the next pass then starts with ``decode.build`` taking the
+        lock, so that waiting for it is inside a span), else ``_REST``."""
         with _telemetry.span("decode.iteration", admits=0, rows=0) as it:
-            with _telemetry.span("decode.admit") as sp:
-                with self._cond:
-                    if self._epoch != epoch or self._state == _STOPPED:
-                        return _EXIT
-                    sp.attrs["waiting"] = len(self._waiting)
-                    admits = self._admit_locked()
-                sp.attrs["admitted"] = it.attrs["admits"] = len(admits)
-            for seq in admits:
-                # requeued by failover: pages intact, nothing to prefill
-                if not seq.prefilled and not self._prefill(seq, epoch):
-                    return _EXIT
             with _telemetry.span("decode.build") as sp:
                 with self._cond:
-                    if self._epoch != epoch:
+                    if self._epoch != epoch or self._state == _STOPPED:
                         return _EXIT
                     # the per-token deadline hop: a sequence whose
                     # end-to-end budget ran out mid-generation is retired
@@ -378,55 +386,71 @@ class DecodeScheduler:
                                 f"after {len(s.emitted)} of {s.max_new} "
                                 "tokens"))
                     seqs = [s for s in self._active if s.state == _S_RUNNING]
-                    if not seqs:
-                        if not admits:
-                            self._cond.wait(0.005)  # pool-blocked
-                        return _REST
                     # what each sends to the step, beside it (no per-lane
                     # unpacking on this path: 64 lanes a pass)
                     rows = [self._row_locked(s) for s in seqs]
                 sp.attrs["rows"] = it.attrs["rows"] = len(seqs)
-            try:
-                _faults.check("decode")
-                with _telemetry.span(
-                        "decode.step",
-                        rows=len(seqs) * self.engine.block_length,
-                        bucket=bucketing.bucket_for(
-                            len(seqs), self.engine.decode_buckets),
-                        placed=sum(map(self._places, seqs, rows))) as sp:
-                    toks = self.engine.decode_step(rows)
-                    sp.attrs.update(self.engine.last_step)
-            except FaultInjected as e:
-                _telemetry.event("decode_fault_absorbed", kind=e.kind,
-                                 endpoint=self.engine.name)
-                return _REST            # transient: re-form and retry
-            except Exception as e:
-                with self._cond:
-                    for s in seqs:
-                        self._fail_seq_locked(s, e)
-                return _REST
-            # one decode step = one unit of real work funding the decode
-            # tier's retry budget (failover requeues spend from it)
-            _tailguard.retry_deposit("decode")
-            with _telemetry.span("decode.emit") as sp:
-                with self._cond:
-                    if self._epoch != epoch:
-                        return _EXIT    # died-and-replaced mid-step: the
-                                        # new generation already owns these
-                                        # sequences; emitting now would dup
-                    live = [(s, row[3], out) for s, row, out
-                            in zip(seqs, rows, toks)
-                            # the others were retired concurrently (cancel)
-                            if s.state in (_S_RUNNING, _S_PAUSED)]
-                    before = self._stats.counters["tokens"]
-                    self._stats.placed(sum(
-                        self._absorb_locked(s, commit, out)
-                        for s, commit, out in live))
-                    self._stats.set_queue_depth(len(self._waiting))
-                    more = self._runnable_locked()
-                    sp.attrs["tokens"] = \
-                        self._stats.counters["tokens"] - before
-        return _AGAIN if more else _REST
+            prefills, toks = [], None
+            if not seqs:
+                prefills = self._admit_and_launch(epoch, it)
+            else:
+                try:
+                    _faults.check("decode")
+                    with _telemetry.span(
+                            "decode.step",
+                            rows=len(seqs) * self.engine.block_length,
+                            bucket=bucketing.bucket_for(
+                                len(seqs), self.engine.decode_buckets),
+                            placed=sum(map(self._places, seqs, rows))) as sp:
+                        step = self.engine.launch_step(rows)
+                        prefills = self._admit_and_launch(epoch, it)
+                        toks = self.engine.finish_step(step)
+                        sp.attrs.update(self.engine.last_step)
+                except FaultInjected as e:
+                    _telemetry.event("decode_fault_absorbed", kind=e.kind,
+                                     endpoint=self.engine.name)
+                    return _REST            # transient: re-form and retry
+                except Exception as e:
+                    # an error that surfaces at the step's fetch is the
+                    # chip's: the prefills launched in its shadow read and
+                    # donated the pools that step returned, so their
+                    # sequences fail with the step's
+                    with self._cond:
+                        for s in seqs + [p[0] for p in prefills or ()]:
+                            self._fail_seq_locked(s, e)
+                    return _REST
+            if prefills is None:
+                return _EXIT
+            if toks is not None:
+                # one decode step = one unit of real work funding the decode
+                # tier's retry budget (failover requeues spend from it)
+                _tailguard.retry_deposit("decode")
+                with _telemetry.span("decode.emit") as sp:
+                    with self._cond:
+                        if self._epoch != epoch:
+                            return _EXIT    # died-and-replaced mid-step:
+                                            # the new generation already
+                                            # owns these sequences;
+                                            # emitting now would dup
+                        live = [(s, row[3], out) for s, row, out
+                                in zip(seqs, rows, toks)
+                                # the others were retired concurrently
+                                if s.state in (_S_RUNNING, _S_PAUSED)]
+                        before = self._stats.counters["tokens"]
+                        self._stats.placed(sum(
+                            self._absorb_locked(s, commit, out)
+                            for s, commit, out in live))
+                        self._stats.set_queue_depth(len(self._waiting))
+                        sp.attrs["tokens"] = \
+                            self._stats.counters["tokens"] - before
+            for launched in prefills:
+                if not self._finish_prefill(*launched, epoch):
+                    return _EXIT
+            with self._cond:
+                if not seqs and not it.attrs["admits"]:
+                    self._cond.wait(0.005)      # pool-blocked
+                    return _REST
+                return _AGAIN if self._runnable_locked() else _REST
 
     # -- what a sequence sends to a step, and takes from it -----------------
     def _row_locked(self, s: "_Seq"):    # mxlint: disable=CONC200
@@ -487,40 +511,81 @@ class DecodeScheduler:
                     self._emit_locked(s, tok, at, sure)
         return len(chosen)
 
-    def _prefill(self, seq: "_Seq", epoch: int) -> bool:
-        """Prefill one admitted sequence and emit its first token, under a
-        ``decode.prefill`` span of the request's own trace. False once this
-        generation is fenced out. Generation by blocks prefills the
-        prompt's whole blocks and emits nothing yet: the ragged tail opens
-        the first block."""
+    def _admit_and_launch(self, epoch: int, it) -> Optional[list]:
+        """Admit from the waiting queue into the free slots and launch the
+        prefill of each admitted sequence, back to back and waiting for
+        none: behind a step in flight they queue on the chip and run while
+        this thread goes on. ``it`` is the pass's span, which these hang
+        under whatever is open around the call. Returns what
+        :meth:`_finish_prefill` takes, per prefill launched; None once this
+        generation is fenced out."""
+        with _telemetry.span("decode.admit", parent=it) as sp:
+            with self._cond:
+                if self._epoch != epoch:
+                    return None
+                sp.attrs["waiting"] = len(self._waiting)
+                admits = self._admit_locked()
+            sp.attrs["admitted"] = it.attrs["admits"] = len(admits)
         L = self.engine.block_length
-        n = len(seq.prompt) // L * L
-        with _telemetry.span(
-                "decode.prefill", trace_id=seq.trace_id, sid=seq.sid,
-                prompt_len=len(seq.prompt),
-                bucket=bucketing.bucket_for(n, self.engine.prefill_buckets),
-                queue_wait_us=_now_us() - seq.enqueue_us):
-            try:
-                tok = self.engine.prefill(
-                    seq.prompt[:n], self.engine.pool.table(seq.sid)) \
-                    if n else None
-            except BaseException as e:
-                with self._cond:
-                    self._fail_seq_locked(seq, e)
-                if not isinstance(e, Exception):
-                    raise               # WorkerKilled et al: thread dies
-                return True
-            seq.prefilled = True
-            with _telemetry.span("decode.emit", tokens=int(L == 1)):
-                with self._cond:
-                    if self._epoch != epoch:
-                        return False
-                    if L == 1:
-                        self._emit_locked(seq, tok)
-                    else:
-                        seq.pos = n
-                        self._start_block_locked(seq)
+        launched = []
+        for seq in admits:
+            if seq.prefilled:
+                continue    # requeued by failover: pages intact
+            # generation by blocks prefills the prompt's whole blocks: the
+            # ragged tail opens the first block
+            n = len(seq.prompt) // L * L
+            with _telemetry.span(
+                    "decode.prefill", trace_id=seq.trace_id, parent=it,
+                    sid=seq.sid, prompt_len=len(seq.prompt),
+                    bucket=bucketing.bucket_for(
+                        n, self.engine.prefill_buckets),
+                    queue_wait_us=_now_us() - seq.enqueue_us) as sp:
+                try:
+                    call = self.engine.launch_prefill(
+                        seq.prompt[:n], self.engine.pool.table(seq.sid)) \
+                        if n else None
+                except BaseException as e:
+                    self._fail_seq(seq, e)      # its own sequence only
+                    continue
+                sp.attrs["overlapped"] = int(call is not None
+                                             and call.overlapped)
+            launched.append((seq, call, sp))
+        return launched
+
+    def _finish_prefill(self, seq: "_Seq", call, under, epoch: int) -> bool:
+        """Wait for a launched prefill and emit its first token, under the
+        ``decode.prefill`` span it was launched under (``under``: the
+        request's own trace). False once this generation is fenced out.
+        Generation by blocks emits nothing yet."""
+        L = self.engine.block_length
+        try:
+            tok = self.engine.finish_prefill(call) \
+                if call is not None else None
+        except BaseException as e:
+            self._fail_seq(seq, e)
+            return True
+        with _telemetry.span("decode.emit", parent=under,
+                             tokens=int(L == 1)):
+            with self._cond:
+                if self._epoch != epoch:
+                    return False
+                if seq.state != _S_RUNNING:
+                    return True     # retired since its launch (stop())
+                seq.prefilled = True
+                if L == 1:
+                    self._emit_locked(seq, tok)
+                else:
+                    seq.pos = len(seq.prompt) // L * L
+                    self._start_block_locked(seq)
         return True
+
+    def _fail_seq(self, seq: "_Seq", error: BaseException):
+        """Fail one sequence on an error of its own prefill; what is no
+        ``Exception`` (WorkerKilled et al) goes on to end the thread."""
+        with self._cond:
+            self._fail_seq_locked(seq, error)
+        if not isinstance(error, Exception):
+            raise error
 
     def _admit_locked(self) -> List[_Seq]:    # mxlint: disable=CONC200
         """EDF admission: pull waiting sequences into free batch slots,

@@ -124,6 +124,11 @@ class DecodeStats:
             # lanes could hold (lanes x max_seq_len)
             "ctx_live": 0, "ctx_capacity": 0,
             "moe.expert_load_max": 0.0, "moe.expert_load_mean": 0.0,
+            # how often a pass's order engages: prefills launched while a
+            # step was in flight, of all; and the time steps' fetches
+            # blocked (the chip set the pace for that long, the host for
+            # the rest)
+            "prefills": 0, "prefills_overlapped": 0, "step_fetch_wait_us": 0,
             **{f"seq_{ev}": 0 for ev in _SEQ_EVENTS},
         }
         self.prefill = LatencyHistogram()
@@ -159,7 +164,7 @@ class DecodeStats:
 
     def record_step(self, dur_us: float, seqs: int, bucket: int, *,
                     rows: int = None, commits: int = None, expert_load=(),
-                    ctx=(0, 0)):
+                    ctx=(0, 0), fetch_wait_us: int = 0):
         """One step executable run over ``seqs`` sequences padded to
         ``bucket``: ``rows`` forwarded (default one a sequence), ``commits``
         of them writing their K/V (default all), and where the model routes
@@ -168,7 +173,9 @@ class DecodeStats:
         / ``_mean`` so that a window's ratio is a difference of sums.
         ``ctx`` = (cached positions the sequences attended to, positions
         their lanes can hold): what the step's attention read of what a
-        gather of all lanes would have."""
+        gather of all lanes would have. ``dur_us`` runs from the step's
+        launch until its result was in hand, ``fetch_wait_us`` is the part of
+        it the fetch blocked."""
         rows = seqs if rows is None else rows
         commits = seqs if commits is None else commits
         with self._lock:
@@ -179,6 +186,7 @@ class DecodeStats:
             self.counters["blocks_committed"] += commits
             self.counters["ctx_live"] += ctx[0]
             self.counters["ctx_capacity"] += ctx[1]
+            self.counters["step_fetch_wait_us"] += fetch_wait_us
             if expert_load:
                 self.counters["moe.expert_load_max"] += expert_load[0]
                 self.counters["moe.expert_load_mean"] += expert_load[1]
@@ -197,8 +205,13 @@ class DecodeStats:
             self.counters["tokens_placed"] += n
         self._m_placed.inc(n)
 
-    def record_prefill(self, dur_us: float):
+    def record_prefill(self, dur_us: float, overlapped: bool = False):
+        """One prefill, ``dur_us`` from its launch until its first token was
+        in hand; ``overlapped`` where it was launched while a step was in
+        flight."""
         with self._lock:
+            self.counters["prefills"] += 1
+            self.counters["prefills_overlapped"] += bool(overlapped)
             self.prefill.record(dur_us)
         self._m_prefill.observe(dur_us)
 

@@ -129,12 +129,18 @@ class Span:
 
 
 @contextmanager
-def span(name: str, trace_id: Optional[str] = None, **attrs):
+def span(name: str, trace_id: Optional[str] = None,
+         parent: Optional[Span] = None, **attrs):
     """Open a nested span. ``trace_id`` adopts an existing trace (cross-thread
     propagation); otherwise the parent's trace is inherited, or a fresh trace
-    is started at the root. Yields the Span (``.trace_id`` is the handle to
-    stamp onto queue items / requests for later adoption)."""
-    parent = _CURRENT.get()
+    is started at the root. ``parent`` names the span this one hangs under
+    where that is not the one open around it: the late half of work whose
+    first half ran under a span that has closed since (a launch and the
+    fetch of its result, with other work between them). Yields the Span
+    (``.trace_id`` is the handle to stamp onto queue items / requests for
+    later adoption)."""
+    if parent is None:
+        parent = _CURRENT.get()
     if trace_id is None:
         if parent is not None:
             trace_id = parent.trace_id
