@@ -2758,11 +2758,13 @@ inline Symbol block_attention(
     const std::string& heads = "null" /* raw JSON */,
     const std::string& kv_heads = "null" /* raw JSON */,
     int64_t block_length = 1,
+    const std::string& sm_scale = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "heads", detail::Raw{heads});
   detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
   detail::Attr(&j, "block_length", static_cast<int64_t>(block_length));
+  detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
   std::vector<Symbol> ins{q, k, v, positions, ctx_acc, ctx_max, ctx_sum};
   return Symbol::Create("block_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
@@ -4373,7 +4375,7 @@ inline Symbol mish(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
-// moe_ffn: positional inputs (x, router, w_gate, w_up, w_down)
+// moe_ffn: positional inputs (x, router, w_gate, w_up, w_down, router_bias)
 inline Symbol moe_ffn(
     const std::string& name,
     Symbol x,
@@ -4381,15 +4383,22 @@ inline Symbol moe_ffn(
     Symbol w_gate,
     Symbol w_up,
     Symbol w_down,
+    Symbol router_bias = Symbol(),
     const std::string& top_k = "null" /* raw JSON */,
     bool norm_topk = true,
     int64_t first_expert = 0,
+    const std::string& n_group = "null" /* raw JSON */,
+    const std::string& topk_group = "null" /* raw JSON */,
+    double routed_scale = 1.0,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "top_k", detail::Raw{top_k});
   detail::Attr(&j, "norm_topk", norm_topk);
   detail::Attr(&j, "first_expert", static_cast<int64_t>(first_expert));
-  std::vector<Symbol> ins{x, router, w_gate, w_up, w_down};
+  detail::Attr(&j, "n_group", detail::Raw{n_group});
+  detail::Attr(&j, "topk_group", detail::Raw{topk_group});
+  detail::Attr(&j, "routed_scale", routed_scale);
+  std::vector<Symbol> ins{x, router, w_gate, w_up, w_down, router_bias};
   return Symbol::Create("moe_ffn", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
 }
@@ -4620,12 +4629,14 @@ inline Symbol multi_head_attention(
     double dropout = 0.0,
     bool causal = false,
     const std::string& use_flash = "null" /* raw JSON */,
+    const std::string& sm_scale = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "heads", static_cast<int64_t>(heads));
   detail::Attr(&j, "dropout", dropout);
   detail::Attr(&j, "causal", causal);
   detail::Attr(&j, "use_flash", detail::Raw{use_flash});
+  detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
   std::vector<Symbol> ins{q, k, v, mask};
   return Symbol::Create("multi_head_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
@@ -4995,12 +5006,14 @@ inline Symbol paged_attention(
     const std::string& heads = "null" /* raw JSON */,
     const std::string& kv_heads = "null" /* raw JSON */,
     const std::string& sm_scale = "null" /* raw JSON */,
+    const std::string& v_dim = "null" /* raw JSON */,
     const std::string& interpret = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "heads", detail::Raw{heads});
   detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
   detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
+  detail::Attr(&j, "v_dim", detail::Raw{v_dim});
   detail::Attr(&j, "interpret", detail::Raw{interpret});
   std::vector<Symbol> ins{q, k_pool, v_pool, tables, lengths, layer};
   return Symbol::Create("paged_attention", name, detail::Inputs(std::move(ins)),
@@ -5334,9 +5347,11 @@ inline Symbol rotary_embedding(
     Symbol x,
     Symbol positions,
     double theta = 10000.0,
+    const std::string& scaling = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "theta", theta);
+  detail::Attr(&j, "scaling", detail::Raw{scaling});
   std::vector<Symbol> ins{x, positions};
   return Symbol::Create("rotary_embedding", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));

@@ -6,4 +6,5 @@ from .vision import get_model
 from .bert import (BERTModel, BERTForPretraining, bert_base, bert_large,
                    shard_for_tensor_parallel)
 from .dlrm import DLRM, dlrm_tiny
-from .moe_lm import MoEDecoderLM
+from .moe_lm import DecoderLM, MoEDecoderLM
+from .mla_lm import MLADecoderLM

@@ -32,13 +32,17 @@ to ``block_length`` rows a sequence:
 What the endpoint learns from the block: ``kv_units`` (the pool's row),
 ``block_length``, ``mask_token_id`` (None: causal, one token a step).
 Weights are (in, out). The math is ``jax.numpy`` on the parameters' arrays.
+
+``DecoderLM`` is what such models share and ``mla_lm.MLADecoderLM`` builds
+on too: embedding, the loop over layers, final RMSNorm, the untied head and
+the three entry points; a subclass brings its parameters and ``_layer``.
 """
 from __future__ import annotations
 
 from ..block import HybridBlock
 from ...ndarray.ndarray import NDArray
 
-__all__ = ["MoEDecoderLM"]
+__all__ = ["DecoderLM", "MoEDecoderLM"]
 
 
 def _one():
@@ -46,7 +50,86 @@ def _one():
     return initializer.One()
 
 
-class MoEDecoderLM(HybridBlock):
+class DecoderLM(HybridBlock):
+    """The trunk of a pre-norm decoder-only LM and its incremental-decode
+    protocol. A subclass sets ``num_layers``, ``units``, ``vocab_size``,
+    ``rms_eps``, ``kv_units``, creates ``embed_weight`` (:meth:`_embed`), its
+    layers' parameters, then ``final_norm`` and ``head_weight``
+    (:meth:`_head`), in that order inside its ``name_scope``, and gives
+    ``_layer(i, x, positions, cache) -> (y, the rows to cache (a tuple: keys
+    and values, or one latent), rows per held expert or None)``."""
+
+    block_length = 1
+    mask_token_id = None
+
+    def _get(self, name, shape, dtype, **kw):
+        p = self.params.get(name, shape=shape, dtype=dtype, **kw)
+        self._reg_params[name] = p       # saved and loaded by name
+        return p
+
+    def _embed(self, dtype):
+        self.embed_weight = self._get("embed_weight",
+                                      (self.vocab_size, self.units), dtype)
+
+    def _head(self, dtype):
+        self.final_norm = self._get("final_norm_gamma", (self.units,), dtype,
+                                    init=_one())
+        self.head_weight = self._get("head_weight",
+                                     (self.units, self.vocab_size), dtype)
+
+    def _run(self, ids, positions, cache=None):
+        """(logits (B, S, V) float32, the rows to cache layer by layer,
+        loads (expert layers, E_held))."""
+        import jax.numpy as jnp
+        from ...ops import nn as ops
+        x = self.embed_weight.data().data[ids]
+        kept, loads = [], []
+        for i in range(self.num_layers):
+            x, rows, load = self._layer(i, x, positions, cache)
+            kept += rows
+            if load is not None:
+                loads.append(load)
+        x = ops.rms_norm(x, self.final_norm.data().data, eps=self.rms_eps)
+        logits = jnp.dot(x, self.head_weight.data().data,
+                         preferred_element_type=jnp.float32)
+        return logits, kept, jnp.stack(loads)
+
+    @staticmethod
+    def _raw(*arrays):
+        import jax.numpy as jnp
+        return [jnp.asarray(a.data if isinstance(a, NDArray) else a)
+                for a in arrays]
+
+    def _whole(self, tokens):
+        import jax.numpy as jnp
+        (ids,) = self._raw(tokens)
+        ids = ids.astype(jnp.int32)
+        positions = jnp.broadcast_to(
+            jnp.arange(ids.shape[1], dtype=jnp.int32), ids.shape)
+        return self._run(ids, positions)
+
+    def forward(self, tokens):
+        return NDArray(self._whole(tokens)[0])
+
+    def prefill_collect(self, tokens):
+        logits, kept, _ = self._whole(tokens)
+        return (logits,) + tuple(kept)
+
+    def decode_step(self, ids, positions, *cache):
+        """``cache`` = (the pool's arrays..., tables)."""
+        import jax.numpy as jnp
+        ids, positions, *cache = self._raw(ids, positions, *cache)
+        causal = ids.ndim == 1      # one row a sequence, as TransformerLM's
+        if causal:
+            ids, positions = ids[:, None], positions[:, None]
+        logits, kept, loads = self._run(ids.astype(jnp.int32),
+                                        positions.astype(jnp.int32), cache)
+        if causal:
+            logits, kept = logits[:, 0], [a[:, 0] for a in kept]
+        return (logits,) + tuple(kept) + (loads,)
+
+
+class MoEDecoderLM(DecoderLM):
     def __init__(self, num_layers=2, units=64, num_heads=4, num_kv_heads=2,
                  head_dim=16, expert_hidden=32, num_experts=8,
                  experts_per_token=2, vocab_size=256, norm_topk=True,
@@ -73,12 +156,10 @@ class MoEDecoderLM(HybridBlock):
         H, D, F = units, head_dim, expert_hidden
 
         def get(name, shape, **kw):
-            p = self.params.get(name, shape=shape, dtype=dtype, **kw)
-            self._reg_params[name] = p       # saved and loaded by name
-            return p
+            return self._get(name, shape, dtype, **kw)
 
         with self.name_scope():
-            self.embed_weight = get("embed_weight", (vocab_size, H))
+            self._embed(dtype)
             self.layers = []
             for i in range(num_layers):
                 self.layers.append({
@@ -95,12 +176,11 @@ class MoEDecoderLM(HybridBlock):
                     "w_up": get(f"l{i}_experts_up_weight", (held, H, F)),
                     "w_down": get(f"l{i}_experts_down_weight", (held, F, H)),
                 })
-            self.final_norm = get("final_norm_gamma", (H,), init=_one())
-            self.head_weight = get("head_weight", (H, vocab_size))
+            self._head(dtype)
 
     # ------------------------------------------------------------------
     def _layer(self, i, x, positions, cache=None):
-        """One block over x (B, S, H): (y, k, v, rows per held expert).
+        """One block over x (B, S, H): (y, (k, v), rows per held expert).
         ``cache`` = (k_pool, v_pool, tables): the rows also attend to their
         sequence's cached positions before ``positions[:, 0]``."""
         from ...ops import nn as ops
@@ -130,53 +210,4 @@ class MoEDecoderLM(HybridBlock):
             h.reshape(B * S, H), p["router"], p["w_gate"], p["w_up"],
             p["w_down"], top_k=self.experts_per_token,
             norm_topk=self.norm_topk, first_expert=self.held_experts[0])
-        return x + y.reshape(B, S, H), k, v, load
-
-    def _run(self, ids, positions, cache=None):
-        """(logits (B, S, V) float32, [k, v per layer], loads (layers, E))."""
-        import jax.numpy as jnp
-        from ...ops import nn as ops
-        x = self.embed_weight.data().data[ids]
-        kvs, loads = [], []
-        for i in range(self.num_layers):
-            x, k, v, load = self._layer(i, x, positions, cache)
-            kvs += [k, v]
-            loads.append(load)
-        x = ops.rms_norm(x, self.final_norm.data().data, eps=self.rms_eps)
-        logits = jnp.dot(x, self.head_weight.data().data,
-                         preferred_element_type=jnp.float32)
-        return logits, kvs, jnp.stack(loads)
-
-    @staticmethod
-    def _raw(*arrays):
-        import jax.numpy as jnp
-        return [jnp.asarray(a.data if isinstance(a, NDArray) else a)
-                for a in arrays]
-
-    def _whole(self, tokens):
-        import jax.numpy as jnp
-        (ids,) = self._raw(tokens)
-        ids = ids.astype(jnp.int32)
-        positions = jnp.broadcast_to(
-            jnp.arange(ids.shape[1], dtype=jnp.int32), ids.shape)
-        return self._run(ids, positions)
-
-    def forward(self, tokens):
-        return NDArray(self._whole(tokens)[0])
-
-    def prefill_collect(self, tokens):
-        logits, kvs, _ = self._whole(tokens)
-        return (logits,) + tuple(kvs)
-
-    def decode_step(self, ids, positions, k_pool, v_pool, tables):
-        import jax.numpy as jnp
-        ids, positions, *cache = self._raw(ids, positions, k_pool, v_pool,
-                                           tables)
-        causal = ids.ndim == 1      # one row a sequence, as TransformerLM's
-        if causal:
-            ids, positions = ids[:, None], positions[:, None]
-        logits, kvs, loads = self._run(ids.astype(jnp.int32),
-                                       positions.astype(jnp.int32), cache)
-        if causal:
-            logits, kvs = logits[:, 0], [a[:, 0] for a in kvs]
-        return (logits,) + tuple(kvs) + (loads,)
+        return x + y.reshape(B, S, H), (k, v), load

@@ -692,8 +692,11 @@ def div_sqrt_dim(x):
 
 @register("multi_head_attention", jit=True)
 def multi_head_attention(q, k, v, mask=None, *, heads=1, dropout=0.0, causal=False,
-                         use_flash=None):
-    """Batched SDPA: q/k/v (N, L, H*D). On TPU the unmasked/causal path runs the
+                         use_flash=None, sm_scale=None):
+    """Batched SDPA: q/k (N, L, H*D), v (N, L, H*Dv), Dv = D unless the values
+    are narrower than the keys (latent attention's plain form: 192 and 128);
+    scores times ``sm_scale`` (default ``1/sqrt(D)``). On TPU the
+    unmasked/causal path runs the
     flash-attention Pallas kernel (ops/pallas/flash_attention.py); padding-mask
     and non-TPU paths use the XLA composite.
 
@@ -708,23 +711,28 @@ def multi_head_attention(q, k, v, mask=None, *, heads=1, dropout=0.0, causal=Fal
     ``mask=jnp.tril(jnp.ones((Lq, Lk), bool))`` instead of ``causal=True``."""
     N, Lq, HD = q.shape
     D = HD // heads
+    Dv = v.shape[-1] // heads
+    stated = sm_scale
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(D)
     qh = q.reshape(N, Lq, heads, D).transpose(0, 2, 1, 3)
     kh = k.reshape(N, -1, heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(N, -1, heads, D).transpose(0, 2, 1, 3)
+    vh = v.reshape(N, -1, heads, Dv).transpose(0, 2, 1, 3)
     if use_flash is None:
         from .pallas.flash_attention import _on_tpu
         use_flash = mask is None and Lq == kh.shape[2] and _on_tpu()
     if use_flash and mask is None and Lq == kh.shape[2]:
         from .pallas.flash_attention import flash_attention
-        out = flash_attention(qh, kh, vh, causal=causal)
-        return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * D)
+        out = flash_attention(qh, kh, vh, causal=causal, sm_scale=sm_scale)
+        return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * Dv)
     if mask is None:
         # same dense SDPA the flash op's sub-tile fallback uses — one copy
         from .pallas.flash_attention import _dense_attention
-        out = _dense_attention(qh, kh, vh, 1.0 / math.sqrt(D), causal)
-        return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * D)
+        out = _dense_attention(qh, kh, vh, float(sm_scale), causal)
+        return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * Dv)
     att = jnp.einsum("nhld,nhmd->nhlm", qh, kh,
-                     preferred_element_type=jnp.float32) / math.sqrt(D)
+                     preferred_element_type=jnp.float32)
+    att = att / math.sqrt(D) if stated is None else att * stated
     if causal:
         # bottom-right aligned for Lq != Lk, same convention as
         # _dense_attention (the last query row sees every key)
@@ -736,7 +744,7 @@ def multi_head_attention(q, k, v, mask=None, *, heads=1, dropout=0.0, causal=Fal
     p = jax.nn.softmax(att, axis=-1).astype(q.dtype)
     out = jnp.einsum("nhlm,nhmd->nhld", p, vh,
                      preferred_element_type=jnp.float32).astype(q.dtype)
-    return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * D)
+    return out.transpose(0, 2, 1, 3).reshape(N, Lq, heads * Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -746,16 +754,67 @@ def multi_head_attention(q, k, v, mask=None, *, heads=1, dropout=0.0, causal=Fal
 _MASKED = -1e30      # underflows to an exactly-zero softmax weight in f32
 
 
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention temperature for a context stretched ``factor``
+    times: ``0.1 * mscale * ln(factor) + 1`` (1 at or under a factor of 1).
+    A model multiplies its softmax scale by the square of the one taken at
+    ``mscale_all_dim``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_frequencies(dim, theta, scaling=None):
+    """(the ``dim // 2`` inverse frequencies as float32 numpy, what cos and
+    sin are multiplied by). ``scaling`` None: ``theta ** (-2i / dim)`` and 1.
+    Else YaRN's (a mapping with ``factor``, ``original_max_position_
+    embeddings``, ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``):
+    each frequency blended between itself and itself / ``factor`` by a linear
+    ramp over the pair index, from the pair that makes ``beta_fast`` turns in
+    the original context (kept as it is, and every faster one) to the pair
+    that makes ``beta_slow`` (divided, and every slower one); cos and sin
+    times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)``."""
+    import numpy as onp
+    exps = onp.arange(0, dim, 2, dtype=onp.float64) / dim
+    inv = float(theta) ** -exps
+    if not scaling:
+        return inv.astype(onp.float32), 1.0
+    sc = dict(scaling)
+    factor = float(sc["factor"])
+    span = float(sc["original_max_position_embeddings"])
+
+    def pair_of(turns):     # the pair index whose wavelength makes ``turns``
+        return dim * math.log(span / (turns * 2 * math.pi)) \
+            / (2 * math.log(float(theta)))
+
+    low = max(math.floor(pair_of(sc.get("beta_fast", 32))), 0)
+    high = min(math.ceil(pair_of(sc.get("beta_slow", 1))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = onp.clip((onp.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    inv = inv * (1.0 - ramp) + inv / factor * ramp
+    scale = yarn_mscale(factor, sc.get("mscale", 1.0)) \
+        / yarn_mscale(factor, sc.get("mscale_all_dim", 0.0) or 0.0)
+    return inv.astype(onp.float32), scale
+
+
 @register("rotary_embedding", jit=True)
-def rotary_embedding(x, positions, *, theta=10000.0):
-    """Rotary positions over the whole head, rotate-half: ``x`` (..., S,
-    heads, D), ``positions`` (..., S) int. Angles and the rotation are
+def rotary_embedding(x, positions, *, theta=10000.0, scaling=None):
+    """Rotary positions over the whole of ``x``'s last axis (a model that
+    rotates a slice of its head passes the slice), rotate-half: ``x`` (..., S,
+    heads, D), ``positions`` (..., S) int. ``scaling``: None, or YaRN's
+    settings (:func:`rotary_frequencies`). Angles and the rotation are
     float32; the result has ``x``'s dtype."""
     D = x.shape[-1]
-    inv = float(theta) ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    ang = positions.astype(jnp.float32)[..., None] * inv
+    if scaling:
+        inv, scale = rotary_frequencies(D, theta, scaling)
+    else:   # on the device in float32: a host's float64 powers round apart
+        inv = float(theta) ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        scale = 1.0
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xa = x.astype(jnp.float32)
     half = jnp.concatenate([-xa[..., D // 2:], xa[..., :D // 2]], -1)
     return (xa * cos + half * sin).astype(x.dtype)
@@ -763,17 +822,20 @@ def rotary_embedding(x, positions, *, theta=10000.0):
 
 @register("block_attention", jit=True)
 def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
-                    ctx_sum=None, *, heads, kv_heads, block_length=1):
+                    ctx_sum=None, *, heads, kv_heads, block_length=1,
+                    sm_scale=None):
     """Attention of rows to their own blocks and to everything before them.
 
-    ``q`` (B, S, heads*D), ``k``/``v`` (B, S, kv_heads*D): the rows' own
-    projections, ``positions`` (B, S) int32. Query head h attends with KV
+    ``q`` (B, S, heads*D), ``k`` (B, S, kv_heads*D), ``v`` (B, S,
+    kv_heads*Dv; Dv = D but for a latent row, whose values are its first
+    columns): the rows' own projections, ``positions`` (B, S) int32; scores
+    times ``sm_scale`` (default ``1/sqrt(D)``). Query head h attends with KV
     head ``h // (heads / kv_heads)``. A row at position i sees a row at
     position j iff ``j // block_length <= i // block_length``: both
     directions inside a block, causal between blocks (``block_length`` 1 is
     the causal mask).
 
-    ``ctx_acc`` (B, S, heads, D), ``ctx_max``, ``ctx_sum`` (B, S, heads), if
+    ``ctx_acc`` (B, S, heads, Dv), ``ctx_max``, ``ctx_sum`` (B, S, heads), if
     given, are a second part every row also attends to, already reduced to
     its unnormalised output, the maximum of its scores and the sum of their
     exponentials under that maximum, all float32: a decode step's cached
@@ -784,11 +846,13 @@ def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
     B, S, _ = q.shape
     G = heads // kv_heads
     D = q.shape[-1] // heads
+    Dv = v.shape[-1] // kv_heads
     qh = q.reshape(B, S, kv_heads, G, D)
     blk = positions // block_length                        # (B, S)
     mask = blk[:, None, :] <= blk[:, :, None]              # (B, q, k)
     s = jnp.einsum("bqhgd,bkhd->bqhgk", qh, k.reshape(B, S, kv_heads, D),
-                   preferred_element_type=jnp.float32) / math.sqrt(D)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(D) if sm_scale is None else s * sm_scale
     s = jnp.where(mask[:, :, None, None], s, _MASKED)
     top = s.max(-1)                                        # (B, q, h, g)
     if ctx_acc is not None:
@@ -797,17 +861,18 @@ def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
     e = jnp.exp(s - top[..., None])
     denom = e.sum(-1)
     out = jnp.einsum("bqhgk,bkhd->bqhgd", e.astype(q.dtype),
-                     v.reshape(B, S, kv_heads, D),
+                     v.reshape(B, S, kv_heads, Dv),
                      preferred_element_type=jnp.float32)
     if ctx_acc is not None:
         w = jnp.exp(ctx_max - top)
         denom = denom + ctx_sum.reshape(top.shape) * w
         out = out + ctx_acc.reshape(out.shape) * w[..., None]
     out = out / denom[..., None]
-    return out.reshape(B, S, heads * D).astype(q.dtype)
+    return out.reshape(B, S, heads * Dv).astype(q.dtype)
 
 
 _GMM_WEIGHT_TILE_BYTES = 4 << 20     # of a (K, N) tile; Mosaic holds two
+_GMM_RESULT_TILE_COLS = 4096         # of a float32 (tm, N) tile; it holds three
 
 
 def _gmm_tiling(m, k, n, itemsize):
@@ -816,14 +881,28 @@ def _gmm_tiling(m, k, n, itemsize):
     2048 x 768 in bfloat16): at 16 rows a group the product is bound by
     reading each held expert's weights once, and one long DMA a group reads
     them at 600 GB/s on a v5e where (128, 128, 128) tiles reach 80 and the
-    compiler's own ``ragged_dot`` 200-240 (microbenchmark, PR 28)."""
+    compiler's own ``ragged_dot`` 200-240 (microbenchmark, PR 28). A matrix
+    that does not fit is cut along K, and a result wider than
+    ``_GMM_RESULT_TILE_COLS`` along N too (a (128, 7168) float32 result tile,
+    its accumulator and two weight tiles pass the 16 MiB a kernel may hold):
+    7168 x 2048 goes as (128, 896, 2048) and 2048 x 7168 as (128, 512, 3584),
+    3.5 MiB of weights a DMA either way; an expert's matrix is still read
+    once, a column block after the other. Swept on a v5e at 16 held experts
+    (PR 32): these two read 654 and 670 GB/s at a step's 2 rows an expert and
+    636 and 594 at a prefill pass's 128, the best or within 3% of it over
+    five legal tilings each ((128, 1792, 1024) 612 and 596, (128, 2048, 512)
+    689 and 506; tiles that hold more than 16 MiB are refused)."""
     tm = 128 if m % 128 == 0 else m if m < 128 and m % 16 == 0 else None
     if tm is None or k % 128 or n % 128:
         return None
+    tn = n
+    while tn > _GMM_RESULT_TILE_COLS and tn % 256 == 0:
+        tn //= 2
     tk = k
-    while tk * n * itemsize > _GMM_WEIGHT_TILE_BYTES and tk % 256 == 0:
+    while tk * tn * itemsize > _GMM_WEIGHT_TILE_BYTES and tk % 256 == 0:
         tk //= 2
-    return (tm, tk, n) if tk * n * itemsize <= _GMM_WEIGHT_TILE_BYTES else None
+    return (tm, tk, tn) if tk * tn * itemsize <= _GMM_WEIGHT_TILE_BYTES \
+        else None
 
 
 def _grouped_matmul(rows, w, sizes):
@@ -851,42 +930,162 @@ def _grouped_matmul(rows, w, sizes):
                    tiling=tiling)
 
 
-@register("moe_ffn", jit=True)
-def moe_ffn(x, router, w_gate, w_up, w_down, *, top_k, norm_topk=True,
-            first_expert=0):
-    """Routed SwiGLU experts over rows ``x`` (T, H): returns (the part of the
-    layer's result that the held experts give (T, H), rows routed to each held
-    expert (E_held,) int32).
-
-    ``router`` (H, E) scores every expert: a float32 softmax over all E, the
-    ``top_k`` largest, divided by their sum under ``norm_topk``. ``w_gate``,
-    ``w_up`` (E_held, H, F) and ``w_down`` (E_held, F, H) are the experts this
-    chip holds, ``first_expert`` onward; rows routed elsewhere add nothing
-    here. No row is dropped and there is no capacity: the (row, expert) pairs
-    are sorted by expert and each expert multiplies exactly its own rows
-    (:func:`_grouped_matmul`), so the work grows with the rows routed and not
-    with rows x experts. Accumulation is float32."""
-    T, _ = x.shape
-    held = w_gate.shape[0]
+def route_softmax(x, router, *, top_k, norm_topk=True):
+    """(weights (T, top_k) float32, experts (T, top_k) int32): a float32
+    softmax of ``x @ router`` over all E experts, the ``top_k`` largest,
+    divided by their sum under ``norm_topk``."""
     probs = jax.nn.softmax(
         jnp.dot(x, router, preferred_element_type=jnp.float32), -1)
     top_p, top_i = lax.top_k(probs, top_k)
     if norm_topk:
         top_p = top_p / top_p.sum(-1, keepdims=True)
-    expert = top_i.reshape(-1) - first_expert
-    here = (expert >= 0) & (expert < held)
-    expert = jnp.where(here, expert, held)          # elsewhere: sorted last
-    order = jnp.argsort(expert, stable=True)
-    sizes = jnp.bincount(expert, length=held + 1)[:held].astype(jnp.int32)
+    return top_p, top_i
+
+
+def route_grouped_sigmoid(x, router, bias, *, top_k, n_group, topk_group,
+                          norm_topk=True, scale=1.0):
+    """(weights, experts) as :func:`route_softmax`, by the group-limited
+    sigmoid rule: scores ``s = sigmoid(x @ router)`` in float32; the choice is
+    made on ``c = s + bias`` (a learned correction (E,) that moves the choice
+    and never the weight): the E experts lie in ``n_group`` equal groups, a
+    group's score is the sum of its two largest ``c``, the ``topk_group`` best
+    groups are kept and of their experts the ``top_k`` largest ``c``. The
+    weights are the chosen experts' ``s``, divided by their sum under
+    ``norm_topk``, times ``scale``."""
+    T = x.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(x, router, preferred_element_type=jnp.float32))
+    c = s + bias.astype(jnp.float32)
+    by_group = c.reshape(T, n_group, -1)
+    group_score = lax.top_k(by_group, 2)[0].sum(-1)            # (T, groups)
+    _, kept = lax.top_k(group_score, topk_group)
+    keep = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], kept].set(True)
+    c = jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(T, -1)
+    _, top_i = lax.top_k(c, top_k)
+    top_s = jnp.take_along_axis(s, top_i, -1)
+    if norm_topk:
+        top_s = top_s / top_s.sum(-1, keepdims=True)
+    return top_s * scale, top_i
+
+
+# (row, expert) pairs one pass of the grouped products takes where a chip
+# holds a share of the experts: a pass's rows, its three float32 products and
+# its weighted result are this long, whatever the rows x top_k routed in all
+_PAIR_BLOCK = 2048
+
+
+def _expert_pass(x, order, weight, sizes, w_gate, w_up, w_down, top_k):
+    """The three grouped products over the pairs ``order`` names (sorted by
+    expert, ``sizes`` a held expert), each result row times its ``weight``."""
     rows = x[order // top_k]
     gate = _grouped_matmul(rows, w_gate, sizes)
     up = _grouped_matmul(rows, w_up, sizes)
     y = _grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down,
                         sizes)
-    weight = jnp.where(here, top_p.reshape(-1), 0.0)[order]
-    y = jnp.where(here[order][:, None], y * weight[:, None], 0.0)
-    out = y[jnp.argsort(order)].reshape(T, top_k, -1).sum(1)
+    # rows past the held experts' pairs are not computed: whatever they hold
+    live = jnp.arange(len(order)) < sizes.sum()
+    return jnp.where(live[:, None], y * weight[:, None], 0.0)
+
+
+def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
+               all_held=False):
+    """The held experts' part of a routed SwiGLU layer over rows ``x`` (T, H),
+    given each row's ``top_k`` experts ``top_i`` and weights ``top_w``:
+    (result (T, H), rows routed to each held expert (E_held,) int32).
+
+    ``w_gate``, ``w_up`` (E_held, H, F) and ``w_down`` (E_held, F, H) are the
+    experts this chip holds, ``first_expert`` onward; rows routed elsewhere add
+    nothing here. No row is dropped and there is no capacity: the (row,
+    expert) pairs are sorted by expert, the held experts' first, and each
+    expert multiplies exactly its own rows (:func:`_grouped_matmul`).
+    ``all_held`` says every expert is here: all T x top_k pairs are then
+    gathered at once. Of a share, the pairs routed here are gathered
+    ``_PAIR_BLOCK`` at a time, as many passes as they fill, and each pass is
+    added to its rows: the work and the scratch grow with the rows routed
+    here, not with all that were routed (a sixteenth of the experts see a
+    sixteenth of 8 x 4,096 prefill rows, whose gathered rows and float32
+    products would be 1.9 GB). The passes are for a share only: adding a
+    pass to its rows costs T x its pairs x H, which a share keeps at a
+    sixteenth of T x T x top_k x H and a chip that holds every expert would
+    pay in full (128 experts of 2,048 x 768, 8 a row, on a v5e: 3.53 ms in
+    one pass against 4.09 in passes at 1,024 rows, 2.84 against 3.00 at 512;
+    PERF.md, PR 32), and it sums a row's pairs in the order they sorted to,
+    where one pass sums them in the row's own order whatever the rest of the
+    batch holds (the step's bitwise contract). Accumulation is float32."""
+    T, H = x.shape
+    top_k = top_i.shape[1]
+    held = w_gate.shape[0]
+    pairs = T * top_k
+    expert = top_i.reshape(-1) - first_expert
+    here = (expert >= 0) & (expert < held)
+    expert = jnp.where(here, expert, held)          # elsewhere: sorted last
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.bincount(expert, length=held + 1)[:held].astype(jnp.int32)
+    weight = jnp.where(here, top_w.reshape(-1), 0.0)[order]
+    if all_held or pairs <= _PAIR_BLOCK:
+        y = _expert_pass(x, order, weight, sizes, w_gate, w_up, w_down, top_k)
+        out = y[jnp.argsort(order)].reshape(T, top_k, -1).sum(1)
+        return out.astype(x.dtype), sizes
+    ends = jnp.cumsum(sizes)
+    pad = -pairs % _PAIR_BLOCK      # the last pass's slice stays inside
+    order = jnp.pad(order, (0, pad))
+    weight = jnp.pad(weight, (0, pad))
+
+    def one_pass(i, out):
+        at = i * _PAIR_BLOCK
+        idx = lax.dynamic_slice_in_dim(order, at, _PAIR_BLOCK)
+        # what of each expert's run of pairs lies in this pass
+        part = jnp.clip(jnp.minimum(ends, at + _PAIR_BLOCK)
+                        - jnp.maximum(ends - sizes, at), 0)
+        y = _expert_pass(x, idx, lax.dynamic_slice_in_dim(
+            weight, at, _PAIR_BLOCK), part, w_gate, w_up, w_down, top_k)
+        # each pair's result to its row, as a product with the 0/1 matrix of
+        # (row, pair): on a v5e a scatter-add of 2,048 rows of 7,168 into
+        # 4,096 takes 3.9 ms, the two products 1.4 (PERF.md, PR 32).
+        # bfloat16 operands carry the float32 result in two parts, so
+        # nothing is rounded before the sum
+        mine = (jnp.arange(T)[:, None] == (idx // top_k)[None, :]) \
+            .astype(x.dtype)
+        parts = [y.astype(x.dtype)]
+        if x.dtype == jnp.bfloat16:
+            parts.append((y - parts[0].astype(jnp.float32)).astype(x.dtype))
+        for part_y in parts:
+            out = out + jnp.dot(
+                mine, part_y, preferred_element_type=jnp.float32,
+                precision=(lax.Precision.DEFAULT if x.dtype == jnp.bfloat16
+                           else lax.Precision.HIGHEST))
+        return out
+
+    out = lax.fori_loop(0, -(-ends[-1] // _PAIR_BLOCK), one_pass,
+                        jnp.zeros((T, H), jnp.float32))
     return out.astype(x.dtype), sizes
+
+
+@register("moe_ffn", jit=True)
+def moe_ffn(x, router, w_gate, w_up, w_down, router_bias=None, *, top_k,
+            norm_topk=True, first_expert=0, n_group=None, topk_group=None,
+            routed_scale=1.0):
+    """Routed SwiGLU experts over rows ``x`` (T, H): returns (the part of the
+    layer's result that the held experts give (T, H), rows routed to each held
+    expert (E_held,) int32).
+
+    ``router`` (H, E) scores every expert, by one of two rules: a float32
+    softmax over all E (:func:`route_softmax`), or, where ``router_bias`` (E,)
+    and ``n_group`` / ``topk_group`` are given, sigmoid scores chosen within
+    the best groups under the correction bias and scaled by ``routed_scale``
+    (:func:`route_grouped_sigmoid`). Either feeds the one expert product,
+    :func:`expert_ffn`, with the experts this chip holds, ``first_expert``
+    onward of the router's E."""
+    if router_bias is None:
+        top_w, top_i = route_softmax(x, router, top_k=top_k,
+                                     norm_topk=norm_topk)
+    else:
+        top_w, top_i = route_grouped_sigmoid(
+            x, router, router_bias, top_k=top_k, n_group=n_group,
+            topk_group=topk_group, norm_topk=norm_topk, scale=routed_scale)
+    return expert_ffn(x, top_w, top_i, w_gate, w_up, w_down,
+                      first_expert=first_expert,
+                      all_held=w_gate.shape[0] == router.shape[1])
 
 
 # ---------------------------------------------------------------------------

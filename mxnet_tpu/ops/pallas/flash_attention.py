@@ -193,6 +193,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
+    Dv = v.shape[-1]        # the values may be narrower than the keys
     bq = min(block_q, S)
     bk = min(block_k, S)
     Sp = -(-S // max(bq, bk)) * max(bq, bk)
@@ -203,7 +204,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         v = jnp.pad(v, pad)
     qr = q.reshape(B * H, Sp, D)
     kr = k.reshape(B * H, Sp, D)
-    vr = v.reshape(B * H, Sp, D)
+    vr = v.reshape(B * H, Sp, Dv)
     num_k = pl.cdiv(Sp, bk)
     grid = (B * H, pl.cdiv(Sp, bq), num_k)
     kernel = functools.partial(_attention_fwd_kernel, sm_scale=sm_scale,
@@ -216,24 +217,24 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Sp, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             scratch((bq, _LANES), jnp.float32),   # running max (lane-bcast)
             scratch((bq, _LANES), jnp.float32),   # running sum
-            scratch((bq, D), jnp.float32),        # output accumulator
+            scratch((bq, Dv), jnp.float32),       # output accumulator
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    out = out.reshape(B, H, Sp, D)[:, :, :S]
+    out = out.reshape(B, H, Sp, Dv)[:, :, :S]
     lse = lse[..., 0].reshape(B, H, Sp)[:, :, :S]
     return out, lse
 
@@ -576,7 +577,10 @@ def _dense_attention(q, k, v, sm_scale, causal):
 @register("flash_attention", jit=True)
 def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     block_q=None, block_k=None, interpret=None):
-    """Fused attention over (B, H, S, D). Pallas kernel on TPU; interpreter
+    """Fused attention over (B, H, S, D). ``v`` may be (B, H, S, Dv) with
+    Dv != D (latent attention's plain form: keys of 192, values of 128): the
+    forward kernel takes it as it is; the backward kernels do not, so that
+    call has no gradient. Pallas kernel on TPU; interpreter
     (still the same kernel) elsewhere so tests exercise identical code.
     Short sequences (S < 512) on the compiled TPU path take a dense XLA
     route instead — measured faster there, and Mosaic rejects sub-tile
@@ -595,11 +599,17 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             (not explicit and q.shape[2] < _MIN_KERNEL_S):
         return _dense_attention(q, k, v, float(sm_scale), bool(causal))
     # None = adaptive default (an EXPLICIT block size is always honored):
-    # 1024/1024 from S>=16K, 512/1024 below (r5 sweep)
+    # 1024/1024 from S>=16K, 512/1024 below (r5 sweep, heads of 64); heads
+    # wider than a lane tile stream more of K a q block, and 1024 rows of q
+    # a block pay for it at any S (keys of 192, values of 128, 128 heads on
+    # a v5e, PR 32: S=4096 12.0 -> 10.0 ms, S=2048 3.8 -> 3.3)
     long_ctx = q.shape[2] >= _LONG_S
     if block_q is None:
-        block_q = _LONG_BLOCK_Q if long_ctx else DEFAULT_BLOCK_Q
+        block_q = _LONG_BLOCK_Q if long_ctx or q.shape[-1] > _LANES \
+            else DEFAULT_BLOCK_Q
     if block_k is None:
         block_k = _LONG_BLOCK_K if long_ctx else DEFAULT_BLOCK_K
-    return _flash(q, k, v, float(sm_scale), bool(causal), int(block_q),
-                  int(block_k), bool(interpret))
+    run = _flash if v.shape[-1] == q.shape[-1] else \
+        lambda *a: _flash_fwd(*a)[0]        # forward only
+    return run(q, k, v, float(sm_scale), bool(causal), int(block_q),
+               int(block_k), bool(interpret))

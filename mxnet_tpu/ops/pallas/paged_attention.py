@@ -36,6 +36,17 @@ operands multiply at ``highest``, bfloat16 operands in one exact MXU pass
 with the probabilities cast to bfloat16 for the product with the values, as
 ``block_attention`` does.
 
+A **latent pool** (``v_pool`` None, ``v_dim`` given) is one pool whose row
+serves as keys *and* values: latent attention in its absorbed form caches one
+compressed row a position (512 + 64 rotary numbers), every query head attends
+to that same row (``kv_heads`` 1: 128 heads of one lane row are the 128 rows
+of one matmul), and the values are the row's first ``v_dim`` columns. Each
+live page is then fetched once, into one buffer, and used twice; the result's
+``acc`` is ``v_dim`` wide. The pool's row is whole lane tiles (576 numbers
+stored 640 wide, zeros after them: a DMA cannot slice an HBM array whose rows
+end inside a tile), and narrower queries are padded with zeros to it.
+Everything else is as above.
+
 Off the TPU the same entry point evaluates the same per-lane math as plain
 ``jax.numpy`` over the lane's pages (``interpret=True`` runs the kernel
 itself under the Pallas interpreter: the tests do).
@@ -88,12 +99,15 @@ def _group_width(kv_dim, head_dim, rows):
     return kv_dim
 
 
-def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
-            acc_ref, m_ref, l_ref, k_buf, v_buf, sems, state,
-            *, page_size, pages_per_block, pages_per_chunk, pages_per_seq,
-            batch, sm_scale):
+def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
+            page_size, pages_per_block, pages_per_chunk, pages_per_seq,
+            batch, sm_scale, v_dim):
+    if v_dim is None:
+        k_hbm, v_hbm, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, state = refs
+    else:       # a latent pool: its row is the keys, its first columns the values
+        k_hbm, acc_ref, m_ref, l_ref, k_buf, sems, state = refs
     b = pl.program_id(0)
-    n_groups, rows, width = acc_ref.shape
+    n_groups, rows, width = q_ref.shape
     block = pages_per_block * page_size      # positions a block of DMAs
     span = pages_per_chunk * page_size       # positions a pass of compute
     layer = layer_ref[0]
@@ -106,8 +120,11 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
                            - i * pages_per_block, pages_per_block)
 
     def page_copies(page, slot, j):
-        return (pltpu.make_async_copy(k_hbm.at[layer, page],
-                                      k_buf.at[slot, j], sems.at[0, slot]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, page],
+                                       k_buf.at[slot, j], sems.at[0, slot])
+        if v_dim is not None:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       v_buf.at[slot, j], sems.at[1, slot]))
 
@@ -129,7 +146,8 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
     @pl.when(b == 0)
     def _first_lane():
         k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        if v_dim is None:
+            v_buf[...] = jnp.zeros_like(v_buf)
         state[0] = 0        # the slot the next block to compute lies in
         state[1] = 0        # whether any block's DMAs have been started
 
@@ -168,7 +186,8 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
             for g in range(n_groups):
                 cols = slice(g * width, (g + 1) * width)
                 k = k_buf[slot, at, :, cols].reshape(span, width)
-                v = v_buf[slot, at, :, cols].reshape(span, width)
+                v = k[:, :v_dim] if v_dim is not None else \
+                    v_buf[slot, at, :, cols].reshape(span, width)
                 q = q_ref[g]
                 s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32,
@@ -195,10 +214,13 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
 
 
 def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
-                    interpret):
+                    v_dim, interpret):
     """``qg`` (B, groups, rows, width), the queries by column group: the
-    kernel's (acc (B, groups, rows, width), m, l (B, groups, rows, 1))."""
+    kernel's (acc (B, groups, rows, width; ``v_dim`` of a latent pool), m, l
+    (B, groups, rows, 1))."""
     B, n_groups, rows, width = qg.shape
+    out_width = width if v_dim is None else v_dim
+    pools = (k_pool,) if v_dim is not None else (k_pool, v_pool)
     _, _, page_size, kv_dim = k_pool.shape
     P = tables.shape[1]
     page_bytes = page_size * kv_dim * k_pool.dtype.itemsize
@@ -210,7 +232,7 @@ def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
     kernel = functools.partial(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         pages_per_chunk=pages_per_chunk, pages_per_seq=P, batch=B,
-        sm_scale=sm_scale)
+        sm_scale=sm_scale, v_dim=v_dim)
     lane = lambda b, *_: (b, 0, 0, 0)     # and the prefetched scalars
     buf = pltpu.VMEM((2, pages_per_block, page_size, kv_dim), k_pool.dtype)
     return pl.pallas_call(
@@ -218,16 +240,16 @@ def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
-            in_specs=[pl.BlockSpec((None, n_groups, rows, width), lane),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[pl.BlockSpec((None, n_groups, rows, width), lane),
+            in_specs=[pl.BlockSpec((None, n_groups, rows, width), lane)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=[pl.BlockSpec((None, n_groups, rows, out_width), lane),
                        pl.BlockSpec((None, n_groups, rows, 1), lane),
                        pl.BlockSpec((None, n_groups, rows, 1), lane)],
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((2,), jnp.int32)]),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((len(pools), 2)),
+               pltpu.SMEM((2,), jnp.int32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_groups, rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_groups, rows, out_width), jnp.float32),
             jax.ShapeDtypeStruct((B, n_groups, rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, n_groups, rows, 1), jnp.float32)],
         # lanes in order: a lane's last block starts the next lane's first
@@ -235,16 +257,18 @@ def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(layer.reshape(1), lengths, tables.reshape(-1), qg, k_pool, v_pool)
+    )(layer.reshape(1), lengths, tables.reshape(-1), qg, *pools)
 
 
-def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale):
+def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
+                   v_dim):
     """The kernel's result as plain ``jax.numpy``: each lane's pages read
     through its table, one masked pass over all of them."""
     B, n_groups, rows, width = qg.shape
     C = tables.shape[1] * k_pool.shape[2]
     k = k_pool[layer][tables].reshape(B, C, n_groups, width)
-    v = v_pool[layer][tables].reshape(B, C, n_groups, width)
+    v = k[..., :v_dim] if v_dim is not None else \
+        v_pool[layer][tables].reshape(B, C, n_groups, width)
     prec = _precision(qg.dtype)
     s = jnp.einsum("bgrw,bcgw->bgrc", qg, k, precision=prec,
                    preferred_element_type=jnp.float32) * sm_scale
@@ -259,13 +283,18 @@ def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale):
 
 
 def _attend(q, k_pool, v_pool, tables, lengths, layer, *, heads, kv_heads,
-            sm_scale, context):
+            sm_scale, v_dim, context):
     """:func:`paged_attention` over arrays alone: the queries laid out by
     column group, ``context`` (the kernel or the plain expression) over them,
     and its result back by head."""
     B, L, _ = q.shape
     kv_dim = k_pool.shape[-1]
     D = kv_dim // kv_heads
+    if v_dim is not None and q.shape[-1] < heads * D:
+        # queries of a latent narrower than the pool's padded row
+        q = q.reshape(B, L, heads, -1)
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, D - q.shape[-1]),)) \
+            .reshape(B, L, heads * D)
     G = heads // kv_heads
     width = _group_width(kv_dim, D, heads * L)
     per = width // D                    # KV heads a column group
@@ -281,12 +310,15 @@ def _attend(q, k_pool, v_pool, tables, lengths, layer, *, heads, kv_heads,
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, -rows % tile), (0, 0)))
     acc, m, l = context(qg, k_pool, v_pool, tables.astype(jnp.int32),
                         lengths.astype(jnp.int32),
-                        jnp.asarray(layer, jnp.int32), sm_scale=sm_scale)
-    # back to (B, L, heads, ...): a head's own D columns of its group
-    acc = acc[:, :, :rows].reshape(B, n_groups, per, G, L, per, D)
+                        jnp.asarray(layer, jnp.int32), sm_scale=sm_scale,
+                        v_dim=v_dim)
+    # back to (B, L, heads, ...): a head's own D columns of its group (of a
+    # latent pool, one group of one head: all ``v_dim`` columns)
+    Dv = acc.shape[-1] // per
+    acc = acc[:, :, :rows].reshape(B, n_groups, per, G, L, per, Dv)
     own = jnp.arange(per)
-    acc = acc[:, :, own, :, :, own]     # (per, B, groups, G, L, D)
-    acc = acc.transpose(1, 4, 2, 0, 3, 5).reshape(B, L, heads, D)
+    acc = acc[:, :, own, :, :, own]     # (per, B, groups, G, L, Dv)
+    acc = acc.transpose(1, 4, 2, 0, 3, 5).reshape(B, L, heads, Dv)
 
     def rows_to_heads(x):
         x = x[:, :, :rows, 0].reshape(B, n_groups, per, G, L)
@@ -296,14 +328,14 @@ def _attend(q, k_pool, v_pool, tables, lengths, layer, *, heads, kv_heads,
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(heads, kv_heads, sm_scale, context):
+def _jitted(heads, kv_heads, sm_scale, v_dim, context):
     """One jitted :func:`_attend` per configuration: a model's layers (and
     its step programs' traces) then share one trace of the kernel a shape.
     Tracing and lowering a kernel per layer took more of an endpoint's
     warm-up than all the rest of it."""
     return jax.jit(functools.partial(
         _attend, heads=heads, kv_heads=kv_heads, sm_scale=sm_scale,
-        context=context))
+        v_dim=v_dim, context=context))
 
 
 _INTERPRETED = functools.partial(_pallas_context, interpret=True)
@@ -312,7 +344,7 @@ _COMPILED = functools.partial(_pallas_context, interpret=False)
 
 @register("paged_attention", jit=True)
 def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
-                    kv_heads=None, sm_scale=None, interpret=None):
+                    kv_heads=None, sm_scale=None, v_dim=None, interpret=None):
     """The context part of a decode step's attention, through the page table.
 
     ``q`` (B, L, heads*D): the step's L rows a lane; ``k_pool``/``v_pool``
@@ -320,9 +352,12 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
     (B, P) int32 physical page ids; ``lengths`` (B,) int32: every row of lane
     b attends to positions ``0..lengths[b]-1`` of its pages and to nothing
     else; ``layer`` (an int or an int32 scalar) the layer of the pools to
-    read. Query head h reads KV head ``h // (heads / kv_heads)``.
+    read. Query head h reads KV head ``h // (heads / kv_heads)``. A latent
+    pool is ``k_pool`` alone (``v_pool`` None, ``kv_heads`` 1): its row is
+    every head's key and its first ``v_dim`` columns their value.
 
-    Returns ``(acc (B, L, heads, D), m (B, L, heads), l (B, L, heads))``, all
+    Returns ``(acc (B, L, heads, D; ``v_dim`` of a latent pool), m (B, L,
+    heads), l (B, L, heads))``, all
     float32: with scores ``s = q.k * sm_scale`` (default ``1/sqrt(D)``),
     ``m = max s`` (``-1e30`` for a lane of length 0), ``l = sum exp(s - m)``
     and ``acc = sum exp(s - m) v``. ``interpret``: None picks the compiled
@@ -336,5 +371,9 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
         context = _COMPILED if _on_tpu() else _dense_context
     else:
         context = _INTERPRETED if interpret else _COMPILED
-    return _jitted(heads, kv_heads, float(sm_scale), context)(
+    if (v_pool is None) != (v_dim is not None):
+        raise ValueError("paged_attention: a latent pool is k_pool alone "
+                         "with v_dim stated; K and V pools state none")
+    return _jitted(heads, kv_heads, float(sm_scale),
+                   None if v_dim is None else int(v_dim), context)(
         q, k_pool, v_pool, tables, lengths, layer)

@@ -266,8 +266,8 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
         # the pools ride as executable arguments: committed single-device
         # arrays are rejected by a sharded AOT call, so place them
         # replicated once; every later update keeps the mesh placement
-        self.pool.update_arrays(jax.device_put(self.pool.k_pool, repl),
-                                jax.device_put(self.pool.v_pool, repl))
+        self.pool.update_arrays(*(jax.device_put(a, repl)
+                                  for a in self.pool.arrays))
 
     def _platform(self) -> str:
         return self._dmesh.mesh.devices.flat[0].platform
@@ -292,9 +292,9 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
             self._placed_params = None
             self._placed_key = None
             repl = self._dmesh.replicated()
-            self.pool.update_arrays(
-                jax.device_put(onp.asarray(self.pool.k_pool), repl),
-                jax.device_put(onp.asarray(self.pool.v_pool), repl))
+            self.pool.update_arrays(*(
+                jax.device_put(onp.asarray(a), repl)
+                for a in self.pool.arrays))
 
     def _param_datas(self):
         import jax
@@ -311,19 +311,22 @@ class ShardedDecodeEndpoint(DecodeEndpoint):
         import jax
         repl = self._dmesh.replicated()
         # batch 1 cannot shard: the whole prefill replicates (bitwise by
-        # construction); 6 args — params tree takes repl as a prefix
+        # construction); params, three operands and the pool's arrays — the
+        # params tree takes repl as a prefix
         return jax.jit(fn, donate_argnums=donate,
-                       in_shardings=(repl,) * 6, out_shardings=repl)
+                       in_shardings=(repl,) * (4 + len(self.pool.arrays)),
+                       out_shardings=repl)
 
     def _jit_decode(self, fn, donate):
         import jax
         repl = self._dmesh.replicated()
         bsh = self._dmesh.sharding(self._batch_axis)
-        # (params, ids, positions, tables, valid, k_pool, v_pool)
-        in_sh = (repl, bsh, bsh, bsh, bsh, repl, repl)
-        # (next_ids, k_pool, v_pool)
+        pools = (repl,) * len(self.pool.arrays)     # K and V, or a latent
+        # (params, ids, positions, tables, valid, the pools)
+        in_sh = (repl, bsh, bsh, bsh, bsh) + pools
+        # (next_ids, the pools)
         return jax.jit(fn, donate_argnums=donate,
-                       in_shardings=in_sh, out_shardings=(bsh, repl, repl))
+                       in_shardings=in_sh, out_shardings=(bsh,) + pools)
 
     def __repr__(self):
         return (f"ShardedDecodeEndpoint({self.name!r}, "

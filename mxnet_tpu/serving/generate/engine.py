@@ -30,7 +30,11 @@ block's K/V in place — or is a denoising step whose keys saw mask tokens and
 are dropped; what comes back per row is the arg-max id other than the mask
 token and its float32 softmax probability, never the logits. The pool's row
 is the block's ``kv_units`` (default ``units``) and its dtype the
-parameters'.
+parameters'. A block that states ``kv_latent`` caches one row a position
+that serves as keys and values (latent attention: ``gluon.model_zoo.mla_lm``):
+its pool is one array and not two, its ``prefill_collect`` returns one row
+set a layer and its ``decode_step`` takes the one pool, and the executables
+carry, write and donate that one array; everything else is the same.
 
 Bitwise contract: every model op is per-row and masked lanes carry exactly
 zero softmax weight, so a row's output depends only on its own tokens and
@@ -62,23 +66,24 @@ def _now_us() -> int:
 
 
 def _step(block, plist, num_layers, page_size, param_datas, ids, positions,
-          tables, valid, k_pool, v_pool):
+          tables, valid, *pools):
     """One traced decode step: run the block's ``decode_step`` on its L rows
     a lane (``ids``/``positions`` (B,) for L = 1, else (B, L)) against the
-    pools as they stand, read through ``tables``; then write the rows' K/V
-    in place for the lanes ``valid`` flags. Returns (logits, whatever the
-    block returned after its K/V, k_pool, v_pool)."""
+    ``pools`` (K and V, or the one latent array) as they stand, read through
+    ``tables``; then write the rows' K/V in place for the lanes ``valid``
+    flags. Returns (logits, whatever the block returned after its K/V, the
+    pools)."""
     import jax.numpy as jnp
     from ...gluon.block import pure_apply
     outs, _, _ = pure_apply(block, plist, param_datas,
-                            (ids, positions, k_pool, v_pool, tables), None,
+                            (ids, positions, *pools, tables), None,
                             training=False, method="decode_step")
-    last = 1 + 2 * num_layers
-    ks = jnp.stack(outs[1:last:2], 0)      # (layers, B[, L], kv)
-    vs = jnp.stack(outs[2:last:2], 0)
-    k_pool, v_pool = write_step((k_pool, v_pool), (ks, vs), tables,
-                                positions, valid, page_size)
-    return outs[0], outs[last:], k_pool, v_pool
+    n = len(pools)
+    last = 1 + n * num_layers
+    rows = tuple(jnp.stack(outs[1 + j:last:n], 0)      # (layers, B[, L], kv)
+                 for j in range(n))
+    pools = write_step(pools, rows, tables, positions, valid, page_size)
+    return outs[0], outs[last:], pools
 
 
 def _candidates(logits, mask_id):
@@ -133,7 +138,7 @@ class DecodeEndpoint:
     ``gluon.model_zoo.bert.TransformerLM``: ``num_layers``/``units``
     attributes, ``prefill_collect(tokens)`` and
     ``decode_step(ids, positions, k_pool, v_pool, tables)``; optionally
-    ``kv_units``,
+    ``kv_units``, ``kv_latent``,
     ``block_length`` and ``mask_token_id`` (module docstring), and after the
     layers' K/V a ``decode_step`` may return the rows routed to each expert,
     (layers, experts), which the step reduces to two numbers.
@@ -197,7 +202,9 @@ class DecodeEndpoint:
                                 self.max_seq_len,
                                 page_size=page_size, num_pages=num_pages,
                                 dtype=self._param_datas()[0].dtype,
-                                device=self.ctx.jax_device())
+                                device=self.ctx.jax_device(),
+                                latent=bool(getattr(block, "kv_latent",
+                                                    False)))
         if self.max_seq_len % self.block_length \
                 or self.pool.page_size % self.block_length:
             raise MXNetError(
@@ -286,16 +293,16 @@ class DecodeEndpoint:
                 if not hasattr(self, "pool") else self.pool.page_size
             causal = self.mask_token_id is None
 
-            def prefill(param_datas, tokens, length, table, k_pool, v_pool):
+            def prefill(param_datas, tokens, length, table, *pools):
                 outs, _, _ = pure_apply(block, plist, param_datas, (tokens,),
                                         None, training=False,
                                         method="prefill_collect")
                 logits = outs[0]                       # (1, S, V)
-                ks = jnp.stack(outs[1::2], 0)[:, 0]    # (layers, S, kv)
-                vs = jnp.stack(outs[2::2], 0)[:, 0]
-                k_pool, v_pool = write_prefill(
-                    (k_pool, v_pool), (ks, vs), table[0], length[0],
-                    page_size)
+                n = len(pools)      # K and V rows a layer, or the latent's
+                rows = tuple(jnp.stack(outs[1 + j::n], 0)[:, 0]
+                             for j in range(n))        # (layers, S, kv)
+                pools = write_prefill(pools, rows, table[0], length[0],
+                                      page_size)
                 if causal:
                     next_id = jnp.argmax(logits[0, length[0] - 1]) \
                         .astype(jnp.int32)
@@ -303,9 +310,9 @@ class DecodeEndpoint:
                     # a block's first tokens come from its first denoising
                     # step: the head's product is never computed here
                     next_id = jnp.zeros((), jnp.int32)
-                return next_id.reshape(1), k_pool, v_pool
+                return (next_id.reshape(1), *pools)
 
-            donate = (4, 5) if self._donate_pools() else ()
+            donate = self._pool_args(4) if self._donate_pools() else ()
             self._pf_jfn = self._jit_prefill(prefill, donate)
         return self._pf_jfn
 
@@ -319,22 +326,23 @@ class DecodeEndpoint:
             num_layers = int(block.num_layers)
             mask_id = self.mask_token_id
 
-            def decode(param_datas, ids, positions, tables, valid,
-                       k_pool, v_pool):
-                logits, aux, k_pool, v_pool = _step(
+            def decode(param_datas, ids, positions, tables, valid, *pools):
+                logits, aux, pools = _step(
                     block, plist, num_layers, page_size, param_datas, ids,
-                    positions, tables, valid, k_pool, v_pool)
+                    positions, tables, valid, *pools)
                 if mask_id is None:
-                    picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    picked = (jnp.argmax(logits, axis=-1).astype(jnp.int32),)
                 else:
                     picked = _candidates(logits, mask_id)
-                    if aux:     # rows routed to each expert, (layers, E):
-                        # the busiest's (mean over layers) and the mean
-                        load = aux[0].astype(jnp.float32)
-                        picked += (load.max(-1).mean(), load.mean())
-                return picked, k_pool, v_pool
+                if aux:     # rows routed to each expert, (layers, E):
+                    # the busiest's (mean over layers) and the mean
+                    load = aux[0].astype(jnp.float32)
+                    picked += (load.max(-1).mean(), load.mean())
+                if len(picked) == 1:
+                    picked = picked[0]
+                return (picked, *pools)
 
-            donate = (5, 6) if self._donate_pools() else ()
+            donate = self._pool_args(5) if self._donate_pools() else ()
             self._dec_jfn = self._jit_decode(decode, donate)
         return self._dec_jfn
 
@@ -343,8 +351,13 @@ class DecodeEndpoint:
     # ------------------------------------------------------------------
     def _pool_sds(self):
         import jax
-        return (jax.ShapeDtypeStruct(self.k_pool_shape, self.pool_dtype),
-                jax.ShapeDtypeStruct(self.k_pool_shape, self.pool_dtype))
+        return tuple(jax.ShapeDtypeStruct(self.k_pool_shape, self.pool_dtype)
+                     for _ in self.pool.arrays)
+
+    def _pool_args(self, first: int):
+        """Argument numbers of the pool's arrays, which follow an
+        executable's other arguments from ``first`` on."""
+        return tuple(range(first, first + len(self.pool.arrays)))
 
     @property
     def k_pool_shape(self):
@@ -445,9 +458,9 @@ class DecodeEndpoint:
                     table = onp.zeros((1, P), onp.int32)
                     t0 = _now_us()
                     out = comp(self._param_datas(), toks, length, table,
-                               self.pool.k_pool, self.pool.v_pool)
+                               *self.pool.arrays)
                     jax.block_until_ready(out)
-                    self.pool.update_arrays(out[1], out[2])
+                    self.pool.update_arrays(*out[1:])
                     self.prefill_cost.observe(b, _now_us() - t0)
         for b in self.decode_buckets:
             fresh = b not in self._decode_execs
@@ -461,9 +474,9 @@ class DecodeEndpoint:
                     valid = onp.zeros((b,), bool)
                     t0 = _now_us()
                     out = comp(self._param_datas(), ids, pos, tables, valid,
-                               self.pool.k_pool, self.pool.v_pool)
+                               *self.pool.arrays)
                     jax.block_until_ready(out)
-                    self.pool.update_arrays(out[1], out[2])
+                    self.pool.update_arrays(*out[1:])
                     self.step_cost.observe(b, _now_us() - t0)
         return n
 
@@ -490,10 +503,10 @@ class DecodeEndpoint:
             length = onp.asarray([n], onp.int32)
         call = _Launched(S, overlapped=self._step_in_flight)
         with _telemetry.span("decode.launch", kind="prefill", bucket=S):
-            call.result, k, v = comp(
+            call.result, *pools = comp(
                 self._param_datas(), toks, length, table.reshape(1, -1),
-                self.pool.k_pool, self.pool.v_pool)
-        self.pool.update_arrays(k, v)
+                *self.pool.arrays)
+        self.pool.update_arrays(*pools)
         call.send_home()
         return call
 
@@ -550,10 +563,10 @@ class DecodeEndpoint:
         call = _Launched(B, lanes=n, commits=int(valid.sum()),
                          ctx_live=int(ctx_live))
         with _telemetry.span("decode.launch", kind="step", bucket=B):
-            call.result, k, v = comp(
+            call.result, *pools = comp(
                 self._param_datas(), ids, pos, tables, valid,
-                self.pool.k_pool, self.pool.v_pool)
-        self.pool.update_arrays(k, v)
+                *self.pool.arrays)
+        self.pool.update_arrays(*pools)
         call.send_home()
         self._step_in_flight = True
         return call
@@ -565,10 +578,10 @@ class DecodeEndpoint:
         try:
             with _telemetry.span("decode.fetch", parent=call.under,
                                  kind="step") as sp:
-                if L == 1:
-                    out = onp.asarray(call.result)      # sync point
-                else:
+                if isinstance(call.result, tuple):      # sync point
                     out = [onp.asarray(a) for a in call.result]
+                else:
+                    out = [onp.asarray(call.result)]
                 call.result = None  # the device buffers go here, in a span
         finally:
             self._step_in_flight = False
@@ -580,16 +593,18 @@ class DecodeEndpoint:
                           "fetch_wait_us": sp.dur_us}
         # the straggler a grouped expert product waits for against the rows
         # an expert gets on average (every row the executable computes is
-        # routed, padding lanes too)
-        expert_load = tuple(float(a) for a in out[2:]) if L > 1 else ()
+        # routed, padding lanes too): after the ids (and, of a block step,
+        # their confidences)
+        expert_load = tuple(float(a) for a in out[1 + (L > 1):])
         if expert_load:
             self.last_step.update(zip(
                 ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
         self.stats.record_step(dt, n, call.bucket, rows=n * L,
                                commits=call.commits, expert_load=expert_load,
-                               ctx=ctx, fetch_wait_us=sp.dur_us)
+                               ctx=ctx, ctx_bytes=ctx[0] * self.pool.row_bytes,
+                               fetch_wait_us=sp.dur_us)
         if L == 1:
-            return tuple(int(x) for x in out[:n])
+            return tuple(int(x) for x in out[0][:n])
         return [(out[0][i], out[1][i]) for i in range(n)]
 
     def snapshot(self) -> Dict:

@@ -11,7 +11,18 @@ compiled prefill/decode-step programs are independent of pool contents and
 of which sequence owns which page.
 
 Layout: ``(num_layers, num_pages, page_size, kv_dim)`` per pool (one for K,
-one for V). **Page 0 is reserved as a scratch page** and never allocated:
+one for V). A **latent pool** (``latent=True``) is one array and not two: its
+row is a position's compressed latent (latent attention caches 512 + 64
+rotary numbers a position a layer, shared by all heads), which the decode
+step's attention reads once and uses as the keys and, in its first columns,
+as the values; it is allocated, written, donated and compacted once, and
+everything below holds for it with ``arrays`` one long. Its rows lie on whole
+lane tiles: ``kv_dim`` 576 is stored 640 wide, the last 64 columns zero (the
+kernel's DMA takes a page's rows whole, and Mosaic slices an array in HBM
+only along whole tiles of 128 lanes; XLA's own tiled layout would give a
+576-wide row the same 640 in HBM, so the chip holds no byte more, and the
+array now says what it holds). The writes pad a row, the kernel its queries.
+**Page 0 is reserved as a scratch page** and never allocated:
 a step's writes for padded/invalid rows are routed to it, and padded
 page-table entries name it. A decode step reads the pool where it lies
 (``ops/pallas/paged_attention``): each lane's pages through its table, up to
@@ -46,6 +57,8 @@ from ...resilience import faults as _faults
 from ..errors import KVPoolExhausted
 
 __all__ = ["PagedKVPool", "KVPoolExhausted", "write_prefill", "write_step"]
+
+_LANES = 128        # a latent pool's row is whole tiles of this many columns
 
 _POOL_PAGES = _telemetry.gauge(
     "mxtpu_kv_pool_pages",
@@ -100,6 +113,7 @@ def write_prefill(pool, vals, table_row, length, page_size: int):
     import jax
     import jax.numpy as jnp
     from jax import lax
+    vals = _as_wide_as(pool, vals)
     S = jax.tree.leaves(vals)[0].shape[1]
     n_pages = -(-S // page_size)
     pad = n_pages * page_size - S
@@ -152,6 +166,7 @@ def write_step(pool, vals, tables, positions, valid, page_size: int):
     import jax.numpy as jnp
     from jax import lax
     B = tables.shape[0]
+    vals = _as_wide_as(pool, vals)
     if positions.ndim == 1:         # one row a sequence: a block of one
         positions = positions[:, None]
         vals = jax.tree.map(lambda v: v[:, :, None], vals)
@@ -172,6 +187,17 @@ def write_step(pool, vals, tables, positions, valid, page_size: int):
     return lax.fori_loop(0, B, body, pool)
 
 
+def _as_wide_as(pool, vals):
+    """``vals`` with zero columns up to their pool's row (a latent pool's
+    rows are whole lane tiles; K and V rows are their pools' width already)."""
+    import jax
+    import jax.numpy as jnp
+    return jax.tree.map(
+        lambda p, v: v if v.shape[-1] == p.shape[3] else jnp.pad(
+            v, [(0, 0)] * (v.ndim - 1) + [(0, p.shape[3] - v.shape[-1])]),
+        pool, vals)
+
+
 # ---------------------------------------------------------------------------
 # host-side pool management
 # ---------------------------------------------------------------------------
@@ -187,7 +213,7 @@ class PagedKVPool:
     def __init__(self, name: str, num_layers: int, kv_dim: int,
                  max_seq_len: int, page_size: Optional[int] = None,
                  num_pages: Optional[int] = None, dtype="float32",
-                 device=None):
+                 device=None, latent: bool = False):
         import jax
         import jax.numpy as jnp
         if page_size is None:
@@ -211,12 +237,24 @@ class PagedKVPool:
                 f"KV pool {name!r}: one sequence needs {self.pages_per_seq} "
                 f"pages for max_seq_len={max_seq_len} but the pool only has "
                 f"{self.num_pages - 1} usable pages")
-        shape = (self.num_layers, self.num_pages, self.page_size, self.kv_dim)
+        self.latent = bool(latent)
+        # a latent's row on whole lane tiles (module docstring)
+        self.row_dim = -(-self.kv_dim // _LANES) * _LANES if self.latent \
+            else self.kv_dim
+        shape = (self.num_layers, self.num_pages, self.page_size,
+                 self.row_dim)
         # allocated on ``device`` (None: JAX's default), never staged
         # through another one — the pool is the largest array decode holds
+        # the pool's arrays, as the executables take and return them: K and
+        # V, or the one latent array
         with jax.default_device(device):
-            self.k_pool = jnp.zeros(shape, dtype=dtype)
-            self.v_pool = jnp.zeros(shape, dtype=dtype)
+            self.arrays = tuple(jnp.zeros(shape, dtype=dtype)
+                                for _ in range(1 if self.latent else 2))
+        # bytes one cached position is, over all layers and arrays (its
+        # ``kv_dim`` numbers, not a latent row's padding): what a step's
+        # attention must read of each position it attends to
+        self.row_bytes = sum(self.num_layers * self.kv_dim * a.dtype.itemsize
+                             for a in self.arrays)
         self._lock = threading.Lock()
         # LIFO free list, page 0 (scratch) excluded for the pool's lifetime
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
@@ -234,7 +272,21 @@ class PagedKVPool:
         _memstats.register(
             "serving", f"{name}.kv_pool", owner=self,
             device=self._device_label(),
-            sizer=lambda p: int(p.k_pool.nbytes) + int(p.v_pool.nbytes))
+            sizer=lambda p: p.nbytes)
+
+    @property
+    def k_pool(self):
+        """The keys' array; a latent pool's only one."""
+        return self.arrays[0]
+
+    @property
+    def v_pool(self):
+        """The values' array; None of a latent pool, whose row is both."""
+        return None if self.latent else self.arrays[1]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.arrays)
 
     def _device_label(self) -> str:
         try:
@@ -340,16 +392,15 @@ class PagedKVPool:
                 "occupancy": used / max(1, self.num_pages - 1),
                 "sequences": len(self._tables),
                 "pages_per_seq": self.pages_per_seq,
-                "bytes": int(self.k_pool.nbytes) + int(self.v_pool.nbytes),
+                "bytes": self.nbytes,
             }
 
     # -- engine hooks -------------------------------------------------------
-    def update_arrays(self, k_pool, v_pool):
+    def update_arrays(self, *arrays):
         """Install the pool arrays a compiled step returned (worker thread
         only — the single-dispatcher rule, so no lock: defrag() and this
         never run concurrently)."""
-        self.k_pool = k_pool    # mxlint: disable=CONC200
-        self.v_pool = v_pool    # mxlint: disable=CONC200
+        self.arrays = tuple(arrays)    # mxlint: disable=CONC200
 
     def defrag(self) -> int:
         """Compact live pages down to the lowest physical ids.
@@ -372,10 +423,8 @@ class PagedKVPool:
             if moves:
                 old_ids = jnp.asarray([m[0] for m in moves], jnp.int32)
                 new_ids = jnp.asarray([m[1] for m in moves], jnp.int32)
-                self.k_pool = self.k_pool.at[:, new_ids].set(
-                    self.k_pool[:, old_ids])
-                self.v_pool = self.v_pool.at[:, new_ids].set(
-                    self.v_pool[:, old_ids])
+                self.arrays = tuple(a.at[:, new_ids].set(a[:, old_ids])
+                                    for a in self.arrays)
                 for old, new, sid, i in moves:
                     self._tables[sid][i] = new
             n_used = len(order)

@@ -537,7 +537,9 @@ class DecodeScheduler:
             with _telemetry.span(
                     "decode.prefill", trace_id=seq.trace_id, parent=it,
                     sid=seq.sid, prompt_len=len(seq.prompt),
-                    bucket=bucketing.bucket_for(
+                    # the rung it runs at and the rows of it that are the
+                    # prompt's: the rest is padding
+                    tokens=n, bucket=bucketing.bucket_for(
                         n, self.engine.prefill_buckets),
                     queue_wait_us=_now_us() - seq.enqueue_us) as sp:
                 try:

@@ -122,7 +122,7 @@ class DecodeStats:
             "blocks_committed": 0,
             # cached positions the steps' lanes attended to, of those their
             # lanes could hold (lanes x max_seq_len)
-            "ctx_live": 0, "ctx_capacity": 0,
+            "ctx_live": 0, "ctx_capacity": 0, "ctx_bytes": 0,
             "moe.expert_load_max": 0.0, "moe.expert_load_mean": 0.0,
             # how often a pass's order engages: prefills launched while a
             # step was in flight, of all; and the time steps' fetches
@@ -164,7 +164,7 @@ class DecodeStats:
 
     def record_step(self, dur_us: float, seqs: int, bucket: int, *,
                     rows: int = None, commits: int = None, expert_load=(),
-                    ctx=(0, 0), fetch_wait_us: int = 0):
+                    ctx=(0, 0), ctx_bytes: int = 0, fetch_wait_us: int = 0):
         """One step executable run over ``seqs`` sequences padded to
         ``bucket``: ``rows`` forwarded (default one a sequence), ``commits``
         of them writing their K/V (default all), and where the model routes
@@ -173,7 +173,10 @@ class DecodeStats:
         / ``_mean`` so that a window's ratio is a difference of sums.
         ``ctx`` = (cached positions the sequences attended to, positions
         their lanes can hold): what the step's attention read of what a
-        gather of all lanes would have. ``dur_us`` runs from the step's
+        gather of all lanes would have, and ``ctx_bytes`` the bytes of the
+        pool those positions are (positions x the pool's row over all layers
+        and arrays: a latent pool's row once, a K and a V row otherwise):
+        what the step's attention had to read. ``dur_us`` runs from the step's
         launch until its result was in hand, ``fetch_wait_us`` is the part of
         it the fetch blocked."""
         rows = seqs if rows is None else rows
@@ -186,6 +189,7 @@ class DecodeStats:
             self.counters["blocks_committed"] += commits
             self.counters["ctx_live"] += ctx[0]
             self.counters["ctx_capacity"] += ctx[1]
+            self.counters["ctx_bytes"] += ctx_bytes
             self.counters["step_fetch_wait_us"] += fetch_wait_us
             if expert_load:
                 self.counters["moe.expert_load_max"] += expert_load[0]

@@ -166,3 +166,92 @@ def test_the_two_parts_merge_into_one_softmax(dtype, L, heads, kv_heads, D,
                       precision="highest").reshape(B, L, heads * D)
     assert got.dtype == dtype
     onp.testing.assert_allclose(_f32(got), want, atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# a latent pool: one array whose row is every head's key and, in its first
+# columns, their value (latent attention's absorbed form, cut small: 8 heads
+# on a row of 96 + 32 numbers; 200 = 160 + 40 stored 256 wide, in bfloat16)
+# ---------------------------------------------------------------------------
+LATENT = [
+    pytest.param(jnp.float32, 8, 128, 96, 128, 2e-5, id="f32_row128"),
+    pytest.param(jnp.bfloat16, 16, 200, 160, 256, 2e-2, id="bf16_row200in256")]
+
+
+def _latent_case(dtype, heads, row, stored, lengths, seed=0):
+    rng = onp.random.default_rng(seed)
+    B = len(lengths)
+    pool = onp.zeros((LAYERS, PAGES, PAGE, stored), onp.float32)
+    pool[..., :row] = rng.normal(size=(LAYERS, PAGES, PAGE, row))
+    tables = rng.permutation(onp.arange(1, PAGES))[:B * P].reshape(B, P)
+    q = jnp.asarray(rng.normal(size=(B, 1, heads * row)), dtype)
+    return (q, jnp.asarray(pool, dtype), jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _latent_op(heads, v_dim, interpret):
+    return lambda q, pool, tables, lengths: paged_attention(
+        q, pool, None, tables, lengths, LAYER, heads=heads, kv_heads=1,
+        v_dim=v_dim, sm_scale=0.11, interpret=interpret)
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernel", "plain_expression"])
+@pytest.mark.parametrize("dtype,heads,row,v_dim,stored,tol", LATENT)
+def test_a_latent_pool_is_read_as_keys_and_as_values(dtype, heads, row, v_dim,
+                                                     stored, tol, interpret):
+    """Lengths that end inside a page, on one and past one: the kernel's
+    context part is the dense expression's, the values the row's first
+    ``v_dim`` columns, queries narrower than the stored row padded to it."""
+    q, pool, tables, lengths = _latent_case(dtype, heads, row, stored,
+                                            LENGTHS)
+    acc, m, l = _latent_op(heads, v_dim, interpret)(q, pool, tables, lengths)
+    assert acc.shape == (len(LENGTHS), 1, heads, v_dim)
+    k = _f32(pool[LAYER][tables]).reshape(len(LENGTHS), P * PAGE, stored)
+    qh = _f32(q).reshape(len(LENGTHS), heads, row)
+    s = jnp.einsum("bhd,bcd->bhc", qh, k[..., :row],
+                   precision="highest") * 0.11
+    seen = (jnp.arange(P * PAGE)[None] < lengths[:, None])[:, None]
+    s = jnp.where(seen, s, -1e30)
+    want_m = s.max(-1)
+    p = jnp.where(seen, jnp.exp(s - want_m[..., None]), 0.0)
+    want_acc = jnp.einsum("bhc,bcd->bhd", p, k[..., :v_dim],
+                          precision="highest")
+    want_l = p.sum(-1)
+    assert float(jnp.abs(acc[0]).max()) == 0.0 and float(l[0].max()) == 0.0
+    onp.testing.assert_allclose(m[1:, 0], want_m[1:], atol=tol * 10, rtol=tol)
+    onp.testing.assert_allclose(l[1:, 0], want_l[1:], rtol=tol * 5)
+    onp.testing.assert_allclose(acc[1:, 0] / l[1:, 0, :, None],
+                                want_acc[1:] / want_l[1:, :, None], atol=tol)
+
+
+def test_a_latent_lane_depends_on_its_own_pages_and_length_alone():
+    """Bitwise: garbage on the scratch page, past a lane's length and on
+    pages past it changes nothing, and a lane alone in a bucket of one reads
+    what it reads among seven others."""
+    heads, row, v_dim, stored = 8, 128, 96, 128
+    q, pool, tables, lengths = _latent_case(
+        jnp.float32, heads, row, stored, [PAGE * 2 + 3] * 8)
+    op = _latent_op(heads, v_dim, True)
+    alone = op(q[:1], pool, tables[:1], lengths[:1])
+    pos = jnp.arange(P * PAGE).reshape(P, PAGE)
+    dirty = pool.at[:, 0].set(GARBAGE)
+    rows = jnp.where((pos >= lengths[0])[None, :, :, None], GARBAGE,
+                     dirty[:, tables[0]])
+    dirty = dirty.at[:, tables[0]].set(rows)
+    others = jnp.asarray([5, 0, 64, 17, 33, 1, 48, 16], jnp.int32) \
+        .at[3].set(lengths[0])
+    order = onp.asarray([1, 2, 4, 0, 3, 5, 6, 7])
+    among = op(q[order], dirty, tables[order], others)
+    for one, many in zip(alone, among):
+        assert onp.array_equal(onp.asarray(one[0]), onp.asarray(many[3]))
+
+
+def test_a_latent_pool_states_its_value_width():
+    q, pool, tables, lengths = _latent_case(jnp.float32, 8, 128, 128, [5])
+    with pytest.raises(ValueError, match="latent pool"):
+        paged_attention(q, pool, None, tables, lengths, LAYER, heads=8,
+                        kv_heads=1)
+    with pytest.raises(ValueError, match="latent pool"):
+        paged_attention(q, pool, pool, tables, lengths, LAYER, heads=8,
+                        kv_heads=1, v_dim=96)

@@ -211,6 +211,86 @@ def test_decode_step_reads_and_writes_the_pools_in_place(
     assert text.count("tpu_custom_call") == layers
 
 
+# cell deepseek_v3.doc_qa_c64 at its published widths: the latent pool of 5
+# layers, 20,481 pages, rows of 512 + 64 numbers stored 640 wide in bfloat16,
+# 320 pages a lane; 128 query heads of 576 on the one shared row
+LATENT_POOL, LATENT_P = (5, 20481, 16, 640), 320
+
+
+def test_latent_paged_attention_compiles_to_one_kernel(one_chip):
+    """One pool operand, one kernel, nothing of the pool's size beside it,
+    the result 512 wide: each page is fetched once and used as K and as V."""
+    comp = jax.jit(lambda q, pool, tables, lengths: paged_attention(
+        q, pool, None, tables, lengths, 4, heads=128, kv_heads=1, v_dim=512,
+        sm_scale=0.135, interpret=False)).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+                ((64, 1, 128 * 576), jnp.bfloat16),
+                (LATENT_POOL, jnp.bfloat16), ((64, LATENT_P), I32),
+                ((64,), I32))]).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[64,1,128,512]" in text
+    assert comp.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_a_latent_pool_is_written_in_place(one_chip):
+    """The one array donated: a step's rows and a prefill's (576 numbers,
+    padded to the stored 640) are written with no pool-sized copy."""
+    def fn(pool, rows, tables, positions, valid, many, table_row, length):
+        (pool,) = write_step((pool,), (rows,), tables, positions, valid, PAGE)
+        return write_prefill((pool,), (many,), table_row, length, PAGE)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (LATENT_POOL, jnp.bfloat16), ((5, 64, 576), jnp.bfloat16),
+        ((64, LATENT_P), I32), ((64,), I32), ((64,), jnp.bool_),
+        ((5, 4096, 576), jnp.bfloat16), ((LATENT_P,), I32), ((), I32))]
+    comp = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = comp.as_text()
+    assert "input_output_alias" in text.splitlines()[0]
+    makers = set(re.findall(
+        r"= bf16\[5,20481,16,640\]\{[^}]*\} ([\w-]+)\(", text))
+    assert makers and "copy" not in makers, makers
+    assert comp.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# the plain form of latent attention in that cell's prefill: 128 heads, keys
+# of 128 + 64 and values of 128, at the shortest rung the kernel takes, the
+# longest a prompt fills and the ladder's last
+@pytest.mark.parametrize("S", [512, 4096, 5120])
+def test_flash_with_narrower_values_compiles_to_one_kernel(one_chip, S):
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, sm_scale=0.135,
+                                        interpret=False),
+        ((1, 128, S, 192), jnp.bfloat16), ((1, 128, S, 192), jnp.bfloat16),
+        ((1, 128, S, 128), jnp.bfloat16), sharding=one_chip)
+    assert text.count("tpu_custom_call") == 1
+    assert f"bf16[128,{S},128]" in text        # the result is the values' width
+
+
+# that cell's routed experts: 16 of 256 held, 7168 x 2048, 8 a row under the
+# sigmoid rule; a step's 64 rows take their 512 pairs in one pass, a
+# 4,096-row prefill takes the pairs routed here 2,048 at a time
+@pytest.mark.parametrize("rows,pairs", [(64, 512), (4096, 2048)])
+def test_a_share_of_wide_experts_compiles_to_three_grouped_matmul_kernels(
+        one_chip, rows, pairs, monkeypatch):
+    from mxnet_tpu.ops import nn as ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    E, H, F, held = 256, 7168, 2048, 16
+    bf = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, bf, sharding=one_chip) for s in (
+        (rows, H), (H, E), (held, H, F), (held, H, F), (held, F, H), (E,))]
+    comp = jax.jit(lambda x, r, g, u, d, b: ops.moe_ffn(
+        x, r, g, u, d, b, top_k=8, n_group=8, topk_group=4,
+        routed_scale=2.5)).lower(*args).compile()
+    kernels = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call",
+                         comp.as_text())
+    assert sorted(k.split("{")[0] for k in kernels) == sorted(
+        [f"f32[{pairs},{F}]"] * 2 + [f"f32[{pairs},{H}]"])
+    # the gathered rows and the float32 products of one pass, not of all
+    # 8 x 4,096 pairs (1.9 GB)
+    assert comp.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 # the routed experts of the sdar_30b_a3b cell at its published widths: rows
 # of one lane's block, of the full step (64 lanes x 4) and of an S=1,024
 # prefill; 128 experts of 2048 x 768, 8 a row
