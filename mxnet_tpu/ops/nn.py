@@ -11,6 +11,7 @@ is a lax.scan (compiled once, no per-step dispatch — the cuDNN-fused-RNN analo
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -480,16 +481,64 @@ def lrn(x, *, nsize=5, alpha=1e-4, beta=0.75, knorm=2.0):
 # ---------------------------------------------------------------------------
 # Dropout (nn/dropout.cc) — key passed explicitly; wrappers thread the global RNG
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("keep", "local", "mesh", "axes"))
+def _bits_per_shard(key, *, keep, local, mesh, axes):
+    """A keep mask whose dimension 0 is divided over ``axes`` of ``mesh``,
+    each shard's ``local`` rows drawn on that shard from its own fold of
+    ``key``; mesh axes not named stay the partitioner's. Jitted so that a
+    model's many sites of one shape are traced and lowered once."""
+    from jax.sharding import PartitionSpec as P
+
+    def draw(k):
+        return jax.random.bernoulli(
+            jax.random.fold_in(k, lax.axis_index(axes)), keep, local)
+
+    return jax.shard_map(draw, mesh=mesh, in_specs=P(), out_specs=P(axes),
+                         axis_names=frozenset(axes))(key)
+
+
+def _dropout_bits(key, keep, shape):
+    """The keep mask of ``shape``: one draw, or one draw a shard of the batch.
+
+    A data-parallel train step publishes the mesh axes its batch is divided
+    over (``parallel.mesh.batch_axes``). The SPMD partitioner cannot divide a
+    generator's draw, so a mask drawn whole there is drawn whole on every
+    device, each of which uses its own 1/n. Under such a step the mask is
+    drawn a shard at a time instead, unless its dimension 0 is 1 (broadcast
+    over the batch) or the devices do not divide it."""
+    from ..parallel.mesh import current_batch_axes
+    pub = current_batch_axes()
+    if pub is None:
+        return jax.random.bernoulli(key, keep, shape)
+    n = pub.size
+    if not shape or shape[0] == 1 or shape[0] % n:
+        pub.on_draw("whole")
+        return jax.random.bernoulli(key, keep, shape)
+    pub.on_draw("per_shard")
+    return _bits_per_shard(key, keep=keep, local=(shape[0] // n,) + shape[1:],
+                           mesh=pub.mesh.mesh, axes=pub.axes)
+
+
 @register("Dropout")
 def dropout(x, key=None, *, p=0.5, mode="training", axes=(), training=False,
             cudnn_off=False):
+    """Inverted dropout: ``x * mask / (1 - p)``, the mask's dimensions in
+    ``axes`` broadcast.
+
+    The same key gives the same mask, forward, backward and on a retried or
+    replayed step. Under a ``ParallelTrainStep`` whose batch is divided over
+    n > 1 devices the mask is drawn a shard at a time (``_dropout_bits``),
+    so there the same key gives another mask on another mesh size, as
+    ``step`` and ``step_n`` already differ. A mask is independent bits, and
+    any partition of it is a mask: a model whose dimension 0 is not the batch
+    (time-major) gets as valid a one, which the partitioner reshards."""
     if not training or p <= 0 or key is None:
         return x
     shape = list(x.shape)
     for a in axes:
         shape[a] = 1
     keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, tuple(shape)).astype(x.dtype) / keep
+    mask = _dropout_bits(key, keep, tuple(shape)).astype(x.dtype) / keep
     return x * mask
 
 

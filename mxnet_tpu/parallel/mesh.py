@@ -19,7 +19,7 @@ from typing import Dict, Optional, Sequence, Tuple
 from ..base import MXNetError
 
 __all__ = ["DeviceMesh", "make_mesh", "current_mesh", "replicated", "shard_spec",
-           "carve_slices"]
+           "carve_slices", "batch_axes", "current_batch_axes"]
 
 _AXES = ("dp", "fsdp", "pp", "tp", "sp", "ep")  # canonical ordering, outer→inner
 
@@ -171,6 +171,44 @@ def carve_slices(sizes: Sequence[int], devices=None):
 
 def current_mesh() -> Optional[DeviceMesh]:
     stack = getattr(_current, "stack", None)
+    return stack[-1] if stack else None
+
+
+class batch_axes:
+    """Publish, for the length of a trace on this thread, that dimension 0
+    of the model's input is divided over ``axes`` of ``mesh``.
+
+    ``ParallelTrainStep`` holds it around the trace of its model, and only
+    where those axes span more than one device. An op that makes data of the
+    batch's shape out of nothing (``ops/nn.py:dropout``'s mask) reads it with
+    :func:`current_batch_axes` and makes each shard's part on that shard.
+    ``on_draw(kind)`` tells the publisher what such an op did."""
+
+    def __init__(self, mesh: DeviceMesh, axes: Tuple[str, ...], on_draw):
+        self.mesh = mesh
+        self.axes = tuple(axes)
+        self.on_draw = on_draw
+
+    @property
+    def size(self) -> int:
+        n = 1
+        for a in self.axes:
+            n *= self.mesh.axis_size(a)
+        return n
+
+    def __enter__(self):
+        stack = getattr(_current, "batch_axes", None)
+        if stack is None:
+            stack = _current.batch_axes = []
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _current.batch_axes.pop()
+
+
+def current_batch_axes() -> Optional[batch_axes]:
+    stack = getattr(_current, "batch_axes", None)
     return stack[-1] if stack else None
 
 

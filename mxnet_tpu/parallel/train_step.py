@@ -16,6 +16,7 @@ TPU replacement for ctx_group model parallelism, symbol.py:1562-1711).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as onp
@@ -25,7 +26,7 @@ from ..ndarray.ndarray import NDArray
 from .. import telemetry as _telemetry
 from ..resilience import faults as _faults
 from ..resilience.retry import RetryPolicy
-from .mesh import DeviceMesh
+from .mesh import DeviceMesh, batch_axes
 
 __all__ = ["ParallelTrainStep", "pure_apply"]
 
@@ -45,6 +46,17 @@ _DONATED_REPLACE = _telemetry.counter(
     "Times the autoformat path re-placed carried (donated) state into a "
     "different executable's layouts — the OOM-retryable transition; steady "
     "growth means step()/step_n() shape churn is thrashing layouts.")
+_DROPOUT_DRAWS = _telemetry.counter(
+    "mxtpu_train_dropout_draws_total",
+    "Dropout masks traced under a train step whose batch is divided over "
+    "more than one device: per_shard = drawn a shard at a time, whole = the "
+    "whole mask drawn on every device (a leading dimension of 1, or one the "
+    "devices do not divide). Counted when a step is traced, not when it "
+    "runs.", labelnames=("draw",))
+
+
+def _count_dropout_draw(kind):
+    _DROPOUT_DRAWS.labels(kind).inc()
 
 
 from ..gluon.block import pure_apply, _trace_nd as _mk_nd  # shared primitive
@@ -197,6 +209,7 @@ class ParallelTrainStep:
         block = self._block
         aux_cell = self._aux_ids_cell
         cdtype = self._compute_dtype
+        batch_scope = self._batch_scope
 
         def step(train_params, aux_params, opt_states, x, y, extras, key,
                  lrs, wds, t):
@@ -213,8 +226,10 @@ class ParallelTrainStep:
                         jnp.issubdtype(tp[j].dtype, jnp.floating) else tp[j]
                 xin = x.astype(cdtype) if cdtype is not None and \
                     jnp.issubdtype(x.dtype, jnp.floating) else x
-                outs, aux_vals, aux_pids = pure_apply(
-                    block, plist, cur, (xin,) + tuple(extras), key, training=True)
+                with batch_scope():
+                    outs, aux_vals, aux_pids = pure_apply(
+                        block, plist, cur, (xin,) + tuple(extras), key,
+                        training=True)
                 aux_cell.clear()
                 aux_cell.extend(aux_pids)
                 outs_nd = [_mk_nd(o) for o in outs]
@@ -272,6 +287,19 @@ class ParallelTrainStep:
             return loss_val, new_train, new_aux, new_states
 
         return step
+
+    def _batch_scope(self):
+        """What the model's trace may know of the batch's division: the
+        mesh axes in the data spec's leading entry, published
+        (``mesh.batch_axes``) where they span more than one device. With one
+        device, or a batch that is not divided, nothing is published and the
+        trace is the one-device trace."""
+        spec = self._data_sharding.spec
+        lead = spec[0] if len(spec) else None
+        axes = () if lead is None else (lead,) if isinstance(lead, str) \
+            else tuple(lead)
+        scope = batch_axes(self._mesh, axes, _count_dropout_draw)
+        return scope if scope.size > 1 else contextlib.nullcontext()
 
     def _shardings(self):
         t_sh = [self._param_shardings[i] for i in self._trainable_idx]
@@ -544,7 +572,12 @@ class ParallelTrainStep:
         Matches K separate ``step()`` calls exactly for deterministic models
         (incl. lr schedules and Adam's t); models with in-graph randomness
         (Dropout) consume split subkeys of one key instead of K session keys,
-        so the random streams differ (both are valid dropout masks)."""
+        so the random streams differ (both are valid dropout masks). In the
+        same way the same key gives other masks on another mesh size: where
+        the batch is divided over n > 1 devices each shard's rows are drawn
+        from the key folded with the shard's index (``ops/nn.py:dropout``).
+        On one mesh the same key gives the same masks, here as in
+        ``step``."""
         from ..ops.registry import _profiler_running
         k = _leading_dim(xs)
         examples = _leading_dim(xs, axis=1) * k if k else 0

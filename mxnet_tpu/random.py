@@ -34,7 +34,14 @@ def _prng_impl():
     'auto' picks the hardware-friendly rbg generator on TPU (measured +13%
     BERT-base pretraining throughput — threefry burns MXU-adjacent cycles
     generating dropout bits) and threefry on CPU, keeping test runs on the
-    virtual CPU mesh bit-reproducible with older snapshots."""
+    virtual CPU mesh bit-reproducible with older snapshots.
+
+    An rbg draw is one ``rng-bit-generator`` op whose output is a function
+    of the key and the *whole* shape: the SPMD partitioner cannot give a
+    device its slice of it without drawing all of it (threefry's bits are
+    elementwise in a counter, which divides). So a draw that should be
+    divided over devices is made per shard by whoever knows the division:
+    ``ops/nn.py:_dropout_bits`` under a data-parallel train step."""
     from . import config
     from .base import MXNetError
     impl = config.get("MXNET_PRNG_IMPL", "auto")

@@ -259,3 +259,156 @@ def test_step_n_matches_step():
                                   sorted(net2.collect_params().items())):
         onp.testing.assert_allclose(p1.data().asnumpy(), p2.data().asnumpy(),
                                     rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# dropout under a train step whose batch is divided: the mask is drawn a shard
+# at a time (ops/nn.py:_dropout_bits reads parallel.mesh.batch_axes)
+# ---------------------------------------------------------------------------
+_P = 0.25
+# mesh, the published batch axes, the input's spec, its shape, dropout's
+# broadcast axes, and what the draw has to be: (kind, shards of dimension 0)
+DROPOUT_DRAWS = [
+    pytest.param({"dp": 4}, ("dp",), ("dp",), (64, 8, 16), (),
+                 ("per_shard", 4), id="dp4"),
+    pytest.param({"dp": 2, "tp": 2}, ("dp",), ("dp", None, "tp"), (64, 8, 16),
+                 (), ("per_shard", 2), id="dp2_tp2_leaves_tp_alone"),
+    pytest.param({"dp": 2, "fsdp": 2}, ("dp", "fsdp"), (("dp", "fsdp"),),
+                 (64, 8, 16), (), ("per_shard", 4), id="batch_over_two_axes"),
+    pytest.param({"dp": 4}, ("dp",), ("dp",), (64, 8, 16), (0,),
+                 ("whole", 1), id="mask_broadcast_over_the_batch"),
+    pytest.param({"dp": 4}, ("dp",), (), (6, 8, 16), (),
+                 ("whole", 1), id="rows_the_devices_do_not_divide"),
+]
+
+
+@pytest.mark.parametrize("axes,batch,spec,shape,drop_axes,want", DROPOUT_DRAWS)
+def test_dropout_mask_under_published_batch_axes(axes, batch, spec, shape,
+                                                 drop_axes, want):
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn as ops
+    from mxnet_tpu.parallel.mesh import batch_axes
+
+    mesh = parallel.make_mesh(axes, devices=jax.devices()[:4])
+    said = []
+
+    def masked(x, key):
+        with batch_axes(mesh, batch, said.append):
+            return ops.dropout(x, key, p=_P, axes=drop_axes, training=True)
+
+    fn = jax.jit(masked, in_shardings=(mesh.sharding(*spec),
+                                       mesh.replicated()))
+    x = jnp.ones(shape, jnp.float32)
+    key = jax.random.PRNGKey(3)
+    kind, shards = want
+    out = onp.asarray(fn(x, key))
+    assert said == [kind]
+
+    # a valid mask: every entry 0 or 1/keep, kept at the rate 1 - p
+    keep = 1.0 - _P
+    mask = out > 0
+    assert set(onp.unique(out)) <= {0.0, onp.float32(1.0 / keep)}
+    drawn = mask[0] if drop_axes else mask
+    sd = (keep * _P / drawn.size) ** 0.5
+    assert abs(drawn.mean() - keep) < 3 * sd
+    # each shard draws from its own fold of the key
+    parts = mask.reshape((shards, -1))
+    for i in range(shards):
+        for j in range(i):
+            assert (parts[i] != parts[j]).any()
+    # the same key on the same mesh: the same mask; another key: another
+    onp.testing.assert_array_equal(onp.asarray(fn(x, key)), out)
+    assert (onp.asarray(fn(x, jax.random.PRNGKey(4))) != out).any()
+    # the backward uses the forward's mask
+    up = jnp.arange(x.size, dtype=jnp.float32).reshape(shape) / x.size
+    _, vjp = jax.vjp(lambda a: fn(a, key), x)
+    onp.testing.assert_array_equal(onp.asarray(vjp(up)[0]),
+                                   out * onp.asarray(up))
+
+    # what is drawn where, in the program as lowered (an rbg key's draw is
+    # one op there): the shard's rows under a shard_map over the batch axes
+    # alone, or the whole mask and no shard_map
+    text = fn.lower(x, jax.random.key(3, impl="rbg")).as_text()
+    drawn_shapes = re.findall(r"rng_bit_generator.*-> \(tensor<2xui64>, "
+                              r"tensor<([\dx]+)xui32>\)", text)
+    manual = re.findall(r"manual_axes=\{([^}]*)\}", text)
+    mask_shape = [1 if a in drop_axes else d for a, d in enumerate(shape)]
+    mask_shape[0] //= shards
+    assert drawn_shapes == ["x".join(map(str, mask_shape))]
+    # (an axis left to the partitioner shows up only inside, where
+    # axis_index of the batch axes is read)
+    assert manual[:1] == ([", ".join(f'"{a}"' for a in batch)]
+                          if kind == "per_shard" else [])
+
+
+def _dropout_step(mesh_axes, n_devices):
+    import jax
+    from mxnet_tpu.gluon import nn as gnn
+    net = gnn.HybridSequential()
+    net.add(gnn.Dense(16, in_units=8, flatten=False), gnn.Dropout(_P),
+            gnn.Dense(4, in_units=16, flatten=False))
+    net.initialize(mx.init.Xavier())
+    mesh = parallel.make_mesh(mesh_axes, devices=jax.devices()[:n_devices])
+    return parallel.ParallelTrainStep(
+        net, gloss.L2Loss(), mx.optimizer.SGD(learning_rate=0.1), mesh)
+
+
+def _draws():
+    from mxnet_tpu.parallel.train_step import _DROPOUT_DRAWS
+    return {kind: _DROPOUT_DRAWS.labels(kind).value
+            for kind in ("per_shard", "whole")}
+
+
+@pytest.mark.parametrize("entry", ["step", "step_n"])
+def test_a_data_parallel_step_draws_per_shard_and_repeats_bitwise(entry):
+    """The retry in ``_step_impl`` and the NumericsGuard's replay rest on it:
+    the same key on the same mesh gives the same masks."""
+    rng = onp.random.RandomState(2)
+    X = rng.randn(3, 32, 8).astype("float32")
+    Y = rng.randn(3, 32, 4).astype("float32")
+    step = _dropout_step({"dp": 4}, 4)
+    start = step.state_dict()
+    before = _draws()
+
+    def run():
+        mx.random.seed(5)
+        if entry == "step_n":
+            return step.step_n(X, Y).asnumpy()
+        return onp.stack([step(x, y).asnumpy() for x, y in zip(X, Y)])
+
+    first = run()
+    step.load_state_dict(start)
+    onp.testing.assert_array_equal(run(), first)
+    assert len(set(first.tolist())) == 3
+    # one Dropout, traced once: counted when traced, not when run
+    after = _draws()
+    assert after["per_shard"] - before["per_shard"] == 1
+    assert after["whole"] == before["whole"]
+
+
+def test_a_one_device_step_is_traced_as_with_nothing_published(monkeypatch):
+    """Mesh {"dp": 1} divides nothing: the step lowers to the text it has
+    with no batch axes published, and no draw is counted."""
+    import contextlib
+    import jax
+    import jax.numpy as jnp
+
+    def lowered(step):
+        step._build()
+        n = len(step._trainable_idx)
+        return step._step_fn.lower(
+            step.params, [], step._opt_states, jnp.zeros((32, 8)),
+            jnp.zeros((32, 4)), (), jax.random.PRNGKey(0), jnp.zeros(n),
+            jnp.zeros(n), jnp.float32(1)).as_text()
+
+    before = _draws()
+    text = lowered(_dropout_step({"dp": 1}, 1))
+    assert _draws() == before
+    bare = _dropout_step({"dp": 1}, 1)
+    monkeypatch.setattr(bare, "_batch_scope", contextlib.nullcontext)
+    assert text == lowered(bare)
+    assert "manual_axes" not in text
+    # four devices: the same model does publish, and the text says so
+    assert "manual_axes" in lowered(_dropout_step({"dp": 4}, 4))

@@ -26,6 +26,10 @@ from mxnet_tpu.serving.generate import (DecodeEndpoint, DecodeScheduler,
 
 
 def _lm(seed=0, **kw):
+    # the initialisers draw from mx.random's key chain: left where the
+    # worker's earlier tests put it, one state in two dozen gives weights
+    # whose greedy decode is not history-sensitive (the oracle's own check)
+    mx.random.seed(seed)
     onp.random.seed(seed)
     cfg = dict(num_layers=2, units=32, hidden_size=64, num_heads=2,
                vocab_size=50, max_length=64)

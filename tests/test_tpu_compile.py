@@ -27,7 +27,7 @@ from mxnet_tpu.serving.generate.kv_cache import write_prefill, write_step
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
@@ -41,9 +41,14 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_text(fn, *shapes_dtypes, sharding):
@@ -313,3 +318,64 @@ def test_moe_ffn_compiles_to_three_grouped_matmul_kernels(one_chip, rows,
     pairs = rows * 8
     assert sorted(k.split("{")[0] for k in grouped) == sorted(
         [f"f32[{pairs},{F}]"] * 2 + [f"f32[{pairs},{H}]"])
+
+
+# a data-parallel train step's dropout at the shape of the four-chip BERT
+# cell (256 samples a chip, 128 tokens, 768 wide). The SPMD partitioner does
+# not divide an rng-bit-generator: drawn whole, the mask of all 1,024 samples
+# is drawn on every chip (12% of the cell's device time before PR 33)
+DROPOUT_STEPS = [pytest.param(4, 0, id="dp4_step"),
+                 pytest.param(4, 2, id="dp4_step_n"),
+                 pytest.param(1, 0, id="one_chip_step")]
+
+
+@pytest.mark.parametrize("chips,k", DROPOUT_STEPS)
+def test_a_train_step_draws_dropout_bits_for_its_own_shard_only(
+        topo, monkeypatch, chips, k):
+    import mxnet_tpu as mx
+    from jax.sharding import Mesh
+    from mxnet_tpu import gluon, parallel
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.parallel.mesh import DeviceMesh
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(768, in_units=768, flatten=False), nn.Dropout(0.1),
+            nn.Dense(8, in_units=768, flatten=False))
+    net.initialize()
+    mesh = DeviceMesh(Mesh(topo.devices[:chips], ("dp",)))
+
+    def described(a, sharding=None, **_):
+        # nothing can be placed on a described chip: the step carries shapes
+        return jax.tree_util.tree_map(
+            lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                                  sharding=sh), a, sharding)
+
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "device_put", described)
+        step = parallel.ParallelTrainStep(
+            net, gluon.loss.L2Loss(), mx.optimizer.SGD(learning_rate=0.1),
+            mesh)
+
+    rep, lead = mesh.replicated(), (k,) if k else ()
+
+    def sds(shape, dtype=jnp.float32, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    data = step._stacked(step._data_sharding) if k else step._data_sharding
+    n = len(step._trainable_idx)
+    if k:
+        fn = step._build_n(k)
+    else:
+        step._build()
+        fn = step._step_fn
+    # rbg, as random._prng_impl picks on the chip
+    key = sds((), jax.random.key(0, impl="rbg").dtype)
+    text = fn.lower(
+        step.params, [], step._opt_states,
+        sds(lead + (256 * chips, 128, 768), sharding=data),
+        sds(lead + (256 * chips, 128, 8), sharding=data), (), key,
+        sds(lead + (n,)), sds(lead + (n,)), sds(())).compile().as_text()
+    draws = re.findall(r"= (u32\[[\d,]*\])\S* rng-bit-generator\(", text)
+    assert draws and set(draws) == {"u32[256,128,768]"}
+    if chips == 1:
+        assert "partition-id" not in text
