@@ -2759,12 +2759,14 @@ inline Symbol block_attention(
     const std::string& kv_heads = "null" /* raw JSON */,
     int64_t block_length = 1,
     const std::string& sm_scale = "null" /* raw JSON */,
+    const std::string& window = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "heads", detail::Raw{heads});
   detail::Attr(&j, "kv_heads", detail::Raw{kv_heads});
   detail::Attr(&j, "block_length", static_cast<int64_t>(block_length));
   detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
+  detail::Attr(&j, "window", detail::Raw{window});
   std::vector<Symbol> ins{q, k, v, positions, ctx_acc, ctx_max, ctx_sum};
   return Symbol::Create("block_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
@@ -3494,6 +3496,7 @@ inline Symbol flash_attention(
     const std::string& block_q = "null" /* raw JSON */,
     const std::string& block_k = "null" /* raw JSON */,
     const std::string& interpret = "null" /* raw JSON */,
+    const std::string& window = "null" /* raw JSON */,
     const std::string& extra_attrs_json = "") {
   std::string j;
   detail::Attr(&j, "causal", causal);
@@ -3501,6 +3504,7 @@ inline Symbol flash_attention(
   detail::Attr(&j, "block_q", detail::Raw{block_q});
   detail::Attr(&j, "block_k", detail::Raw{block_k});
   detail::Attr(&j, "interpret", detail::Raw{interpret});
+  detail::Attr(&j, "window", detail::Raw{window});
   std::vector<Symbol> ins{q, k, v};
   return Symbol::Create("flash_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
@@ -4994,7 +4998,7 @@ inline Symbol pad(
                         detail::Finish(std::move(j), extra_attrs_json));
 }
 
-// paged_attention: positional inputs (q, k_pool, v_pool, tables, lengths, layer)
+// paged_attention: positional inputs (q, k_pool, v_pool, tables, lengths, layer, starts)
 inline Symbol paged_attention(
     const std::string& name,
     Symbol q,
@@ -5003,6 +5007,7 @@ inline Symbol paged_attention(
     Symbol tables,
     Symbol lengths,
     Symbol layer,
+    Symbol starts = Symbol(),
     const std::string& heads = "null" /* raw JSON */,
     const std::string& kv_heads = "null" /* raw JSON */,
     const std::string& sm_scale = "null" /* raw JSON */,
@@ -5015,7 +5020,7 @@ inline Symbol paged_attention(
   detail::Attr(&j, "sm_scale", detail::Raw{sm_scale});
   detail::Attr(&j, "v_dim", detail::Raw{v_dim});
   detail::Attr(&j, "interpret", detail::Raw{interpret});
-  std::vector<Symbol> ins{q, k_pool, v_pool, tables, lengths, layer};
+  std::vector<Symbol> ins{q, k_pool, v_pool, tables, lengths, layer, starts};
   return Symbol::Create("paged_attention", name, detail::Inputs(std::move(ins)),
                         detail::Finish(std::move(j), extra_attrs_json));
 }

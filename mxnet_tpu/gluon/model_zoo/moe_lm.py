@@ -29,8 +29,26 @@ to ``block_length`` rows a sequence:
   the caller to write, or not: a denoising step of block diffusion saw mask
   tokens and its keys are dropped.
 
+**Window and full layers in one model** (``layer_types``, ``sliding_window``,
+``rope_by_type``; causal models): a layer of type ``sliding_attention`` sees
+the last ``sliding_window`` positions, itself among them (``i - j <
+window``), every other type all of them, and each type rotates by its own
+table (``rope_by_type[type]`` = ``{"theta": ..., "scaling": None or YaRN's
+settings}``). The model then states its ``cache_groups`` to the endpoint,
+``(name, layers, window)``: the full layers, which keep every position, and
+the sliding ones, which keep the window: the pool gives each kind its own
+pages and ``decode_step``'s cache is (the first group's pools, the second's,
+the first group's tables, the second's). A prompt of 512 rows or more goes
+through ``ops/pallas/flash_attention`` (banded under a window, KV heads read
+by their group of query heads); the shorter rungs and a step's own rows
+through ``block_attention``. With none of the three the model traces what it
+always did.
+
 What the endpoint learns from the block: ``kv_units`` (the pool's row),
-``block_length``, ``mask_token_id`` (None: causal, one token a step).
+``block_length``, ``mask_token_id`` (None: causal, one token a step),
+``cache_groups`` (None: every layer keeps everything), ``prefill_reads_row``
+(``prefill_collect`` takes the position of the one row whose logits are read,
+and the head multiplies that row alone).
 Weights are (in, out). The math is ``jax.numpy`` on the parameters' arrays.
 
 ``DecoderLM`` is what such models share and ``mla_lm.MLADecoderLM`` builds
@@ -43,6 +61,10 @@ from ..block import HybridBlock
 from ...ndarray.ndarray import NDArray
 
 __all__ = ["DecoderLM", "MoEDecoderLM"]
+
+# rows from which a causal prompt's attention goes through the flash kernel
+# (ops/pallas/flash_attention.py: the compiled kernel's own floor)
+_FLASH_ROWS = 512
 
 
 def _one():
@@ -61,6 +83,8 @@ class DecoderLM(HybridBlock):
 
     block_length = 1
     mask_token_id = None
+    cache_groups = None         # every layer keeps every position
+    prefill_reads_row = True    # prefill_collect(tokens, last)
 
     def _get(self, name, shape, dtype, **kw):
         p = self.params.get(name, shape=shape, dtype=dtype, **kw)
@@ -77,9 +101,11 @@ class DecoderLM(HybridBlock):
         self.head_weight = self._get("head_weight",
                                      (self.units, self.vocab_size), dtype)
 
-    def _run(self, ids, positions, cache=None):
+    def _run(self, ids, positions, cache=None, last=None):
         """(logits (B, S, V) float32, the rows to cache layer by layer,
-        loads (expert layers, E_held))."""
+        loads (expert layers, E_held)). ``last`` (B,): the one row a
+        sequence whose logits are wanted, (B, 1, V): the final norm and the
+        head then see that row alone."""
         import jax.numpy as jnp
         from ...ops import nn as ops
         x = self.embed_weight.data().data[ids]
@@ -89,6 +115,8 @@ class DecoderLM(HybridBlock):
             kept += rows
             if load is not None:
                 loads.append(load)
+        if last is not None:
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
         x = ops.rms_norm(x, self.final_norm.data().data, eps=self.rms_eps)
         logits = jnp.dot(x, self.head_weight.data().data,
                          preferred_element_type=jnp.float32)
@@ -100,19 +128,24 @@ class DecoderLM(HybridBlock):
         return [jnp.asarray(a.data if isinstance(a, NDArray) else a)
                 for a in arrays]
 
-    def _whole(self, tokens):
+    def _whole(self, tokens, last=None):
         import jax.numpy as jnp
         (ids,) = self._raw(tokens)
         ids = ids.astype(jnp.int32)
         positions = jnp.broadcast_to(
             jnp.arange(ids.shape[1], dtype=jnp.int32), ids.shape)
-        return self._run(ids, positions)
+        if last is not None:
+            (last,) = self._raw(last)
+            last = last.astype(jnp.int32)
+        return self._run(ids, positions, last=last)
 
     def forward(self, tokens):
         return NDArray(self._whole(tokens)[0])
 
-    def prefill_collect(self, tokens):
-        logits, kept, _ = self._whole(tokens)
+    def prefill_collect(self, tokens, last=None):
+        """``last`` (B,), if given: the position of the row a sequence whose
+        logits are read (a prompt's last); the logits are then (B, 1, V)."""
+        logits, kept, _ = self._whole(tokens, last)
         return (logits,) + tuple(kept)
 
     def decode_step(self, ids, positions, *cache):
@@ -135,6 +168,7 @@ class MoEDecoderLM(DecoderLM):
                  experts_per_token=2, vocab_size=256, norm_topk=True,
                  rms_eps=1e-6, rope_theta=1e6, block_length=1,
                  mask_token_id=None, held_experts=None, dtype="float32",
+                 layer_types=None, sliding_window=None, rope_by_type=None,
                  **kwargs):
         super().__init__(**kwargs)
         self.num_layers = num_layers
@@ -152,6 +186,28 @@ class MoEDecoderLM(DecoderLM):
         self.block_length = int(block_length)
         self.mask_token_id = mask_token_id
         self.held_experts = tuple(held_experts or (0, num_experts))
+        # a layer's window (None: it sees everything) and rotary table
+        self._windows = [None] * num_layers
+        self._ropes = [{"theta": rope_theta}] * num_layers
+        if layer_types is not None:
+            if len(layer_types) != num_layers or self.block_length != 1:
+                raise ValueError(
+                    f"layer_types names {len(layer_types)} layers of "
+                    f"{num_layers}, under blocks of {self.block_length} (a "
+                    "window is the causal mask's)")
+            sliding = [t == "sliding_attention" for t in layer_types]
+            if any(sliding):
+                self._windows = [int(sliding_window) if s else None
+                                 for s in sliding]
+                of = lambda kind: tuple(i for i, s in enumerate(sliding)
+                                        if s == kind)
+                self.cache_groups = tuple(
+                    group for group in (
+                        ("full", of(False), None),
+                        ("window", of(True), int(sliding_window)))
+                    if group[1])
+            if rope_by_type is not None:
+                self._ropes = [dict(rope_by_type[t]) for t in layer_types]
         held = self.held_experts[1]
         H, D, F = units, head_dim, expert_hidden
 
@@ -179,31 +235,65 @@ class MoEDecoderLM(DecoderLM):
             self._head(dtype)
 
     # ------------------------------------------------------------------
+    def _cache_of(self, i, cache, positions):
+        """What ``paged_attention`` takes after the queries for layer ``i``:
+        its group's pools and tables, the lanes' lengths, its index among the
+        group's layers and, of a window layer, the lanes' lower bounds."""
+        import jax.numpy as jnp
+        first = positions[:, 0]
+        if self.cache_groups is None:
+            return (*cache, first, i)
+        n = len(self.cache_groups)
+        pools, tables = cache[:-n], cache[-n:]
+        per = len(pools) // n
+        for g, (_, layers, window) in enumerate(self.cache_groups):
+            if i in layers:
+                bound = () if window is None else \
+                    (jnp.maximum(first - (window - 1), 0),)
+                return (*pools[g * per:(g + 1) * per], tables[g], first,
+                        layers.index(i), *bound)
+        raise ValueError(f"layer {i} is in no cache group")
+
     def _layer(self, i, x, positions, cache=None):
         """One block over x (B, S, H): (y, (k, v), rows per held expert).
-        ``cache`` = (k_pool, v_pool, tables): the rows also attend to their
-        sequence's cached positions before ``positions[:, 0]``."""
+        ``cache`` = (k_pool, v_pool, tables), of a model with cache groups
+        every group's pools and then every group's tables: the rows also
+        attend to their sequence's cached positions before
+        ``positions[:, 0]``."""
         from ...ops import nn as ops
         from ...ops.pallas.paged_attention import paged_attention
         p = {name: w.data().data for name, w in self.layers[i].items()}
         B, S, H = x.shape
+        window = self._windows[i]
+        banded = {} if window is None else {"window": window}
         h = ops.rms_norm(x, p["ln1"], eps=self.rms_eps)
         heads = lambda t, n: t.reshape(B, S, n, self.head_dim)
         q = ops.rms_norm(heads(h @ p["wq"], self.num_heads), p["q_norm"],
                          eps=self.rms_eps)
         k = ops.rms_norm(heads(h @ p["wk"], self.num_kv_heads), p["k_norm"],
                          eps=self.rms_eps)
-        q = ops.rotary_embedding(q, positions, theta=self.rope_theta)
-        k = ops.rotary_embedding(k, positions, theta=self.rope_theta)
+        q = ops.rotary_embedding(q, positions, **self._ropes[i])
+        k = ops.rotary_embedding(k, positions, **self._ropes[i])
         k = k.reshape(B, S, self.kv_units)
         v = h @ p["wv"]
-        q = q.reshape(B, S, -1)
-        ctx = () if cache is None else paged_attention(
-            q, *cache, positions[:, 0], i, heads=self.num_heads,
-            kv_heads=self.num_kv_heads)
-        att = ops.block_attention(
-            q, k, v, positions, *ctx, heads=self.num_heads,
-            kv_heads=self.num_kv_heads, block_length=self.block_length)
+        if cache is None and self.block_length == 1 and S >= _FLASH_ROWS:
+            # a long prompt: no (S, S) scores in HBM, and under a window the
+            # band's blocks alone
+            from ...ops.pallas.flash_attention import flash_attention
+            by_head = lambda t: t.transpose(0, 2, 1, 3)
+            att = flash_attention(
+                by_head(q), by_head(heads(k, self.num_kv_heads)),
+                by_head(heads(v, self.num_kv_heads)), causal=True, **banded)
+            att = by_head(att).reshape(B, S, -1)
+        else:
+            q = q.reshape(B, S, -1)
+            ctx = () if cache is None else paged_attention(
+                q, *self._cache_of(i, cache, positions),
+                heads=self.num_heads, kv_heads=self.num_kv_heads)
+            att = ops.block_attention(
+                q, k, v, positions, *ctx, heads=self.num_heads,
+                kv_heads=self.num_kv_heads, block_length=self.block_length,
+                **banded)
         x = x + att @ p["wo"]
         h = ops.rms_norm(x, p["ln2"], eps=self.rms_eps)
         y, load = ops.moe_ffn(

@@ -821,7 +821,8 @@ def rotary_frequencies(dim, theta, scaling=None):
     the original context (kept as it is, and every faster one) to the pair
     that makes ``beta_slow`` (divided, and every slower one); cos and sin
     times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
-    mscale_all_dim)``."""
+    mscale_all_dim)``, or times ``attention_factor`` where a configuration
+    states that number itself."""
     import numpy as onp
     exps = onp.arange(0, dim, 2, dtype=onp.float64) / dim
     inv = float(theta) ** -exps
@@ -841,9 +842,11 @@ def rotary_frequencies(dim, theta, scaling=None):
         high += 0.001
     ramp = onp.clip((onp.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
     inv = inv * (1.0 - ramp) + inv / factor * ramp
-    scale = yarn_mscale(factor, sc.get("mscale", 1.0)) \
-        / yarn_mscale(factor, sc.get("mscale_all_dim", 0.0) or 0.0)
-    return inv.astype(onp.float32), scale
+    scale = sc.get("attention_factor")
+    if scale is None:
+        scale = yarn_mscale(factor, sc.get("mscale", 1.0)) \
+            / yarn_mscale(factor, sc.get("mscale_all_dim", 0.0) or 0.0)
+    return inv.astype(onp.float32), float(scale)
 
 
 @register("rotary_embedding", jit=True)
@@ -872,7 +875,7 @@ def rotary_embedding(x, positions, *, theta=10000.0, scaling=None):
 @register("block_attention", jit=True)
 def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
                     ctx_sum=None, *, heads, kv_heads, block_length=1,
-                    sm_scale=None):
+                    sm_scale=None, window=None):
     """Attention of rows to their own blocks and to everything before them.
 
     ``q`` (B, S, heads*D), ``k`` (B, S, kv_heads*D), ``v`` (B, S,
@@ -882,7 +885,8 @@ def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
     head ``h // (heads / kv_heads)``. A row at position i sees a row at
     position j iff ``j // block_length <= i // block_length``: both
     directions inside a block, causal between blocks (``block_length`` 1 is
-    the causal mask).
+    the causal mask). Under a ``window`` it sees only the last ``window``
+    positions, itself among them: ``i - j < window`` besides.
 
     ``ctx_acc`` (B, S, heads, Dv), ``ctx_max``, ``ctx_sum`` (B, S, heads), if
     given, are a second part every row also attends to, already reduced to
@@ -899,6 +903,8 @@ def block_attention(q, k, v, positions, ctx_acc=None, ctx_max=None,
     qh = q.reshape(B, S, kv_heads, G, D)
     blk = positions // block_length                        # (B, S)
     mask = blk[:, None, :] <= blk[:, :, None]              # (B, q, k)
+    if window is not None:
+        mask &= positions[:, :, None] - positions[:, None, :] < window
     s = jnp.einsum("bqhgd,bkhd->bqhgk", qh, k.reshape(B, S, kv_heads, D),
                    preferred_element_type=jnp.float32)
     s = s / math.sqrt(D) if sm_scale is None else s * sm_scale
@@ -940,7 +946,14 @@ def _gmm_tiling(m, k, n, itemsize):
     (PR 32): these two read 654 and 670 GB/s at a step's 2 rows an expert and
     636 and 594 at a prefill pass's 128, the best or within 3% of it over
     five legal tilings each ((128, 1792, 1024) 612 and 596, (128, 2048, 512)
-    689 and 506; tiles that hold more than 16 MiB are refused)."""
+    689 and 506; tiles that hold more than 16 MiB are refused). 2304 x 896
+    (PR 34) is one tile a group again, 4,128,768 B, 1.6% under the limit
+    (the down-projection's 896 x 2304 the same bytes): on a v5e, 64 experts
+    held, each of a layer's three kernels reads its 63 drawn experts' tiles
+    at 750 GB/s at a step's 4 rows an expert (0.347 ms a kernel;
+    ``expert_ffn_roofline_pct.decode`` 91-92) and computes a prefill pass's
+    32,768 pairs, 512 rows an expert, at 135 (gate, up) and 129 (down)
+    TFLOP/s, 1.00 and 1.05 ms a kernel (my chip runs, PR 34)."""
     tm = 128 if m % 128 == 0 else m if m < 128 and m % 16 == 0 else None
     if tm is None or k % 128 or n % 128:
         return None
@@ -1021,6 +1034,13 @@ def route_grouped_sigmoid(x, router, bias, *, top_k, n_group, topk_group,
 # holds a share of the experts: a pass's rows, its three float32 products and
 # its weighted result are this long, whatever the rows x top_k routed in all
 _PAIR_BLOCK = 2048
+# pairs one pass takes where a chip holds every expert: a prompt of more rows
+# goes a block of whole rows at a time (a 16,384-row prefill routes 131,072
+# pairs, whose float32 down-projection alone would be 1.2 GB at 2,304 wide).
+# A pass reads every expert's weights once, so the block is as long as the
+# scratch allows: at 32,768 pairs of 2,304 x 896 a pass computes for 2 ms
+# what it reads in 1
+_ALL_HELD_PAIRS = 32768
 
 
 def _expert_pass(x, order, weight, sizes, w_gate, w_up, w_down, top_k):
@@ -1048,7 +1068,10 @@ def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
     expert) pairs are sorted by expert, the held experts' first, and each
     expert multiplies exactly its own rows (:func:`_grouped_matmul`).
     ``all_held`` says every expert is here: all T x top_k pairs are then
-    gathered at once. Of a share, the pairs routed here are gathered
+    gathered at once, or, past ``_ALL_HELD_PAIRS`` of them, the pairs of a
+    block of whole rows at a time (each block is this function over its rows:
+    a row's pairs stay together and are summed in the row's own order; a
+    step's few hundred pairs go as ever). Of a share, the pairs routed here are gathered
     ``_PAIR_BLOCK`` at a time, as many passes as they fill, and each pass is
     added to its rows: the work and the scratch grow with the rows routed
     here, not with all that were routed (a sixteenth of the experts see a
@@ -1065,6 +1088,19 @@ def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
     top_k = top_i.shape[1]
     held = w_gate.shape[0]
     pairs = T * top_k
+    if all_held and pairs > _ALL_HELD_PAIRS:
+        rows = _ALL_HELD_PAIRS // top_k
+        pad = -T % rows         # rows that route nowhere and weigh nothing
+
+        def blocks(a, fill):
+            a = jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
+            return a.reshape(-1, rows, a.shape[1])
+
+        out, sizes = lax.map(
+            lambda b: expert_ffn(*b, w_gate, w_up, w_down,
+                                 first_expert=first_expert, all_held=True),
+            (blocks(x, 0), blocks(top_w, 0), blocks(top_i, -1)))
+        return out.reshape(-1, H)[:T], sizes.sum(0)
     expert = top_i.reshape(-1) - first_expert
     here = (expert >= 0) & (expert < held)
     expert = jnp.where(here, expert, held)          # elsewhere: sorted last

@@ -13,6 +13,17 @@ backward; Pallas backward kernel is a further optimization).
 
 Layout: (B, H, S, D) with D the head dim. D should be a multiple of 128 lanes
 or small enough to pad; S blocks of 128/256 keep the MXU shape-friendly.
+
+A **window** (causal, forward only): row i sees column j iff ``j <= i`` and
+``i - j < window``, the last ``window`` positions with itself among them. The
+grid's k axis then runs over the blocks of the band alone, not over all of S:
+a q block's first k block is the one that holds its first row's oldest
+column, and blocks wholly behind the band are never visited, as blocks above
+the diagonal are never computed; a 16,384-row layer under a window of 1,024
+costs an eighth of the causal one. **Grouped KV heads**: ``k`` and ``v`` may
+have fewer heads than ``q`` (a divisor); query head h reads KV head ``h //
+(H / H_kv)`` through the k and v blocks' index map, and nothing is repeated
+in HBM.
 """
 from __future__ import annotations
 
@@ -55,7 +66,7 @@ def _dot_precision(dtype):
 
 
 def _masked_scores(q, k_blk, sm_scale, mask_causal, mask_tail, q_offset,
-                   k_offset, block_q, block_k, seq_len):
+                   k_offset, block_q, block_k, seq_len, window=None):
     """q @ k^T * scale with the causal/padded-tail masks this block class
     needs. Dots stay in the input dtype (bf16 MXU-native) with fp32
     accumulation — casting operands to fp32 first would run the MXU at its
@@ -72,6 +83,8 @@ def _masked_scores(q, k_blk, sm_scale, mask_causal, mask_tail, q_offset,
             rows = q_offset + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             causal_ok = rows >= cols
+            if window is not None:
+                causal_ok &= rows - cols < window
             valid = causal_ok if valid is None else (valid & causal_ok)
         s = jnp.where(valid, s, _NEG_INF)
     return s
@@ -123,9 +136,24 @@ def _mask_dispatch(pl, work, causal, q_offset, k_offset, block_q, block_k,
             do(False, False)
 
 
+def _band_first_block(q_offset, window, block_k):
+    """The k block that holds the oldest column a q block starting at
+    ``q_offset`` sees under ``window``."""
+    return jnp.maximum(q_offset - (window - 1), 0) // block_k
+
+
+def _band_blocks(seq, block_q, block_k, window):
+    """k blocks the grid visits a q block under ``window``: from the block of
+    its first row's oldest column to the block of its last row's own, at the
+    q block where that is most."""
+    return max((i + block_q - 1) // block_k
+               - max(i - (window - 1), 0) // block_k + 1
+               for i in range(0, seq, block_q))
+
+
 def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                           m_scr, l_scr, acc_scr, *, sm_scale, causal,
-                          block_k, seq_len, num_k):
+                          block_k, seq_len, num_k, window=None):
     """One (q-block, k-block) grid step. The k axis is the innermost grid
     dimension: K/V blocks stream through VMEM with pallas's automatic
     double-buffered pipelining while the online-softmax state (m, l, acc)
@@ -138,7 +166,9 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     ki = pl.program_id(2)
     block_q = q_ref.shape[1]
     q_offset = qi * block_q
-    k_offset = ki * block_k
+    # under a window the k axis counts from the band's first block
+    k_offset = ki * block_k if window is None else \
+        (_band_first_block(q_offset, window, block_k) + ki) * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -153,12 +183,20 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     if causal:
         work &= k_offset <= q_offset + block_q - 1
 
+    if window is not None:
+        # the band's edges: the diagonal, and the columns the block's last
+        # row no longer sees; a block between them is computed unmasked
+        edge = (k_offset + block_k - 1 > q_offset) | \
+            (k_offset < q_offset + block_q - window)
+        has_tail = seq_len % block_k != 0
+
     def _do_block(mask_causal, mask_tail):
         q = q_ref[0]                                      # (Bq, D)
         k_blk = k_ref[0]                                  # (Bk, D)
         v_blk = v_ref[0]
         s = _masked_scores(q, k_blk, sm_scale, mask_causal, mask_tail,
-                           q_offset, k_offset, block_q, block_k, seq_len)
+                           q_offset, k_offset, block_q, block_k, seq_len,
+                           window)
         m_acc = m_scr[:, 0]
         l_acc = l_scr[:, 0]
         m_blk = jnp.max(s, axis=1)
@@ -173,8 +211,17 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
 
-    _mask_dispatch(pl, work, causal, q_offset, k_offset, block_q, block_k,
-                   seq_len, _do_block)
+    if window is None:
+        _mask_dispatch(pl, work, causal, q_offset, k_offset, block_q,
+                       block_k, seq_len, _do_block)
+    else:
+        @pl.when(work & edge)
+        def _edge():
+            _do_block(True, has_tail)
+
+        @pl.when(work & jnp.logical_not(edge))
+        def _inside():      # under the diagonal: never the padded tail
+            _do_block(False, False)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -188,11 +235,13 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse[:, None], (block_q, _LANES))
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+               window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
+    Hk = k.shape[1]         # KV heads, each shared by H // Hk query heads
     Dv = v.shape[-1]        # the values may be narrower than the keys
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -203,21 +252,33 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
     qr = q.reshape(B * H, Sp, D)
-    kr = k.reshape(B * H, Sp, D)
-    vr = v.reshape(B * H, Sp, Dv)
+    kr = k.reshape(B * Hk, Sp, D)
+    vr = v.reshape(B * Hk, Sp, Dv)
     num_k = pl.cdiv(Sp, bk)
+    if window is None:
+        kv_block = lambda b, i, j: (b, j, 0)
+    else:
+        # the band's blocks alone, from its first one; past the array's end
+        # the last block is named again (no new DMA) and not computed
+        last = num_k - 1
+        num_k = _band_blocks(Sp, bq, bk, window)
+        kv_block = lambda b, i, j: (b, jnp.minimum(
+            _band_first_block(i * bq, window, bk) + j, last), 0)
+    if Hk != H:
+        by_group, G = kv_block, H // Hk
+        kv_block = lambda b, i, j: by_group(b // G, i, j)
     grid = (B * H, pl.cdiv(Sp, bq), num_k)
     kernel = functools.partial(_attention_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, block_k=bk, seq_len=S,
-                               num_k=num_k)
+                               num_k=num_k, window=window)
     scratch = pltpu.VMEM
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), kv_block),
+            pl.BlockSpec((1, bk, Dv), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
@@ -559,7 +620,9 @@ _MIN_PALLAS_S = 512
 _MIN_KERNEL_S = 128
 
 
-def _dense_attention(q, k, v, sm_scale, causal):
+def _dense_attention(q, k, v, sm_scale, causal, window=None):
+    if k.shape[1] != q.shape[1]:    # grouped KV heads: short rows, repeated
+        k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], 1) for a in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
@@ -567,8 +630,10 @@ def _dense_attention(q, k, v, sm_scale, causal):
         # the LAST query row sees every key), which degenerates to plain
         # tril when Lq == Lk
         S, Sk = q.shape[2], k.shape[2]
-        s = jnp.where(jnp.tril(jnp.ones((S, Sk), bool), k=Sk - S), s,
-                      _NEG_INF)
+        seen = jnp.tril(jnp.ones((S, Sk), bool), k=Sk - S)
+        if window is not None:
+            seen &= ~jnp.tril(jnp.ones((S, Sk), bool), k=Sk - S - window)
+        s = jnp.where(seen, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
@@ -576,11 +641,17 @@ def _dense_attention(q, k, v, sm_scale, causal):
 
 @register("flash_attention", jit=True)
 def flash_attention(q, k, v, *, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, interpret=None):
+                    block_q=None, block_k=None, interpret=None, window=None):
     """Fused attention over (B, H, S, D). ``v`` may be (B, H, S, Dv) with
     Dv != D (latent attention's plain form: keys of 192, values of 128): the
     forward kernel takes it as it is; the backward kernels do not, so that
-    call has no gradient. Pallas kernel on TPU; interpreter
+    call has no gradient. ``window`` (with ``causal``): a row sees the last
+    ``window`` positions, itself among them, and the grid visits the band's k
+    blocks alone; ``k`` and ``v`` may be (B, H_kv, S, ...) with H_kv a divisor
+    of H, each KV head read by its group of query heads (module docstring).
+    Both are the forward kernel's alone: the backward kernels take neither,
+    so such a call has no gradient either (a prefill's; differentiating it
+    fails inside ``pallas_call``). Pallas kernel on TPU; interpreter
     (still the same kernel) elsewhere so tests exercise identical code.
     Short sequences (S < 512) on the compiled TPU path take a dense XLA
     route instead — measured faster there, and Mosaic rejects sub-tile
@@ -594,10 +665,13 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
     # S from q), so ANY cross-length call goes dense; equal lengths below
     # the tile minimum go dense for Mosaic legality / dispatch-cost reasons
     # (advisor r4 + r5 review).
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window is the causal mask's")
     if q.shape[2] != k.shape[2] or \
             (not interpret and q.shape[2] < _MIN_PALLAS_S) or \
             (not explicit and q.shape[2] < _MIN_KERNEL_S):
-        return _dense_attention(q, k, v, float(sm_scale), bool(causal))
+        return _dense_attention(q, k, v, float(sm_scale), bool(causal),
+                                window)
     # None = adaptive default (an EXPLICIT block size is always honored):
     # 1024/1024 from S>=16K, 512/1024 below (r5 sweep, heads of 64); heads
     # wider than a lane tile stream more of K a q block, and 1024 rows of q
@@ -609,7 +683,11 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             else DEFAULT_BLOCK_Q
     if block_k is None:
         block_k = _LONG_BLOCK_K if long_ctx else DEFAULT_BLOCK_K
-    run = _flash if v.shape[-1] == q.shape[-1] else \
-        lambda *a: _flash_fwd(*a)[0]        # forward only
-    return run(q, k, v, float(sm_scale), bool(causal), int(block_q),
-               int(block_k), bool(interpret))
+    args = (q, k, v, float(sm_scale), bool(causal), int(block_q),
+            int(block_k), bool(interpret))
+    if window is not None or k.shape[1] != q.shape[1]:
+        return _flash_fwd(*args, None if window is None
+                          else int(window))[0]         # forward only
+    if v.shape[-1] != q.shape[-1]:
+        return _flash_fwd(*args)[0]                     # forward only
+    return _flash(*args)

@@ -47,6 +47,20 @@ stored 640 wide, zeros after them: a DMA cannot slice an HBM array whose rows
 end inside a tile), and narrower queries are padded with zeros to it.
 Everything else is as above.
 
+A **lower bound** a lane (``starts``, beside the lengths and scalar-prefetched
+as they are) is where the lane's context begins: the rows attend to positions
+``starts[b] .. lengths[b] - 1`` alone (a layer that sees only a window of its
+sequence: ``max(0, position - window + 1)``). The loop over blocks then
+starts at the page that holds the bound, pages wholly behind it are neither
+fetched nor computed, and the head of that first page is masked as the tail
+of the last one is. Under a bound the table is a **ring**: logical page p of
+a lane lies in entry ``p mod P`` of its row of P entries (a cache group that
+keeps a window and one page of slack a sequence, ``serving/generate/
+kv_cache.py``), so a lane's positions may pass ``P x page_size`` many times
+over while its live pages, at most P, stay distinct entries. A lane's result
+still depends on its own rows, pages, bound and length alone. With no bound
+nothing above changes: the kernel traces to the text it had.
+
 Off the TPU the same entry point evaluates the same per-lane math as plain
 ``jax.numpy`` over the lane's pages (``interpret=True`` runs the kernel
 itself under the Pallas interpreter: the tests do).
@@ -99,9 +113,13 @@ def _group_width(kv_dim, head_dim, rows):
     return kv_dim
 
 
-def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
+def _kernel(layer_ref, lengths_ref, *refs,
             page_size, pages_per_block, pages_per_chunk, pages_per_seq,
-            batch, sm_scale, v_dim):
+            batch, sm_scale, v_dim, bounded=False):
+    if bounded:     # a lower bound a lane, and its table a ring
+        starts_ref, tables_ref, q_ref, *refs = refs
+    else:
+        tables_ref, q_ref, *refs = refs
     if v_dim is None:
         k_hbm, v_hbm, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, state = refs
     else:       # a latent pool: its row is the keys, its first columns the values
@@ -112,12 +130,20 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
     span = pages_per_chunk * page_size       # positions a pass of compute
     layer = layer_ref[0]
     length = lengths_ref[b]
-    n_blocks = pl.cdiv(length, block)
+    if bounded:
+        # blocks count from the page that holds the lane's bound
+        first_page = lambda lane: starts_ref[lane] // page_size
+        n_blocks = pl.cdiv(pl.cdiv(length, page_size) - first_page(b),
+                           pages_per_block)
+    else:
+        n_blocks = pl.cdiv(length, block)
 
     def live_pages(lane, i):
         """Pages of block ``i`` of ``lane`` that hold a position it sees."""
-        return jnp.minimum(pl.cdiv(lengths_ref[lane], page_size)
-                           - i * pages_per_block, pages_per_block)
+        last = pl.cdiv(lengths_ref[lane], page_size)
+        if bounded:
+            last = last - first_page(lane)
+        return jnp.minimum(last - i * pages_per_block, pages_per_block)
 
     def page_copies(page, slot, j):
         k_copy = pltpu.make_async_copy(k_hbm.at[layer, page],
@@ -130,7 +156,13 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
 
     def start(lane, i, slot):
         def one(j, _):
-            page = tables_ref[lane * pages_per_seq + i * pages_per_block + j]
+            if bounded:
+                page = tables_ref[lane * pages_per_seq + (
+                    first_page(lane) + i * pages_per_block + j)
+                    % pages_per_seq]
+            else:
+                page = tables_ref[lane * pages_per_seq
+                                  + i * pages_per_block + j]
             for c in page_copies(page, slot, j):
                 c.start()
             return ()
@@ -182,7 +214,11 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
             at = pl.ds(c * pages_per_chunk, pages_per_chunk)
             pos = i * block + c * span + lax.broadcasted_iota(
                 jnp.int32, (rows, span), 1)
-            seen = pos < length
+            if bounded:
+                pos = pos + first_page(b) * page_size
+                seen = (pos < length) & (pos >= starts_ref[b])
+            else:
+                seen = pos < length
             for g in range(n_groups):
                 cols = slice(g * width, (g + 1) * width)
                 k = k_buf[slot, at, :, cols].reshape(span, width)
@@ -213,8 +249,8 @@ def _kernel(layer_ref, lengths_ref, tables_ref, q_ref, *refs,
     lax.fori_loop(0, n_blocks, body, ())
 
 
-def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
-                    v_dim, interpret):
+def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, starts=None,
+                    *, sm_scale, v_dim, interpret):
     """``qg`` (B, groups, rows, width), the queries by column group: the
     kernel's (acc (B, groups, rows, width; ``v_dim`` of a latent pool), m, l
     (B, groups, rows, 1))."""
@@ -232,13 +268,14 @@ def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
     kernel = functools.partial(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         pages_per_chunk=pages_per_chunk, pages_per_seq=P, batch=B,
-        sm_scale=sm_scale, v_dim=v_dim)
+        sm_scale=sm_scale, v_dim=v_dim, bounded=starts is not None)
+    bounds = () if starts is None else (starts,)
     lane = lambda b, *_: (b, 0, 0, 0)     # and the prefetched scalars
     buf = pltpu.VMEM((2, pages_per_block, page_size, kv_dim), k_pool.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(bounds),
             grid=(B,),
             in_specs=[pl.BlockSpec((None, n_groups, rows, width), lane)]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -257,22 +294,32 @@ def _pallas_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(layer.reshape(1), lengths, tables.reshape(-1), qg, *pools)
+    )(layer.reshape(1), lengths, *bounds, tables.reshape(-1), qg, *pools)
 
 
-def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
-                   v_dim):
+def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, starts=None,
+                   *, sm_scale, v_dim):
     """The kernel's result as plain ``jax.numpy``: each lane's pages read
     through its table, one masked pass over all of them."""
     B, n_groups, rows, width = qg.shape
-    C = tables.shape[1] * k_pool.shape[2]
+    P, page_size = tables.shape[1], k_pool.shape[2]
+    C = P * page_size
     k = k_pool[layer][tables].reshape(B, C, n_groups, width)
     v = k[..., :v_dim] if v_dim is not None else \
         v_pool[layer][tables].reshape(B, C, n_groups, width)
     prec = _precision(qg.dtype)
     s = jnp.einsum("bgrw,bcgw->bgrc", qg, k, precision=prec,
                    preferred_element_type=jnp.float32) * sm_scale
-    seen = jnp.arange(C, dtype=jnp.int32)[None, :] < lengths[:, None]
+    pos = jnp.arange(C, dtype=jnp.int32)[None, :]
+    if starts is None:
+        seen = pos < lengths[:, None]
+    else:
+        # a ring: entry e holds the one logical page congruent to e among
+        # the P from the bound's page on
+        first = (starts // page_size)[:, None]
+        page = first + (pos // page_size - first) % P
+        pos = page * page_size + pos % page_size
+        seen = (pos < lengths[:, None]) & (pos >= starts[:, None])
     s = jnp.where(seen[:, None, None, :], s, _MASKED)
     m = s.max(-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -282,8 +329,8 @@ def _dense_context(qg, k_pool, v_pool, tables, lengths, layer, *, sm_scale,
     return acc, m, p.sum(-1, keepdims=True)
 
 
-def _attend(q, k_pool, v_pool, tables, lengths, layer, *, heads, kv_heads,
-            sm_scale, v_dim, context):
+def _attend(q, k_pool, v_pool, tables, lengths, layer, starts=None, *,
+            heads, kv_heads, sm_scale, v_dim, context):
     """:func:`paged_attention` over arrays alone: the queries laid out by
     column group, ``context`` (the kernel or the plain expression) over them,
     and its result back by head."""
@@ -310,8 +357,10 @@ def _attend(q, k_pool, v_pool, tables, lengths, layer, *, heads, kv_heads,
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, -rows % tile), (0, 0)))
     acc, m, l = context(qg, k_pool, v_pool, tables.astype(jnp.int32),
                         lengths.astype(jnp.int32),
-                        jnp.asarray(layer, jnp.int32), sm_scale=sm_scale,
-                        v_dim=v_dim)
+                        jnp.asarray(layer, jnp.int32),
+                        *(() if starts is None
+                          else (starts.astype(jnp.int32),)),
+                        sm_scale=sm_scale, v_dim=v_dim)
     # back to (B, L, heads, ...): a head's own D columns of its group (of a
     # latent pool, one group of one head: all ``v_dim`` columns)
     Dv = acc.shape[-1] // per
@@ -343,8 +392,9 @@ _COMPILED = functools.partial(_pallas_context, interpret=False)
 
 
 @register("paged_attention", jit=True)
-def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
-                    kv_heads=None, sm_scale=None, v_dim=None, interpret=None):
+def paged_attention(q, k_pool, v_pool, tables, lengths, layer, starts=None,
+                    *, heads, kv_heads=None, sm_scale=None, v_dim=None,
+                    interpret=None):
     """The context part of a decode step's attention, through the page table.
 
     ``q`` (B, L, heads*D): the step's L rows a lane; ``k_pool``/``v_pool``
@@ -355,6 +405,9 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
     read. Query head h reads KV head ``h // (heads / kv_heads)``. A latent
     pool is ``k_pool`` alone (``v_pool`` None, ``kv_heads`` 1): its row is
     every head's key and its first ``v_dim`` columns their value.
+    ``starts`` (B,) int32, if given: lane b attends to positions
+    ``starts[b]..lengths[b]-1`` alone, and ``tables`` is a ring, logical page
+    p in entry ``p % P`` (module docstring).
 
     Returns ``(acc (B, L, heads, D; ``v_dim`` of a latent pool), m (B, L,
     heads), l (B, L, heads))``, all
@@ -376,4 +429,5 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, heads,
                          "with v_dim stated; K and V pools state none")
     return _jitted(heads, kv_heads, float(sm_scale),
                    None if v_dim is None else int(v_dim), context)(
-        q, k_pool, v_pool, tables, lengths, layer)
+        q, k_pool, v_pool, tables, lengths, layer,
+        *(() if starts is None else (starts,)))

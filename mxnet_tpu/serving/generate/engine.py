@@ -34,7 +34,15 @@ parameters'. A block that states ``kv_latent`` caches one row a position
 that serves as keys and values (latent attention: ``gluon.model_zoo.mla_lm``):
 its pool is one array and not two, its ``prefill_collect`` returns one row
 set a layer and its ``decode_step`` takes the one pool, and the executables
-carry, write and donate that one array; everything else is the same.
+carry, write and donate that one array; everything else is the same. A block
+that states ``cache_groups`` (``(name, layers, window)`` a group: layers that
+keep every position of a sequence and layers that keep a window of it) gets
+a pool of as many groups (``kv_cache.py``): the executables then carry each
+group's arrays and, in place of the one table a lane, each group's, a window
+group's being a ring; a prefill writes a window group the prompt's last
+window's pages alone. A block whose ``prefill_collect`` takes the row to read
+(``prefill_reads_row``) is given ``length - 1``, so that the head multiplies
+that one row and not the bucket's.
 
 Bitwise contract: every model op is per-row and masked lanes carry exactly
 zero softmax weight, so a row's output depends only on its own tokens and
@@ -73,17 +81,38 @@ def _step(block, plist, num_layers, page_size, param_datas, ids, positions,
     ``tables``; then write the rows' K/V in place for the lanes ``valid``
     flags. Returns (logits, whatever the block returned after its K/V, the
     pools)."""
-    import jax.numpy as jnp
     from ...gluon.block import pure_apply
+    by_group = tables if isinstance(tables, tuple) else (tables,)
     outs, _, _ = pure_apply(block, plist, param_datas,
-                            (ids, positions, *pools, tables), None,
+                            (ids, positions, *pools, *by_group), None,
                             training=False, method="decode_step")
-    n = len(pools)
+    n = len(pools) // len(by_group)     # arrays a group: K and V, or one
     last = 1 + n * num_layers
-    rows = tuple(jnp.stack(outs[1 + j:last:n], 0)      # (layers, B[, L], kv)
+    written = ()
+    for g, (layers, window) in enumerate(_groups_of(block, num_layers)):
+        written += write_step(
+            pools[g * n:(g + 1) * n], _group_rows(outs, layers, n),
+            by_group[g], positions, valid, page_size,
+            ring=window is not None)
+    return outs[0], outs[last:], written
+
+
+def _groups_of(block, num_layers):
+    """((layers, window), ...) of the block's cache groups; one group of all
+    layers that keeps everything where it states none."""
+    stated = getattr(block, "cache_groups", None)
+    if not stated:
+        return ((tuple(range(num_layers)), None),)
+    return tuple((tuple(layers), window) for _, layers, window in stated)
+
+
+def _group_rows(outs, layers, n, of=slice(None)):
+    """The rows ``layers`` return for the cache, stacked (layers, ...) per
+    array of a group (``of``: of one sequence of the batch): ``outs`` holds,
+    after its first entry, ``n`` row sets a layer, layer after layer."""
+    import jax.numpy as jnp
+    return tuple(jnp.stack([outs[1 + n * l + j] for l in layers], 0)[:, of]
                  for j in range(n))
-    pools = write_step(pools, rows, tables, positions, valid, page_size)
-    return outs[0], outs[last:], pools
 
 
 def _candidates(logits, mask_id):
@@ -98,6 +127,63 @@ def _candidates(logits, mask_id):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), conf
 
 
+def _prefill(block, plist, page_size, causal, param_datas, tokens, length,
+             table, *pools):
+    """The traced prefill of one prompt: ``prefill_collect`` over the bucket's
+    rows, every layer's rows written into its group's pages, and the first
+    generated token (of a causal model). Returns (next id (1,), the pools)."""
+    import jax.numpy as jnp
+    from ...gluon.block import pure_apply
+    groups = _groups_of(block, int(block.num_layers))
+    # the head for the row that is read, where the block takes it
+    reads_row = causal and getattr(block, "prefill_reads_row", False)
+    outs, _, _ = pure_apply(
+        block, plist, param_datas,
+        (tokens, length - 1) if reads_row else (tokens,),
+        None, training=False, method="prefill_collect")
+    logits = outs[0]            # (1, S, V); (1, 1, V) of the row read
+    by_group = table if isinstance(table, tuple) else (table,)
+    n = len(pools) // len(groups)   # K and V rows a layer, or the latent's
+    written = ()
+    for g, (layers, window) in enumerate(groups):
+        rows = _group_rows(outs, layers, n, 0)         # (layers, S, kv)
+        written += write_prefill(
+            pools[g * n:(g + 1) * n], rows, by_group[g][0], length[0],
+            page_size, window=window)
+    if reads_row:
+        next_id = jnp.argmax(logits[0, 0]).astype(jnp.int32)
+    elif causal:
+        next_id = jnp.argmax(logits[0, length[0] - 1]).astype(jnp.int32)
+    else:
+        # a block's first tokens come from its first denoising step: the
+        # head's product is never computed here
+        next_id = jnp.zeros((), jnp.int32)
+    return (next_id.reshape(1), *written)
+
+
+def _decode(block, plist, page_size, mask_id, param_datas, ids, positions,
+            tables, valid, *pools):
+    """The traced decode step: :func:`_step`, then what the host needs of the
+    logits: the arg-max ids (of a block step the candidates and their
+    confidences) and, where the model routes experts, two numbers of their
+    load. Returns (that, the pools)."""
+    import jax.numpy as jnp
+    logits, aux, pools = _step(
+        block, plist, int(block.num_layers), page_size, param_datas, ids,
+        positions, tables, valid, *pools)
+    if mask_id is None:
+        picked = (jnp.argmax(logits, axis=-1).astype(jnp.int32),)
+    else:
+        picked = _candidates(logits, mask_id)
+    if aux:     # rows routed to each expert, (layers, E):
+        # the busiest's (mean over layers) and the mean
+        load = aux[0].astype(jnp.float32)
+        picked += (load.max(-1).mean(), load.mean())
+    if len(picked) == 1:
+        picked = picked[0]
+    return (picked, *pools)
+
+
 class _Launched:
     """An executable call whose result is still on the chip, from
     ``launch_prefill`` / ``launch_step`` to its ``finish_*``: the bucket it
@@ -108,10 +194,11 @@ class _Launched:
     counters report of it."""
 
     __slots__ = ("bucket", "t0", "under", "result", "overlapped", "lanes",
-                 "commits", "ctx_live")
+                 "commits", "ctx_live", "ctx_by_group")
 
     def __init__(self, bucket: int, *, overlapped: bool = False,
-                 lanes: int = 1, commits: int = 0, ctx_live: int = 0):
+                 lanes: int = 1, commits: int = 0, ctx_live: int = 0,
+                 ctx_by_group: tuple = ()):
         self.bucket = bucket
         self.t0 = _now_us()
         self.under = _telemetry.current_span()
@@ -120,6 +207,7 @@ class _Launched:
         self.lanes = lanes
         self.commits = commits
         self.ctx_live = ctx_live
+        self.ctx_by_group = ctx_by_group    # positions each group's layers read
 
     def send_home(self):
         """Start the result's copy to the host now, behind its call on the
@@ -138,7 +226,7 @@ class DecodeEndpoint:
     ``gluon.model_zoo.bert.TransformerLM``: ``num_layers``/``units``
     attributes, ``prefill_collect(tokens)`` and
     ``decode_step(ids, positions, k_pool, v_pool, tables)``; optionally
-    ``kv_units``, ``kv_latent``,
+    ``kv_units``, ``kv_latent``, ``cache_groups``, ``prefill_reads_row``,
     ``block_length`` and ``mask_token_id`` (module docstring), and after the
     layers' K/V a ``decode_step`` may return the rows routed to each expert,
     (layers, experts), which the step reduces to two numbers.
@@ -197,6 +285,12 @@ class DecodeEndpoint:
         self.last_step: Dict[str, object] = {}
         self._step_in_flight = False    # between launch_step and finish_step
         self._probe()
+        stated = getattr(block, "cache_groups", None)
+        if stated and self.block_length > 1:
+            raise MXNetError(
+                f"decode endpoint {name!r}: cache groups under blocks of "
+                f"{self.block_length} positions (a window is the causal "
+                "step's)")
         self.pool = PagedKVPool(name, int(block.num_layers),
                                 int(getattr(block, "kv_units", block.units)),
                                 self.max_seq_len,
@@ -204,7 +298,11 @@ class DecodeEndpoint:
                                 dtype=self._param_datas()[0].dtype,
                                 device=self.ctx.jax_device(),
                                 latent=bool(getattr(block, "kv_latent",
-                                                    False)))
+                                                    False)),
+                                **({} if not stated else {
+                                    "groups": [(g, len(layers), window) for
+                                               g, layers, window in stated],
+                                    "max_seqs": self.max_batch_size}))
         if self.max_seq_len % self.block_length \
                 or self.pool.page_size % self.block_length:
             raise MXNetError(
@@ -285,32 +383,14 @@ class DecodeEndpoint:
     # ------------------------------------------------------------------
     def _prefill_fn(self):
         if self._pf_jfn is None:
-            import jax
-            import jax.numpy as jnp
-            from ...gluon.block import pure_apply
             block, plist = self.block, self._params
             page_size = int(_config.get("MXNET_KV_PAGE_SIZE")) \
                 if not hasattr(self, "pool") else self.pool.page_size
             causal = self.mask_token_id is None
 
             def prefill(param_datas, tokens, length, table, *pools):
-                outs, _, _ = pure_apply(block, plist, param_datas, (tokens,),
-                                        None, training=False,
-                                        method="prefill_collect")
-                logits = outs[0]                       # (1, S, V)
-                n = len(pools)      # K and V rows a layer, or the latent's
-                rows = tuple(jnp.stack(outs[1 + j::n], 0)[:, 0]
-                             for j in range(n))        # (layers, S, kv)
-                pools = write_prefill(pools, rows, table[0], length[0],
-                                      page_size)
-                if causal:
-                    next_id = jnp.argmax(logits[0, length[0] - 1]) \
-                        .astype(jnp.int32)
-                else:
-                    # a block's first tokens come from its first denoising
-                    # step: the head's product is never computed here
-                    next_id = jnp.zeros((), jnp.int32)
-                return (next_id.reshape(1), *pools)
+                return _prefill(block, plist, page_size, causal, param_datas,
+                                tokens, length, table, *pools)
 
             donate = self._pool_args(4) if self._donate_pools() else ()
             self._pf_jfn = self._jit_prefill(prefill, donate)
@@ -318,29 +398,13 @@ class DecodeEndpoint:
 
     def _decode_fn(self):
         if self._dec_jfn is None:
-            import jax
-            import jax.numpy as jnp
-            from ...gluon.block import pure_apply
             block, plist = self.block, self._params
             page_size = self.pool.page_size
-            num_layers = int(block.num_layers)
             mask_id = self.mask_token_id
 
             def decode(param_datas, ids, positions, tables, valid, *pools):
-                logits, aux, pools = _step(
-                    block, plist, num_layers, page_size, param_datas, ids,
-                    positions, tables, valid, *pools)
-                if mask_id is None:
-                    picked = (jnp.argmax(logits, axis=-1).astype(jnp.int32),)
-                else:
-                    picked = _candidates(logits, mask_id)
-                if aux:     # rows routed to each expert, (layers, E):
-                    # the busiest's (mean over layers) and the mean
-                    load = aux[0].astype(jnp.float32)
-                    picked += (load.max(-1).mean(), load.mean())
-                if len(picked) == 1:
-                    picked = picked[0]
-                return (picked, *pools)
+                return _decode(block, plist, page_size, mask_id, param_datas,
+                               ids, positions, tables, valid, *pools)
 
             donate = self._pool_args(5) if self._donate_pools() else ()
             self._dec_jfn = self._jit_decode(decode, donate)
@@ -351,17 +415,22 @@ class DecodeEndpoint:
     # ------------------------------------------------------------------
     def _pool_sds(self):
         import jax
-        return tuple(jax.ShapeDtypeStruct(self.k_pool_shape, self.pool_dtype)
-                     for _ in self.pool.arrays)
+        return tuple(jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
+                     for a in self.pool.arrays)
+
+    def _tables_sds(self, batch: int):
+        """The page tables of ``batch`` lanes as an executable takes them:
+        one (batch, P) array, or a tuple of them a cache group."""
+        import jax
+        import jax.numpy as jnp
+        sds = tuple(jax.ShapeDtypeStruct((batch, g.pages_per_seq), jnp.int32)
+                    for g in self.pool.groups)
+        return sds[0] if len(sds) == 1 else sds
 
     def _pool_args(self, first: int):
         """Argument numbers of the pool's arrays, which follow an
         executable's other arguments from ``first`` on."""
         return tuple(range(first, first + len(self.pool.arrays)))
-
-    @property
-    def k_pool_shape(self):
-        return tuple(self.pool.k_pool.shape)
 
     @property
     def pool_dtype(self):
@@ -413,10 +482,9 @@ class DecodeEndpoint:
     def _get_prefill(self, seq_bucket: int):
         import jax
         import jax.numpy as jnp
-        P = self.pool.pages_per_seq
         arg_sds = (jax.ShapeDtypeStruct((1, seq_bucket), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32),
-                   jax.ShapeDtypeStruct((1, P), jnp.int32)) + self._pool_sds()
+                   self._tables_sds(1)) + self._pool_sds()
         return self._compile(self._prefill_execs, seq_bucket,
                              self._prefill_fn(), arg_sds, "prefill")
 
@@ -429,11 +497,10 @@ class DecodeEndpoint:
     def _get_decode(self, batch_bucket: int):
         import jax
         import jax.numpy as jnp
-        P = self.pool.pages_per_seq
         rows = self._rows_shape(batch_bucket)
         arg_sds = (jax.ShapeDtypeStruct(rows, jnp.int32),
                    jax.ShapeDtypeStruct(rows, jnp.int32),
-                   jax.ShapeDtypeStruct((batch_bucket, P), jnp.int32),
+                   self._tables_sds(batch_bucket),
                    jax.ShapeDtypeStruct((batch_bucket,), jnp.bool_)) \
             + self._pool_sds()
         return self._compile(self._decode_execs, batch_bucket,
@@ -455,7 +522,8 @@ class DecodeEndpoint:
                 if execute:
                     toks = onp.zeros((1, b), onp.int32)
                     length = onp.asarray([1], onp.int32)
-                    table = onp.zeros((1, P), onp.int32)
+                    table = self.pool.split_tables(
+                        onp.zeros((1, P), onp.int32))
                     t0 = _now_us()
                     out = comp(self._param_datas(), toks, length, table,
                                *self.pool.arrays)
@@ -470,7 +538,8 @@ class DecodeEndpoint:
                 if execute:
                     ids = onp.zeros(self._rows_shape(b), onp.int32)
                     pos = onp.zeros(self._rows_shape(b), onp.int32)
-                    tables = onp.zeros((b, P), onp.int32)
+                    tables = self.pool.split_tables(
+                        onp.zeros((b, P), onp.int32))
                     valid = onp.zeros((b,), bool)
                     t0 = _now_us()
                     out = comp(self._param_datas(), ids, pos, tables, valid,
@@ -504,7 +573,8 @@ class DecodeEndpoint:
         call = _Launched(S, overlapped=self._step_in_flight)
         with _telemetry.span("decode.launch", kind="prefill", bucket=S):
             call.result, *pools = comp(
-                self._param_datas(), toks, length, table.reshape(1, -1),
+                self._param_datas(), toks, length,
+                self.pool.split_tables(table.reshape(1, -1)),
                 *self.pool.arrays)
         self.pool.update_arrays(*pools)
         call.send_home()
@@ -560,8 +630,17 @@ class DecodeEndpoint:
                 tables[i] = row[2]
                 valid[i] = len(row) < 4 or row[3]
                 ctx_live += row[1]      # the cached positions it attends to
+            # the positions each cache group's layers must read of them: all,
+            # or no more than the window less the row itself
+            by_group = tuple(
+                int(ctx_live) if g.window is None else
+                sum(min(row[1], g.window - 1) for row in rows)
+                for g in self.pool.groups)
+            if len(by_group) > 1:
+                self.pool.ring_overwrites(row[1] for row in rows)
+            tables = self.pool.split_tables(tables)
         call = _Launched(B, lanes=n, commits=int(valid.sum()),
-                         ctx_live=int(ctx_live))
+                         ctx_live=int(ctx_live), ctx_by_group=by_group)
         with _telemetry.span("decode.launch", kind="step", bucket=B):
             call.result, *pools = comp(
                 self._param_datas(), ids, pos, tables, valid,
@@ -591,6 +670,12 @@ class DecodeEndpoint:
         self.last_step = {"commits": call.commits, "ctx_live": ctx[0],
                           "ctx_capacity": ctx[1],
                           "fetch_wait_us": sp.dur_us}
+        # what the window layers must read of the live context (a window
+        # group's positions; of a model without one, there is no such attr)
+        ctx_window = [c for c, g in zip(call.ctx_by_group, self.pool.groups)
+                      if g.window is not None]
+        if ctx_window:
+            self.last_step["ctx_window_live"] = ctx_window[0]
         # the straggler a grouped expert product waits for against the rows
         # an expert gets on average (every row the executable computes is
         # routed, padding lanes too): after the ids (and, of a block step,
@@ -601,7 +686,11 @@ class DecodeEndpoint:
                 ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
         self.stats.record_step(dt, n, call.bucket, rows=n * L,
                                commits=call.commits, expert_load=expert_load,
-                               ctx=ctx, ctx_bytes=ctx[0] * self.pool.row_bytes,
+                               ctx=ctx, ctx_bytes=sum(
+                                   c * b for c, b in zip(
+                                       call.ctx_by_group,
+                                       self.pool.group_row_bytes)),
+                               ctx_window=sum(ctx_window),
                                fetch_wait_us=sp.dur_us)
         if L == 1:
             return tuple(int(x) for x in out[0][:n])

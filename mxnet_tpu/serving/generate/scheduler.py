@@ -15,7 +15,9 @@ Correctness invariants (the chaos scenario asserts all three):
   sequence's position advanced under one lock, *after* the device step
   completes. A worker that dies mid-step has emitted nothing for that step.
 - **Whole-budget reservation**: ``ceil((prompt+max_new)/page_size)`` pages
-  are reserved at admission, so KV exhaustion can only happen *before* a
+  are reserved at admission (in every cache group of the pool, all or
+  nothing: a group that keeps a window reserves its ring; ``free`` returns
+  every group's), so KV exhaustion can only happen *before* a
   sequence starts — it stays queued (``KVPoolExhausted`` is absorbed) and
   there is never a half-generated sequence to unwind or re-prefill (which
   would not be bitwise-safe across the prefill/decode paths).

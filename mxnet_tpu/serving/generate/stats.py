@@ -122,7 +122,10 @@ class DecodeStats:
             "blocks_committed": 0,
             # cached positions the steps' lanes attended to, of those their
             # lanes could hold (lanes x max_seq_len)
+            # ctx_window_live: of them, those a layer that keeps a window
+            # has to read (0 of a model without such layers)
             "ctx_live": 0, "ctx_capacity": 0, "ctx_bytes": 0,
+            "ctx_window_live": 0,
             "moe.expert_load_max": 0.0, "moe.expert_load_mean": 0.0,
             # how often a pass's order engages: prefills launched while a
             # step was in flight, of all; and the time steps' fetches
@@ -164,7 +167,8 @@ class DecodeStats:
 
     def record_step(self, dur_us: float, seqs: int, bucket: int, *,
                     rows: int = None, commits: int = None, expert_load=(),
-                    ctx=(0, 0), ctx_bytes: int = 0, fetch_wait_us: int = 0):
+                    ctx=(0, 0), ctx_bytes: int = 0, ctx_window: int = 0,
+                    fetch_wait_us: int = 0):
         """One step executable run over ``seqs`` sequences padded to
         ``bucket``: ``rows`` forwarded (default one a sequence), ``commits``
         of them writing their K/V (default all), and where the model routes
@@ -175,7 +179,9 @@ class DecodeStats:
         their lanes can hold): what the step's attention read of what a
         gather of all lanes would have, and ``ctx_bytes`` the bytes of the
         pool those positions are (positions x the pool's row over all layers
-        and arrays: a latent pool's row once, a K and a V row otherwise):
+        and arrays: a latent pool's row once, a K and a V row otherwise;
+        summed over the pool's cache groups, each at the positions its
+        layers read, ``ctx_window`` of them in a group that keeps a window):
         what the step's attention had to read. ``dur_us`` runs from the step's
         launch until its result was in hand, ``fetch_wait_us`` is the part of
         it the fetch blocked."""
@@ -190,6 +196,7 @@ class DecodeStats:
             self.counters["ctx_live"] += ctx[0]
             self.counters["ctx_capacity"] += ctx[1]
             self.counters["ctx_bytes"] += ctx_bytes
+            self.counters["ctx_window_live"] += ctx_window
             self.counters["step_fetch_wait_us"] += fetch_wait_us
             if expert_load:
                 self.counters["moe.expert_load_max"] += expert_load[0]
@@ -255,6 +262,8 @@ class DecodeStats:
         with self._lock:
             return {
                 "counters": dict(self.counters),
+                "ctx_live": self.counters["ctx_live"],
+                "ctx_window_live": self.counters["ctx_window_live"],
                 "ctx_live_share": self.counters["ctx_live"]
                 / max(1, self.counters["ctx_capacity"]),
                 "prefill": self.prefill.snapshot(),

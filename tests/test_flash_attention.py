@@ -1,4 +1,6 @@
 """Flash attention kernel vs dense reference (fwd + grads)."""
+import math
+
 import numpy as onp
 import pytest
 
@@ -208,3 +210,78 @@ def test_short_seq_dense_route_and_fidelity(causal, monkeypatch):
     for gt, w, name in zip(got_g, want_g, "q k v".split()):
         onp.testing.assert_allclose(onp.asarray(gt), onp.asarray(w),
                                     rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# a window, and KV heads shared by a group of query heads (forward only)
+# ---------------------------------------------------------------------------
+def _dense_banded(q, k, v, window):
+    """The dense mask built from positions: j <= i and i - j < window; K and
+    V repeated by head group."""
+    G = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, G, 1), jnp.repeat(v, G, 1)
+    S = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+        / math.sqrt(q.shape[-1])
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+
+# S not a multiple of the block; the window smaller than a block, equal to
+# it, larger than it, and larger than the sequence; blocks of unequal size
+@pytest.mark.parametrize("S,bq,bk,window", [
+    (200, 64, 64, 32), (200, 64, 64, 64), (200, 64, 64, 100),
+    (256, 64, 128, 48), (300, 128, 64, 70), (150, 64, 64, 400),
+    (192, 64, 64, None)])
+def test_flash_window_and_grouped_heads_match_the_dense_mask(S, bq, bk,
+                                                             window):
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+    keys = jax.random.split(jax.random.PRNGKey(S), 3)
+    q = jax.random.normal(keys[0], (2, 8, S, 32))
+    k = jax.random.normal(keys[1], (2, 2, S, 32))
+    v = jax.random.normal(keys[2], (2, 2, S, 32))
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=bq,
+                          block_k=bk, interpret=True)
+    onp.testing.assert_allclose(out, _dense_banded(q, k, v, window),
+                                atol=2e-5)
+    # the short rows' dense route masks the same
+    short = flash_attention(q[:, :, :48], k[:, :, :48], v[:, :, :48],
+                            causal=True, window=window)
+    onp.testing.assert_allclose(
+        short, _dense_banded(q[:, :, :48], k[:, :, :48], v[:, :, :48],
+                             window), atol=2e-5)
+    if window is None:
+        return
+    # blocks wholly behind the band are not visited: the grid's k axis is the
+    # band's blocks alone
+    Sp = -(-S // max(bq, bk)) * max(bq, bk)
+    visited = fa._band_blocks(Sp, bq, bk, window)
+    # (the band under a q block spans bq + window - 1 columns: window / block
+    # + 2 blocks where q and k blocks are equal)
+    assert visited <= -(-(bq + window - 1) // bk) + 1
+    jaxpr = str(jax.make_jaxpr(lambda *a: flash_attention(
+        *a, causal=True, window=window, block_q=bq, block_k=bk,
+        interpret=True))(q, k, v))
+    assert f"grid=(16, {Sp // bq}, {min(visited, Sp // bk)})" in jaxpr
+
+
+def test_flash_window_costs_the_band_not_the_square():
+    """At the served shapes (16,384 rows, blocks of 1,024, a window of 1,024)
+    a q block visits 2 k blocks where the causal kernel's grid has 16: a
+    window layer's prefill is an eighth of a full one's (half the square is
+    8.5 blocks a q block)."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+    assert fa._band_blocks(16384, 1024, 1024, 1024) == 2
+    assert fa._band_blocks(4096, 512, 1024, 1024) == 2
+    assert fa._band_blocks(8192, 512, 1024, 1024) == 2
+
+
+def test_flash_window_is_forward_only_and_causal():
+    q = jnp.ones((1, 2, 128, 16))
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, window=8)
+    with pytest.raises(Exception):      # no backward kernel takes a window
+        jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True, window=8, interpret=True).sum())(q)

@@ -255,3 +255,165 @@ def test_a_latent_pool_states_its_value_width():
     with pytest.raises(ValueError, match="latent pool"):
         paged_attention(q, pool, pool, tables, lengths, LAYER, heads=8,
                         kv_heads=1, v_dim=96)
+
+
+# ---------------------------------------------------------------------------
+# a lower bound a lane, and a table that is a ring
+# ---------------------------------------------------------------------------
+WINDOW = 40
+RING = -(-(WINDOW + PAGE) // PAGE)          # 4 pages: the window and a page
+
+
+def _ring_case(dtype, heads, kv_heads, D, lengths, starts=None, seed=0):
+    """Lanes whose positions run round a ring of RING pages: position p of
+    lane b lies in ``tables[b, p // PAGE % RING]``. Each lane's live
+    positions hold fresh rows; the rest of its ring what it held before."""
+    q, k_pool, v_pool, tables, _ = _case(dtype, 1, heads, kv_heads, D,
+                                         lengths, seed)
+    lengths = onp.asarray(lengths, onp.int32)
+    if starts is None:
+        starts = onp.maximum(lengths - WINDOW + 1, 0)
+    return (q, k_pool, v_pool, tables[:, :RING],
+            jnp.asarray(lengths), jnp.asarray(starts, jnp.int32))
+
+
+def _ring_reference(q, k_pool, v_pool, tables, lengths, starts, heads,
+                    kv_heads):
+    """(out, m, l) a lane, float32 at ``highest``: positions ``starts[b] ..
+    lengths[b] - 1`` gathered one by one through the ring."""
+    D = k_pool.shape[-1] // kv_heads
+    outs = []
+    for b in range(len(lengths)):
+        pos = onp.arange(int(starts[b]), int(lengths[b]))
+        if not len(pos):
+            outs.append(None)
+            continue
+        page = onp.asarray(tables)[b, pos // PAGE % tables.shape[1]]
+        k = _f32(k_pool[LAYER, page, pos % PAGE]).reshape(-1, kv_heads, D)
+        v = _f32(v_pool[LAYER, page, pos % PAGE]).reshape(-1, kv_heads, D)
+        qh = _f32(q[b, 0]).reshape(kv_heads, heads // kv_heads, D)
+        s = jnp.einsum("hgd,chd->hgc", qh, k,
+                       precision="highest") / onp.sqrt(D)
+        m = s.max(-1)
+        p = jnp.exp(s - m[..., None])
+        acc = jnp.einsum("hgc,chd->hgd", p, v, precision="highest")
+        outs.append((acc.reshape(heads, D), m.reshape(heads),
+                     p.sum(-1).reshape(heads)))
+    return outs
+
+
+# lengths whose bounds lie at 0, inside a page, at a page's edge (length 56:
+# bound 17; 55: 16), and past the ring's 64 positions one and several times
+BOUNDED = [0, 1, 40, 41, 55, 56, 65, 200, 1000]
+
+
+@pytest.mark.parametrize("interpret", [True, None],
+                         ids=["kernel", "plain_expression"])
+@pytest.mark.parametrize("dtype,heads,kv_heads,D,tol", [
+    pytest.param(jnp.float32, 4, 4, 64, 2e-5, id="fused_heads_f32"),
+    pytest.param(jnp.bfloat16, 32, 4, 128, 2e-2, id="grouped_heads_bf16")])
+def test_a_bound_and_a_ring_match_the_dense_expression(dtype, heads,
+                                                       kv_heads, D, tol,
+                                                       interpret):
+    args = _ring_case(dtype, heads, kv_heads, D, BOUNDED)
+    acc, m, l = paged_attention(*args[:5], LAYER, args[5], heads=heads,
+                                kv_heads=kv_heads, interpret=interpret)
+    want = _ring_reference(*args, heads, kv_heads)
+    assert float(l[0].max()) == 0.0          # length 0: nothing seen
+    for b, row in enumerate(want):
+        if row is None:
+            continue
+        w_acc, w_m, w_l = row
+        onp.testing.assert_allclose(m[b, 0], w_m, atol=tol * 10, rtol=tol)
+        onp.testing.assert_allclose(l[b, 0], w_l, rtol=tol * 5)
+        onp.testing.assert_allclose(acc[b, 0] / l[b, 0][:, None],
+                                    w_acc / w_l[:, None], atol=tol)
+
+
+def test_what_lies_behind_a_bound_changes_no_bit(monkeypatch):
+    """Garbage behind each lane's bound (the head of its first live page, the
+    ring's older pages) and past its length: bitwise what zeros there give.
+    Blocks of two pages and chunks of one, so that a lane's live pages span
+    blocks and the first block starts inside the ring."""
+    from mxnet_tpu.ops.pallas import paged_attention as mod
+    monkeypatch.setattr(mod, "_BLOCK_BYTES", 2 * PAGE * 64 * 4 * 4)
+    monkeypatch.setattr(mod, "_CHUNK_BYTES", PAGE * 64 * 4 * 4)
+    mod._jitted.cache_clear()
+    lengths = [3, 41, 56, 65, 200, 1000]
+    q, k_pool, v_pool, tables, lens, starts = _ring_case(
+        jnp.float32, 4, 4, 64, lengths)
+
+    def fill(pool, value):
+        for b, n in enumerate(lengths):
+            live = onp.zeros((RING, PAGE), bool)
+            pos = onp.arange(int(starts[b]), n)
+            live[pos // PAGE % RING, pos % PAGE] = True
+            rows = jnp.where(jnp.asarray(live)[None, :, :, None],
+                             pool[:, tables[b]], value)
+            pool = pool.at[:, tables[b]].set(rows.astype(pool.dtype))
+        return pool.at[:, 0].set(value)
+
+    try:
+        outs = [paged_attention(q, fill(k_pool, value), fill(v_pool, -value),
+                                tables, lens, LAYER, starts, heads=4,
+                                kv_heads=4, interpret=True)
+                for value in (0.0, GARBAGE)]
+        want = _ring_reference(q, k_pool, v_pool, tables, lens, starts, 4, 4)
+    finally:
+        mod._jitted.cache_clear()
+    for clean, dirty in zip(*outs):
+        assert onp.array_equal(onp.asarray(clean), onp.asarray(dirty))
+    acc, _, l = outs[1]
+    for b, (w_acc, _, w_l) in enumerate(want):
+        onp.testing.assert_allclose(acc[b, 0] / l[b, 0][:, None],
+                                    w_acc / w_l[:, None], atol=2e-5)
+
+
+def test_a_bounded_lane_depends_on_nothing_but_itself():
+    lengths = [200] * 8
+    q, k_pool, v_pool, tables, lens, starts = _ring_case(
+        jnp.bfloat16, 32, 4, 128, lengths)
+    op = lambda q, t, n, s: paged_attention(
+        q, k_pool, v_pool, t, n, LAYER, s, heads=32, kv_heads=4,
+        interpret=True)
+    alone = op(q[:1], tables[:1], lens[:1], starts[:1])
+    rng = onp.random.default_rng(2)
+    order = onp.asarray([3, 5, 0, 6, 1, 2, 4, 7])
+    others = rng.integers(0, 400, 8)
+    others[2] = 200
+    among = op(q[order], tables[order], jnp.asarray(others, jnp.int32),
+               jnp.asarray(onp.maximum(others - WINDOW + 1, 0), jnp.int32))
+    for one, many in zip(alone, among):
+        assert onp.array_equal(onp.asarray(one[0]), onp.asarray(many[2]))
+
+
+@pytest.mark.parametrize("dtype,L,heads,kv_heads,D,tol", FAMILIES)
+def test_with_no_bound_the_result_is_what_it_was(dtype, L, heads, kv_heads,
+                                                 D, tol):
+    """A bound of 0 on a table that never wraps reads what no bound reads,
+    bit for bit; and no bound at all takes the kernel that was there (its
+    traced program names no fourth prefetched scalar)."""
+    args = _case(dtype, L, heads, kv_heads, D, LENGTHS)
+    plain = _op(heads, kv_heads, True)(*args)
+    bounded = paged_attention(*args, LAYER, jnp.zeros(len(LENGTHS),
+                                                      jnp.int32),
+                              heads=heads, kv_heads=kv_heads, interpret=True)
+    for a, b in zip(plain, bounded):
+        assert onp.array_equal(onp.asarray(a), onp.asarray(b))
+    def prefetched(*extra):
+        jaxpr = jax.make_jaxpr(lambda *a: paged_attention(
+            *a[:5], LAYER, *a[5:], heads=heads, kv_heads=kv_heads,
+            interpret=True))(*args, *extra)
+        found = []
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(eqn.params["grid_mapping"].num_index_operands)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert prefetched() == [3]          # layer, lengths, tables
+    assert prefetched(jnp.zeros(len(LENGTHS), jnp.int32)) == [4]

@@ -308,6 +308,31 @@ def test_no_row_is_dropped_when_all_rows_choose_one_expert():
     onp.testing.assert_allclose(out, ref.experts(x, p, DIMS), **TOL)
 
 
+@pytest.mark.parametrize("rows", [48, 53, 16])
+def test_a_long_prompts_pairs_go_a_block_of_whole_rows_at_a_time(
+        rows, monkeypatch):
+    """Where a chip holds every expert and the pairs pass the block
+    (``_ALL_HELD_PAIRS``: 32,768 on the chip, 32 here), the rows go 16 at a
+    time, the last block padded with rows that route nowhere: bitwise what
+    each row gets alone in its block's place, the reference's result, and
+    every pair counted once. 16 rows are one pass as ever."""
+    p = layer_weights(7)
+    x = jnp.asarray(onp.random.default_rng(8).normal(0, 1, (rows, 64)),
+                    jnp.float32)
+    args = (p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    whole, whole_load = ops.moe_ffn(x, *args, top_k=2)
+    monkeypatch.setattr(ops, "_ALL_HELD_PAIRS", 32)
+    out, load = ops.moe_ffn(x, *args, top_k=2)
+    onp.testing.assert_array_equal(load, whole_load)
+    assert int(load.sum()) == rows * 2
+    onp.testing.assert_allclose(out, ref.experts(x, p, DIMS), **TOL)
+    onp.testing.assert_array_equal(out, whole)      # a row's own order
+    if rows > 16:
+        text = str(jax.make_jaxpr(lambda x: ops.moe_ffn(x, *args, top_k=2))(
+            x))
+        assert "f32[16,64]" in text and "scan" in text
+
+
 @pytest.mark.parametrize("shares", [2, 4])
 def test_the_shares_of_the_experts_add_up_to_the_whole_layer(shares):
     p = layer_weights(3)

@@ -379,3 +379,262 @@ def test_a_train_step_draws_dropout_bits_for_its_own_shard_only(
     assert draws and set(draws) == {"u32[256,128,768]"}
     if chips == 1:
         assert "partition-id" not in text
+
+
+# ---------------------------------------------------------------------------
+# cell mellum2_12b.repo_qa_c32 at its published widths: 32 query heads on 4 KV
+# heads of 128 in bfloat16; the full group's pools of 2 layers and 32,769
+# pages (1,024 a lane), the window group's of 6 layers and 32 rings of 65
+# pages + the scratch page; a window of 1,024
+# ---------------------------------------------------------------------------
+FULL_POOL, RING_POOL, RING = (2, 32769, 16, 512), (6, 2081, 16, 512), 65
+
+
+def test_paged_attention_with_a_bound_on_a_ring_compiles_to_one_kernel(
+        one_chip):
+    comp = jax.jit(lambda q, k, v, tables, lengths, starts: paged_attention(
+        q, k, v, tables, lengths, 5, starts, heads=32, kv_heads=4,
+        interpret=False)).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+                ((32, 1, 4096), jnp.bfloat16), (RING_POOL, jnp.bfloat16),
+                (RING_POOL, jnp.bfloat16), ((32, RING), I32), ((32,), I32),
+                ((32,), I32))]).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the name the new reader looks for in a trace
+    assert "f32[32,1,32,512]" in text
+    assert comp.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("S", [4096, 16384])
+def test_flash_with_a_window_and_grouped_heads_compiles_to_one_kernel(
+        one_chip, S):
+    comp = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=1024, interpret=False)).lower(*[
+            jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((1, 32, S, 128), (1, 4, S, 128), (1, 4, S, 128))]
+    ).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"bf16[32,{S},128]" in text
+    # K and V are read through the index map: nothing repeats them eightfold
+    # (the scratch is the log-sum-exp the forward drops, S x 32 x 128 float32;
+    # K and V repeated would be as much again)
+    assert comp.memory_analysis().temp_size_in_bytes < 1.25 * S * 32 * 128 * 4
+
+
+@pytest.fixture(scope="module")
+def cut_model():
+    """The cut model of the cell as a block with no weights drawn (its
+    shapes are all a compile needs), its parameters as shapes, and the two
+    groups' pools."""
+    from chipbench import harness
+    from chipbench.models import mellum2 as family
+    from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
+    cell, config = harness.load_cell("mellum2_12b.repo_qa_c32")
+    lm = MoEDecoderLM(
+        num_layers=config["num_hidden_layers"], units=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        expert_hidden=config["moe_intermediate_size"],
+        num_experts=config["num_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        vocab_size=config["vocab_size"], dtype="bfloat16",
+        layer_types=config["layer_types"],
+        sliding_window=config["sliding_window"],
+        rope_by_type=family.rope_by_type(config), prefix="cut_")
+    plist = list(lm.collect_params().values())
+    assert sum(int(onp.prod(p.shape)) for p in plist) == 3_794_968_832
+    return cell, lm, plist
+
+
+import numpy as onp  # noqa: E402  (the fixture above)
+
+
+def _cut_model_args(one_chip, plist, lanes):
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    params = tuple(sds(tuple(p.shape), jnp.bfloat16) for p in plist)
+    tables = (sds((lanes, 1024), I32), sds((lanes, RING), I32))
+    pools = (sds(FULL_POOL, jnp.bfloat16),) * 2 \
+        + (sds(RING_POOL, jnp.bfloat16),) * 2
+    return sds, params, tables, pools
+
+
+def test_the_cut_models_longest_prefill_compiles_within_the_chip(
+        one_chip, cut_model, monkeypatch):
+    """The 16,384-row ``jit_prefill`` at the published widths: eight flash
+    kernels and the routed passes' grouped matmuls, under 3.5 GB of
+    temporaries beside 10.15 GB of weights and pools, and no (S, V) array:
+    the head multiplies the one row that is read."""
+    import functools
+    from mxnet_tpu.serving.generate import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell, lm, plist = cut_model
+    S = cell["max_seq_len"]
+    sds, params, tables, pools = _cut_model_args(one_chip, plist, 1)
+    comp = jax.jit(functools.partial(engine._prefill, lm, plist, 16, True),
+                   donate_argnums=(4, 5, 6, 7)).lower(
+        params, sds((1, S), I32), sds((1,), I32), tables, *pools).compile()
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.5e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    assert not re.search(r"\[(1,)?16384,98304\]", text)
+    assert "f32[98304]" in text         # one row's logits
+    flash = re.findall(
+        r"= \(bf16\[32,16384,128\]\S*, f32\[32,16384,128\]\S*\) custom-call\(",
+        text)
+    assert len(flash) == 8
+    # the routed pairs 32,768 at a time: no product over all 131,072
+    assert "f32[32768,896]" in text and "f32[131072," not in text
+
+
+def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
+                                               monkeypatch):
+    """The 32-lane ``jit_decode``: eight paged-attention kernels (two on the
+    full group's pools, six bounded on the rings) and 24 grouped matmuls;
+    the four pools donated and written in place."""
+    import functools
+    from mxnet_tpu.serving.generate import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, lm, plist = cut_model
+    sds, params, tables, pools = _cut_model_args(one_chip, plist, 32)
+    comp = jax.jit(functools.partial(engine._decode, lm, plist, 16, None),
+                   donate_argnums=(5, 6, 7, 8)).lower(
+        params, sds((32,), I32), sds((32,), I32), tables,
+        sds((32,), jnp.bool_), *pools).compile()
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 8 + 24
+    assert len(re.findall(r"= \(f32\[32,1,32,512\]", text)) == 8
+    assert "input_output_alias" in text.splitlines()[0]
+    for pool in (FULL_POOL, RING_POOL):
+        makers = set(re.findall(
+            r"= bf16\[%d,%d,%d,%d\]\{[^}]*\} ([\w-]+)\(" % pool, text))
+        assert makers and "copy" not in makers, makers
+    assert comp.memory_analysis().temp_size_in_bytes < 128 << 20
+
+
+# ---------------------------------------------------------------------------
+# the other decode families' programs did not move: a pool of one group and
+# the kernel without a bound lower, for the TPU, to the StableHLO text they
+# had at the parent commit (PR 33, 2320329), held here as digests of that
+# text. The digests were made by running ``_program_digests`` of this file
+# with the parent's tree first on the path; source locations, the kernels'
+# debug info and the numbers jax appends to private functions' names are
+# taken out of the text first. ``deepseek_v3``'s prefill is not among them:
+# its head now multiplies the one row that is read.
+# ---------------------------------------------------------------------------
+PARENT_DIGESTS = {
+    "gpt1.step":
+        "299ddc580175265bde6fac71701056159be0eb49e6ab1c5ec5e1fe5e1e8c1795",
+    "gpt1.prefill_s64":
+        "17329dffc7c7888ff4b5ca935b403de7c32143789aef58b684d460dcc4802647",
+    "gpt1.prefill_s512":
+        "7699916e884597e316aa7c18deab21c29f862dfa1e5179481b238bd10ae7d11b",
+    "sdar_30b_a3b.step":
+        "2bcd6bd4e5cff2bc0a7f61399f0213c294a4e3c44f31a9839ad749bb527016a0",
+    "sdar_30b_a3b.prefill_s64":
+        "3c0a79af02da8ad91edc68b57d4f149d2fe3b87220d69ed7df120f22fb5a8762",
+    "sdar_30b_a3b.prefill_s512":
+        "bef4d8d52005c627a011ccd974109c6889ea9631ca18e07dc4c9072ec810551f",
+    "deepseek_v3.step":
+        "47e86cd308853c1b534a7cf8c6f4f02358da7b6d2d7b7f06c528c2f14644a24b",
+}
+
+
+def _small_endpoints():
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo.bert import TransformerLM
+    from mxnet_tpu.gluon.model_zoo.mla_lm import MLADecoderLM
+    from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
+    lm = TransformerLM(vocab_size=96, units=64, hidden_size=128, num_layers=2,
+                       num_heads=4, max_length=1024, prefix="g_")
+    yield "gpt1", lm
+    lm = MoEDecoderLM(num_layers=2, units=256, num_heads=4, num_kv_heads=2,
+                      head_dim=128, expert_hidden=128, num_experts=8,
+                      experts_per_token=2, vocab_size=96, block_length=4,
+                      mask_token_id=95, dtype="bfloat16", prefix="s_")
+    yield "sdar_30b_a3b", lm
+    lm = MLADecoderLM(
+        num_layers=2, units=256, num_heads=4, q_rank=64, kv_rank=128,
+        nope_dim=32, rope_dim=16, v_dim=32, dense_layers=1, dense_hidden=256,
+        expert_hidden=128, num_experts=8, experts_per_token=2, n_group=2,
+        topk_group=1, vocab_size=96, held_experts=(0, 4), dtype="bfloat16",
+        rope_scaling={"factor": 40, "original_max_position_embeddings": 64,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1}, prefix="d_")
+    yield "deepseek_v3", lm
+
+
+def _program_digests(chip):
+    """{program: sha256 of its lowered text} for the small endpoints' step
+    and two prefill rungs (one dense, one through the flash kernel)."""
+    import hashlib
+    import io
+    import jax._src.tpu_custom_call as tcc
+    import mxnet_tpu as mx
+    from mxnet_tpu import serving
+
+    def stripped(module, *, ir_version=None):
+        """``_lower_mosaic_module_to_asm`` with the kernel's source locations
+        taken out before it is serialised."""
+        has = tcc.tpu.private_has_communication(module.operation)
+        with module.context as ctx, module.operation.location as _:
+            op = module.operation.clone()
+            was = ctx.allow_unregistered_dialects
+            ctx.allow_unregistered_dialects = True
+            version = f"target-version={ir_version}" \
+                if ir_version is not None else ""
+            try:
+                tcc.PassManager.parse(
+                    "builtin.module(strip-debuginfo,mosaic-serde{"
+                    "serialize=true " + version + "})").run(op)
+            finally:
+                ctx.allow_unregistered_dialects = was
+            buf = io.BytesIO()
+            op.write_bytecode(buf, desired_version=0)
+            return buf.getvalue(), has
+
+    def text_of(fn, args):
+        text = jax.jit(fn).lower(*args).as_text()
+        text = re.sub(r"loc\(.*?\)|#loc.*", "", text)
+        return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
+
+    lower, tcc._lower_mosaic_module_to_asm = \
+        tcc._lower_mosaic_module_to_asm, stripped
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    out = {}
+    try:
+        for name, lm in _small_endpoints():
+            lm.initialize(mx.init.Normal(0.1))
+            eng = serving.DecodeEndpoint(name, lm, max_seq_len=1024,
+                                         max_batch_size=8, num_pages=129)
+            s = lambda shape, d=I32: jax.ShapeDtypeStruct(shape, d,
+                                                          sharding=chip)
+            of = lambda arrays: tuple(s(tuple(a.shape), a.dtype)
+                                      for a in arrays)
+            params, pools = of(eng._param_datas()), of(eng.pool.arrays)
+            P = eng.pool.pages_per_seq
+            rows = (8,) if eng.block_length == 1 else (8, eng.block_length)
+            # the endpoint's own jit pins the CPU: the traced function anew
+            out[f"{name}.step"] = text_of(
+                eng._decode_fn().__wrapped__,
+                (params, s(rows), s(rows), s((8, P)), s((8,), jnp.bool_),
+                 *pools))
+            for S in (64, 512):
+                out[f"{name}.prefill_s{S}"] = text_of(
+                    eng._prefill_fn().__wrapped__,
+                    (params, s((1, S)), s((1,)), s((1, P)), *pools))
+    finally:
+        tcc._lower_mosaic_module_to_asm = lower
+        jax.default_backend = backend
+    return {k: hashlib.sha256(t.encode()).hexdigest()
+            for k, t in out.items()}
+
+
+def test_the_other_families_programs_lower_to_the_parents_text(one_chip):
+    got = _program_digests(one_chip)
+    assert set(PARENT_DIGESTS) == set(got) - {
+        "deepseek_v3.prefill_s64", "deepseek_v3.prefill_s512"}
+    moved = sorted(k for k, v in PARENT_DIGESTS.items() if got[k] != v)
+    assert not moved, moved
