@@ -28,6 +28,10 @@ to ``block_length`` rows a sequence:
   each expert (layers, E_held)). The block's keys and values come back for
   the caller to write, or not: a denoising step of block diffusion saw mask
   tokens and its keys are dropped.
+- ``decode_step_reading(ids, positions, rows, k_pool, v_pool, tables)``: the
+  same step with logits for ``rows`` (B, R) alone, the rows a sequence that
+  are read, (B, R, V): the endpoint steps two blocks a sequence and reads
+  one.
 
 **Window and full layers in one model** (``layer_types``, ``sliding_window``,
 ``rope_by_type``; causal models): a layer of type ``sliding_attention`` sees
@@ -104,8 +108,8 @@ class DecoderLM(HybridBlock):
     def _run(self, ids, positions, cache=None, last=None):
         """(logits (B, S, V) float32, the rows to cache layer by layer,
         loads (expert layers, E_held)). ``last`` (B,): the one row a
-        sequence whose logits are wanted, (B, 1, V): the final norm and the
-        head then see that row alone."""
+        sequence whose logits are wanted, (B, 1, V), or (B, R): its R rows,
+        (B, R, V): the final norm and the head then see those rows alone."""
         import jax.numpy as jnp
         from ...ops import nn as ops
         x = self.embed_weight.data().data[ids]
@@ -116,7 +120,9 @@ class DecoderLM(HybridBlock):
             if load is not None:
                 loads.append(load)
         if last is not None:
-            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+            x = jnp.take_along_axis(
+                x, last[:, None, None] if last.ndim == 1 else last[..., None],
+                axis=1)
         x = ops.rms_norm(x, self.final_norm.data().data, eps=self.rms_eps)
         logits = jnp.dot(x, self.head_weight.data().data,
                          preferred_element_type=jnp.float32)
@@ -150,13 +156,23 @@ class DecoderLM(HybridBlock):
 
     def decode_step(self, ids, positions, *cache):
         """``cache`` = (the pool's arrays..., tables)."""
+        return self._decode(ids, positions, cache)
+
+    def decode_step_reading(self, ids, positions, rows, *cache):
+        """:meth:`decode_step` with logits for ``rows`` (B, R) alone: the
+        rows a sequence that are read."""
+        (rows,) = self._raw(rows)
+        return self._decode(ids, positions, cache, rows.astype("int32"))
+
+    def _decode(self, ids, positions, cache, rows=None):
         import jax.numpy as jnp
         ids, positions, *cache = self._raw(ids, positions, *cache)
         causal = ids.ndim == 1      # one row a sequence, as TransformerLM's
         if causal:
             ids, positions = ids[:, None], positions[:, None]
         logits, kept, loads = self._run(ids.astype(jnp.int32),
-                                        positions.astype(jnp.int32), cache)
+                                        positions.astype(jnp.int32), cache,
+                                        last=rows)
         if causal:
             logits, kept = logits[:, 0], [a[:, 0] for a in kept]
         return (logits,) + tuple(kept) + (loads,)

@@ -23,12 +23,20 @@ accounting covers decode traffic:
   or rewritten in a step.
 
 A block may state ``block_length`` L > 1 and a ``mask_token_id`` (generation
-by diffusion over blocks, ``gluon.model_zoo.moe_lm``): the step is then L
+by diffusion over blocks, ``gluon.model_zoo.moe_lm``): the step is then 2L
 rows a sequence under the block's own mask, for lanes at any phase of their
-blocks. Each lane's flag says whether this forward *commits* — writes the
-block's K/V in place — or is a denoising step whose keys saw mask tokens and
-are dropped; what comes back per row is the arg-max id other than the mask
-token and its float32 softmax probability, never the logits. The pool's row
+blocks: *slot 0*, the sequence's block at its position, in progress or whole,
+and *slot 1*, the block behind it, which sees slot 0 and the cached context
+as it would have seen them in the pool a forward later. Each lane's flag says
+whether this forward *commits* slot 0 (writes its K/V in place; a whole
+block's) or is a denoising step whose keys saw mask tokens and are dropped;
+slot 1 is never written. What comes back is L rows a lane, slot 1's where the
+forward commits slot 0 (the next block's first denoising step, in the
+forward that commits this one) and slot 0's otherwise, chosen before the
+final norm and the head, and per row the arg-max id other than the mask
+token and its float32 softmax probability, never the logits. There is one
+step program a bucket: a forward that reads slot 0 computes slot 1 and drops
+it (the forward is bound by the weights it reads, whatever its rows). The pool's row
 is the block's ``kv_units`` (default ``units``) and its dtype the
 parameters'. A block that states ``kv_latent`` caches one row a position
 that serves as keys and values (latent attention: ``gluon.model_zoo.mla_lm``):
@@ -75,19 +83,32 @@ def _now_us() -> int:
 
 def _step(block, plist, num_layers, page_size, param_datas, ids, positions,
           tables, valid, *pools):
-    """One traced decode step: run the block's ``decode_step`` on its L rows
-    a lane (``ids``/``positions`` (B,) for L = 1, else (B, L)) against the
-    ``pools`` (K and V, or the one latent array) as they stand, read through
-    ``tables``; then write the rows' K/V in place for the lanes ``valid``
-    flags. Returns (logits, whatever the block returned after its K/V, the
-    pools)."""
+    """One traced decode step: run the block's ``decode_step`` on its rows a
+    lane (``ids``/``positions`` (B,) for L = 1, else (B, 2L): slot 0 and
+    slot 1) against the ``pools`` (K and V, or the one latent array) as they
+    stand, read through ``tables``; then write the rows' K/V (slot 0's L
+    rows) in place for the lanes ``valid`` flags. Returns (logits (of L rows
+    a lane: slot 1's where ``valid``, else slot 0's), whatever the block
+    returned after its K/V, the pools)."""
+    import jax.numpy as jnp
     from ...gluon.block import pure_apply
     by_group = tables if isinstance(tables, tuple) else (tables,)
-    outs, _, _ = pure_apply(block, plist, param_datas,
-                            (ids, positions, *pools, *by_group), None,
-                            training=False, method="decode_step")
     n = len(pools) // len(by_group)     # arrays a group: K and V, or one
     last = 1 + n * num_layers
+    L = int(getattr(block, "block_length", 1))
+    if L == 1:
+        outs, _, _ = pure_apply(block, plist, param_datas,
+                                (ids, positions, *pools, *by_group), None,
+                                training=False, method="decode_step")
+    else:
+        reads = jnp.where(valid, L, 0)[:, None] + jnp.arange(L)
+        outs, _, _ = pure_apply(block, plist, param_datas,
+                                (ids, positions, reads, *pools, *by_group),
+                                None, training=False,
+                                method="decode_step_reading")
+        # slot 0's rows alone are ever written
+        positions = positions[:, :L]
+        outs = outs[:1] + tuple(a[:, :L] for a in outs[1:last]) + outs[last:]
     written = ()
     for g, (layers, window) in enumerate(_groups_of(block, num_layers)):
         written += write_step(
@@ -227,7 +248,9 @@ class DecodeEndpoint:
     attributes, ``prefill_collect(tokens)`` and
     ``decode_step(ids, positions, k_pool, v_pool, tables)``; optionally
     ``kv_units``, ``kv_latent``, ``cache_groups``, ``prefill_reads_row``,
-    ``block_length`` and ``mask_token_id`` (module docstring), and after the
+    ``block_length`` and ``mask_token_id`` (module docstring; with them
+    ``decode_step_reading(ids, positions, rows, *cache)``, whose logits are
+    of ``rows`` (B, L) alone), and after the
     layers' K/V a ``decode_step`` may return the rows routed to each expert,
     (layers, experts), which the step reduces to two numbers.
 
@@ -488,11 +511,17 @@ class DecodeEndpoint:
         return self._compile(self._prefill_execs, seq_bucket,
                              self._prefill_fn(), arg_sds, "prefill")
 
+    @property
+    def step_rows(self) -> int:
+        """Rows a lane a step forwards: one, or two blocks of
+        ``block_length``."""
+        return 1 if self.block_length == 1 else 2 * self.block_length
+
     def _rows_shape(self, batch: int):
         """Shape of a step's ids and positions: a row a lane for one token a
-        step, ``block_length`` rows a lane otherwise."""
+        step, ``step_rows`` a lane otherwise."""
         return (batch,) if self.block_length == 1 \
-            else (batch, self.block_length)
+            else (batch, self.step_rows)
 
     def _get_decode(self, batch_bucket: int):
         import jax
@@ -598,11 +627,14 @@ class DecodeEndpoint:
         mask — their writes land on scratch page 0.
 
         With ``block_length`` L > 1 a row is ``(ids, first position,
-        page_table, commit)``: the L ids of the sequence's current block,
-        and whether this forward writes the block's K/V (a lane that does
-        not is routed to the scratch page like a padding row). Returns
-        ``(ids (L,), confidences (L,))`` per row, and leaves on
-        ``last_step`` what the step's span and counters report."""
+        page_table, commit)``: the 2L ids of the sequence's current block
+        and of the block behind it (mask tokens; padding where the sequence
+        ends with this one), and whether this forward writes the current
+        block's K/V (a lane that does not is routed to the scratch page like
+        a padding row). Returns ``(ids (L,), confidences (L,))`` per row, of
+        the block behind where the forward commits and of the current block
+        otherwise, and leaves on ``last_step`` what the step's span and
+        counters report."""
         return self.finish_step(self.launch_step(rows))
 
     def launch_step(self, rows: Sequence[tuple]) -> "_Launched":
@@ -613,7 +645,7 @@ class DecodeEndpoint:
         donate those, not the arrays this call consumed. The chip runs the
         calls in the order of their launches."""
         n = len(rows)
-        L = self.block_length
+        L, width = self.block_length, self.step_rows
         B = bucketing.bucket_for(n, self.decode_buckets)
         P = self.pool.pages_per_seq
         comp = self._get_decode(B)
@@ -622,7 +654,7 @@ class DecodeEndpoint:
             pos = onp.zeros(self._rows_shape(B), onp.int32)
             tables = onp.zeros((B, P), onp.int32)
             valid = onp.zeros((B,), bool)
-            lanes = onp.arange(L, dtype=onp.int32)
+            lanes = onp.arange(width, dtype=onp.int32)
             ctx_live = 0
             for i, row in enumerate(rows):
                 ids[i] = row[0]
@@ -684,7 +716,8 @@ class DecodeEndpoint:
         if expert_load:
             self.last_step.update(zip(
                 ("moe.expert_load_max", "moe.expert_load_mean"), expert_load))
-        self.stats.record_step(dt, n, call.bucket, rows=n * L,
+        self.stats.record_step(dt, n, call.bucket,
+                               rows=n * self.step_rows,
                                commits=call.commits, expert_load=expert_load,
                                ctx=ctx, ctx_bytes=sum(
                                    c * b for c, b in zip(

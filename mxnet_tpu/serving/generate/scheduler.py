@@ -42,16 +42,24 @@ the pass that fetched it, and a sequence admitted in a pass joins the next
 pass's step.
 
 A step need not yield one token a sequence. An endpoint whose model is
-generated by diffusion over blocks (``block_length`` L > 1) steps L rows a
-sequence, and a pass places 0 to L tokens in it: a denoising step fixes the
-``L / denoising_steps`` still-masked positions of highest confidence, and
-when none is left the block's tokens go to the stream, in sequence order,
-each with the step it was placed at and the confidence it was placed with,
-and the next pass's forward *commits* the block's K/V. Lanes of one batch are at different steps of their blocks.
-The prompt's whole blocks are prefilled; its ragged tail fills the first
-positions of the first block, and positions past ``max_new_tokens`` in the
-last block stay masked. All of a block's state lives on the sequence, so a
-failover requeue resumes it mid-block.
+generated by diffusion over blocks (``block_length`` L > 1) steps 2L rows a
+sequence, its block at ``pos`` and the block behind it, still all mask
+tokens (a *row*, ``_row_locked``), and a pass places 0 to L tokens in it: a
+denoising step fixes the ``L / denoising_steps`` still-masked positions of
+highest confidence, and when none is left the block's tokens go to the
+stream, in sequence order, each with the step it was placed at and the
+confidence it was placed with. The next pass's forward *commits* the whole
+block's K/V and is at once the first denoising step of the block behind it,
+which saw the whole block in the same forward: absorbing its result moves
+``pos`` on, starts that block and places its first tokens. So a block costs
+``denoising_steps`` forwards and no forward of its own to commit it; a
+sequence's last block is finished when it is whole and is never committed
+(nobody reads its K/V). Lanes of one batch are at different steps of their
+blocks. The prompt's whole blocks are prefilled; its ragged tail fills the
+first positions of the first block, and positions past ``max_new_tokens`` in
+the last block stay masked. All of a block's state lives on the sequence,
+"whole and not yet committed" too, so a failover requeue or a paused stream
+resumes where it stood and commits in its next forward.
 """
 from __future__ import annotations
 
@@ -400,7 +408,7 @@ class DecodeScheduler:
                     _faults.check("decode")
                     with _telemetry.span(
                             "decode.step",
-                            rows=len(seqs) * self.engine.block_length,
+                            rows=len(seqs) * self.engine.step_rows,
                             bucket=bucketing.bucket_for(
                                 len(seqs), self.engine.decode_buckets),
                             placed=sum(map(self._places, seqs, rows))) as sp:
@@ -458,25 +466,34 @@ class DecodeScheduler:
     def _row_locked(self, s: "_Seq"):    # mxlint: disable=CONC200
         """``(ids, first position, page table, commit)`` for the engine: the
         last token at its position, always committing, or the current block
-        and whether it is whole and this forward writes its K/V."""
+        with the block behind it, and whether the current one is whole and
+        this forward writes its K/V. The block behind is mask tokens (all
+        that is known lies in the current block), and padding that nothing
+        reads where the sequence ends with this one."""
         table = self.engine.pool.table(s.sid)
-        if self.engine.block_length == 1:
+        L = self.engine.block_length
+        if L == 1:
             return s.emitted[-1], s.pos, table, True
-        return list(s.block), s.pos, table, None not in s.placed
+        return s.block + [self.engine.mask_token_id] * L, s.pos, table, \
+            None not in s.placed
 
     def _places(self, s: "_Seq", row) -> int:
         """Tokens the step about to run fixes in ``s``, which sends it
-        ``row``."""
-        if self.engine.block_length == 1:
+        ``row``: of a forward that commits, in the block behind."""
+        L = self.engine.block_length
+        if L == 1:
             return 1
-        return 0 if row[3] else min(s.per_step, s.placed.count(None))
+        if row[3]:
+            return min(s.per_step, len(s.prompt) + s.max_new - s.pos - L)
+        return min(s.per_step, s.placed.count(None))
 
     def _start_block_locked(self, s: "_Seq"):    # mxlint: disable=CONC200
         """Lay out the block at ``s.pos``: what is known of it (the prompt's
-        ragged tail, in the first block only), then mask tokens."""
+        ragged tail, in the first block only: what was emitted lies in the
+        blocks before), then mask tokens."""
         L = self.engine.block_length
         end = len(s.prompt) + s.max_new
-        known = (s.prompt + s.emitted)[s.pos:s.pos + L]
+        known = s.prompt[s.pos:s.pos + L]
         s.block = known + [self.engine.mask_token_id] * (L - len(known))
         s.placed = [_GIVEN] * len(known) + [
             None if s.pos + i < end else _PAST for i in range(len(known), L)]
@@ -487,9 +504,10 @@ class DecodeScheduler:
                        out) -> int:
         """Take one step's result into ``s``; returns the tokens it fixed.
         One token a step: ``out`` is the next token. Blocks: ``out`` is
-        (ids, confidences) of the block's rows. A forward that committed
-        moves on to the next block; a denoising step places its
-        ``per_step`` most confident masked positions (ties to the lower
+        (ids, confidences) of a block's rows: a forward that committed moves
+        on to the block behind, whose rows they then are (its first
+        denoising step), else the current block's. A denoising step places
+        its ``per_step`` most confident masked positions (ties to the lower
         one), and once the block is whole its tokens are emitted."""
         if self.engine.block_length == 1:
             s.pos += 1
@@ -498,7 +516,7 @@ class DecodeScheduler:
         if commit:
             s.pos += self.engine.block_length
             self._start_block_locked(s)
-            return 0
+            self._stats.commit_merged()
         ids, conf = out
         masked = [i for i, at in enumerate(s.placed) if at is None]
         masked.sort(key=lambda i: (-conf[i], i))

@@ -30,14 +30,16 @@ _STEPS = _telemetry.counter(
 _ROWS = _telemetry.counter(
     "mxtpu_decode_rows_total",
     "Rows of live sequences forwarded by decode steps: one a sequence a step "
-    "for a causal model, block_length a sequence for generation by diffusion "
-    "over blocks.",
+    "for a causal model, two blocks of block_length a sequence for "
+    "generation by diffusion over blocks (the block in hand and the one "
+    "behind it).",
     labelnames=("endpoint",))
 _COMMITS = _telemetry.counter(
     "mxtpu_decode_blocks_committed_total",
     "Sequence-steps whose K/V were written to the pool: every row of a "
-    "causal step; for block diffusion the one forward that commits a "
-    "finished block (its denoising steps write nothing).",
+    "causal step; for block diffusion the forward that commits a finished "
+    "block, which is the next block's first denoising step (the other "
+    "denoising steps write nothing).",
     labelnames=("endpoint",))
 _PLACED = _telemetry.counter(
     "mxtpu_decode_tokens_placed_total",
@@ -120,6 +122,12 @@ class DecodeStats:
             # is counted apart, so that nothing divides tokens by steps
             "forwards": 0, "commits": 0, "rows": 0, "tokens_placed": 0,
             "blocks_committed": 0,
+            # of them, by a forward that was the first denoising step of the
+            # block behind it (counted where that step's result is absorbed).
+            # Every commit a block scheduler sends is one, so the two are
+            # equal but for a forward whose result was never absorbed (a
+            # sequence cancelled under it); 0 for a causal model
+            "commits_merged": 0,
             # cached positions the steps' lanes attended to, of those their
             # lanes could hold (lanes x max_seq_len)
             # ctx_window_live: of them, those a layer that keeps a window
@@ -215,6 +223,12 @@ class DecodeStats:
         with self._lock:
             self.counters["tokens_placed"] += n
         self._m_placed.inc(n)
+
+    def commit_merged(self):
+        """A block's commit rode in the forward whose result, now absorbed,
+        is the first denoising step of the block behind it."""
+        with self._lock:
+            self.counters["commits_merged"] += 1
 
     def record_prefill(self, dur_us: float, overlapped: bool = False):
         """One prefill, ``dur_us`` from its launch until its first token was

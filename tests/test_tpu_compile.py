@@ -514,6 +514,60 @@ def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
     assert comp.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
+def test_the_block_step_of_two_blocks_a_lane_compiles_in_place(one_chip,
+                                                              monkeypatch):
+    """``sdar_30b_a3b.gen256_s2``'s 64-lane ``jit_decode`` at its published
+    widths, no weight drawn: eight rows a lane through the layers (six paged
+    kernels, 18 grouped matmuls over 64 x 8 x 8 = 4,096 pairs), the rows of
+    one block a lane through the head (no (64, 8, V) logits: 311 MB of
+    float32 a step), slot 0's rows written into the donated pools in place."""
+    import functools
+    from chipbench import harness
+    from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
+    from mxnet_tpu.serving.generate import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell, config = harness.load_cell("sdar_30b_a3b.gen256_s2")
+    lm = MoEDecoderLM(
+        num_layers=config["num_hidden_layers"], units=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        expert_hidden=config["moe_intermediate_size"],
+        num_experts=config["num_experts"],
+        experts_per_token=config["num_experts_per_tok"],
+        vocab_size=config["vocab_size"], block_length=config["block_length"],
+        mask_token_id=config["mask_token_id"], dtype="bfloat16",
+        prefix="sdar_")
+    plist = list(lm.collect_params().values())
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    B, L, V = cell["max_batch_size"], config["block_length"], \
+        config["vocab_size"]
+    pool = (config["num_hidden_layers"], cell["num_pages"], PAGE,
+            config["num_key_value_heads"] * config["head_dim"])
+    assert (B, L, V, pool) == (64, 4, 151936, (6, 4097, 16, 512))
+    comp = jax.jit(functools.partial(engine._decode, lm, plist, PAGE,
+                                     config["mask_token_id"]),
+                   donate_argnums=(5, 6)).lower(
+        tuple(sds(tuple(p.shape), jnp.bfloat16) for p in plist),
+        sds((B, 2 * L), I32), sds((B, 2 * L), I32),
+        sds((B, cell["max_seq_len"] // PAGE), I32), sds((B,), jnp.bool_),
+        sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16)).compile()
+    text = comp.as_text()
+    assert set(re.findall(r"f32\[64,\d+,151936\]", text)) \
+        == {"f32[64,4,151936]"}
+    kernels = re.findall(r"= (\S+?)\{[^}]*\} custom-call\([^\n]*tpu_custom_call",
+                         text)
+    assert text.count("tpu_custom_call") == 6 + 18
+    assert sorted(k for k in kernels if k.startswith("f32[4096")) == \
+        ["f32[4096,2048]"] * 6 + ["f32[4096,768]"] * 12
+    assert "input_output_alias" in text.splitlines()[0]
+    makers = set(re.findall(
+        r"= bf16\[%d,%d,%d,%d\]\{[^}]*\} ([\w-]+)\(" % pool, text))
+    assert makers and "copy" not in makers, makers
+    # what the step of one block a lane took (178 MB at the parent)
+    assert comp.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 # ---------------------------------------------------------------------------
 # the other decode families' programs did not move: a pool of one group and
 # the kernel without a bound lower, for the TPU, to the StableHLO text they
@@ -522,7 +576,9 @@ def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
 # with the parent's tree first on the path; source locations, the kernels'
 # debug info and the numbers jax appends to private functions' names are
 # taken out of the text first. ``deepseek_v3``'s prefill is not among them:
-# its head now multiplies the one row that is read.
+# its head now multiplies the one row that is read. ``sdar_30b_a3b.step`` is
+# PR 35's own (two blocks a lane, the rows of one through the head; made by
+# the same function on that PR's tree): every other program is the parent's.
 # ---------------------------------------------------------------------------
 PARENT_DIGESTS = {
     "gpt1.step":
@@ -532,7 +588,7 @@ PARENT_DIGESTS = {
     "gpt1.prefill_s512":
         "7699916e884597e316aa7c18deab21c29f862dfa1e5179481b238bd10ae7d11b",
     "sdar_30b_a3b.step":
-        "2bcd6bd4e5cff2bc0a7f61399f0213c294a4e3c44f31a9839ad749bb527016a0",
+        "a06c3600ccca5cd01e2f2970800bcb66a196a39bd1dd81d82af4ed9830dd5b5d",
     "sdar_30b_a3b.prefill_s64":
         "3c0a79af02da8ad91edc68b57d4f149d2fe3b87220d69ed7df120f22fb5a8762",
     "sdar_30b_a3b.prefill_s512":
@@ -615,7 +671,7 @@ def _program_digests(chip):
                                       for a in arrays)
             params, pools = of(eng._param_datas()), of(eng.pool.arrays)
             P = eng.pool.pages_per_seq
-            rows = (8,) if eng.block_length == 1 else (8, eng.block_length)
+            rows = eng._rows_shape(8)
             # the endpoint's own jit pins the CPU: the traced function anew
             out[f"{name}.step"] = text_of(
                 eng._decode_fn().__wrapped__,
