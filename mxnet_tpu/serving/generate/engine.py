@@ -230,14 +230,25 @@ class _Launched:
         self.ctx_live = ctx_live
         self.ctx_by_group = ctx_by_group    # positions each group's layers read
 
+    def arrays(self) -> tuple:
+        return self.result if isinstance(self.result, tuple) \
+            else (self.result,)
+
     def send_home(self):
         """Start the result's copy to the host now, behind its call on the
         chip: the fetch then waits for the chip alone. Asked for at the
         fetch, each array is a round trip of its own once the chip is done
         (0.6 ms on a v5e; a block step returns four)."""
-        for a in (self.result if isinstance(self.result, tuple)
-                  else (self.result,)):
+        for a in self.arrays():
             a.copy_to_host_async()
+
+    def ready(self) -> int:
+        """1 where the whole result is computed already: a fetch that
+        begins so waits for nothing on the chip, and what it still takes is
+        the host's (the copy home, the interpreter lock); one that begins
+        before is a wait for the chip, which a reader of the pass's time off
+        the processor takes off it."""
+        return int(all(a.is_ready() for a in self.arrays()))
 
 
 class DecodeEndpoint:
@@ -612,7 +623,7 @@ class DecodeEndpoint:
     def finish_prefill(self, call: "_Launched") -> int:
         """The second half: wait for the first generated token."""
         with _telemetry.span("decode.fetch", parent=call.under,
-                             kind="prefill"):
+                             kind="prefill", ready=call.ready()):
             out = int(onp.asarray(call.result)[0])     # sync point
             call.result = None      # the device buffer goes here, in a span
         dt = _now_us() - call.t0
@@ -688,11 +699,8 @@ class DecodeEndpoint:
         n, L = call.lanes, self.block_length
         try:
             with _telemetry.span("decode.fetch", parent=call.under,
-                                 kind="step") as sp:
-                if isinstance(call.result, tuple):      # sync point
-                    out = [onp.asarray(a) for a in call.result]
-                else:
-                    out = [onp.asarray(call.result)]
+                                 kind="step", ready=call.ready()) as sp:
+                out = [onp.asarray(a) for a in call.arrays()]  # sync point
                 call.result = None  # the device buffers go here, in a span
         finally:
             self._step_in_flight = False

@@ -378,8 +378,13 @@ class DecodeScheduler:
         fenced out or the scheduler stopped (every lock take checks the
         fence), ``_AGAIN`` while its last look under the lock saw more to
         run (the next pass then starts with ``decode.build`` taking the
-        lock, so that waiting for it is inside a span), else ``_REST``."""
-        with _telemetry.span("decode.iteration", admits=0, rows=0) as it:
+        lock, so that waiting for it is inside a span), else ``_REST``.
+        The pass's span takes its thread's CPU time too (the one span of
+        a pass that does: two system calls): wall time less that, less the
+        fetches that waited for the chip, is the loop standing without the
+        processor for something on the host."""
+        with _telemetry.span("decode.iteration", cpu=True, admits=0,
+                             rows=0) as it:
             with _telemetry.span("decode.build") as sp:
                 with self._cond:
                     if self._epoch != epoch or self._state == _STOPPED:

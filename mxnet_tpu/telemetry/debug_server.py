@@ -329,8 +329,10 @@ def tracez(limit_traces: int = 50) -> str:
         for s in group:
             dur = s["dur_us"] if s["dur_us"] is not None else 0
             attrs = f" {s['attrs']}" if s["attrs"] else ""
+            cpu = f" (cpu {_fmt_us(s['cpu_us'])})" \
+                if s["cpu_us"] is not None else ""
             lines.append(f"  +{(s['t0_us'] - t0) / 1e3:9.3f}ms "
-                         f"{_fmt_us(dur):>10} {s['name']}{attrs}")
+                         f"{_fmt_us(dur):>10}{cpu} {s['name']}{attrs}")
     return "\n".join(lines) + "\n"
 
 

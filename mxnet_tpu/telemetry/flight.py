@@ -86,6 +86,7 @@ def _span_entry(s) -> Dict:
         "parent_id": s.parent_id,
         "t0_us": s.t0_us,
         "dur_us": s.dur_us,
+        "cpu_us": s.cpu_us,
         "attrs": _clean_attrs(s.attrs) if s.attrs else {},
     }
 

@@ -10,12 +10,22 @@
     the submitter's trace id, and the worker thread re-opens the trace
     around batch assembly and the compiled device step, so a request's
     trace id survives the queue hop.
-  - on exit every span feeds BOTH sinks: the profiler's chrome-trace event
-    stream (when an ``mxnet_tpu.profiler`` session is running — the span
-    lands in the same ``traceEvents`` timeline as per-op events, with the
-    trace id in ``args``), and the registry's
-    ``mxtpu_span_duration_us{name=...}`` histogram (always on — spans are
-    the latency series dashboards scrape).
+  - a span records the wall time it took (``dur_us``) and, where it is
+    opened with ``cpu=True``, the CPU time of the thread that ran it
+    (``cpu_us``, ``time.thread_time_ns``): the difference is the time that
+    thread stood without the processor inside the span (a lock, the
+    interpreter lock, a blocking call). Asked for and not taken on every
+    span, because the thread's CPU clock is a system call: 0.35 us a read on
+    a plain Linux host, but 5.6-6.8 us on the TPU v5e host this was measured
+    on, where it also ticks in 10 ms (PERF.md, PR 36): there a single span's
+    ``cpu_us`` is 0 or 10,000 and only sums over many spans say something.
+  - on exit a span feeds the sinks that have a reader (OBSERVABILITY.md
+    names each): the registry's ``mxtpu_span_duration_us{name=...}``
+    histogram and the flight recorder's ring, always; the profiler's
+    chrome-trace event stream while an ``mxnet_tpu.profiler`` session runs
+    (the span lands in the same ``traceEvents`` timeline as per-op events,
+    with the trace id in ``args``); the per-pid spool while a spool
+    directory is set. A sink that is off costs a span one test.
   - once ``jax`` is imported a span also holds a
     ``jax.profiler.TraceAnnotation`` of the same name (``trace_id`` and
     ``span_id`` as its stats) for its whole life: a no-op while no
@@ -26,9 +36,10 @@ Span names are dot-scoped ``layer.operation`` (``serving.batch``,
 ``train.step``, ``dataloader.wait`` — see OBSERVABILITY.md for the
 convention); attrs are small JSON-able values, never tensors.
 
-Cross-process journeys (the fleet plane): every finished span also lands in
-a bounded in-memory spool buffer; when ``MXNET_SPAN_SPOOL_DIR`` is set the
-buffer drains — every ``MXNET_SPAN_SPOOL_FLUSH_N`` spans, and at interpreter
+Cross-process journeys (the fleet plane): while ``MXNET_SPAN_SPOOL_DIR`` is
+set (read at the process's first span and again at every ``spool_flush()``)
+every finished span also lands in a bounded in-memory spool buffer, which
+drains — every ``MXNET_SPAN_SPOOL_FLUSH_N`` spans, and at interpreter
 exit — into an append-only per-pid JSONL file (``spool-<pid>.jsonl``, the
 compile-ledger file pattern: one ``O_APPEND`` write per batch, size-capped
 and rotated). Each line carries the pid and a wall-clock anchor, so
@@ -41,6 +52,7 @@ from __future__ import annotations
 
 import atexit
 import contextvars
+import itertools
 import json
 import os
 import random
@@ -48,7 +60,6 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 from .metrics import REGISTRY
@@ -67,18 +78,25 @@ _CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
 
 # per-process random source; seeded from urandom, independent of user PRNGs
 _RNG = random.Random()
+# span ids count up, "s1", "s2", ... in hex: unique in the process (``next``
+# of a count is one step under the interpreter lock), and with the pid of a
+# spool line across them. The letter keeps an id a string in the XPlane,
+# whose stats read "10" back as a number
+_SPAN_IDS = itertools.count(1)
 _SPAN_DURATION = REGISTRY.histogram(
     "mxtpu_span_duration_us",
     "Duration of telemetry spans by span name (microseconds).",
     labelnames=("name",))
+# name -> its histogram child's ``observe``, looked up once a name
+_OBSERVE_DURATION: Dict[str, object] = {}
 
 
 def new_trace_id() -> str:
     return f"{_RNG.getrandbits(64):016x}"
 
 
-def _now_us() -> int:
-    return time.perf_counter_ns() // 1000
+_perf_ns = time.perf_counter_ns
+_thread_ns = time.thread_time_ns
 
 
 def _cfg(name, default):
@@ -108,75 +126,87 @@ def _inherited_trace_id() -> Optional[str]:
 
 
 class Span:
-    """One timed region. Created by :func:`span`; read-only for users."""
+    """One timed region, and the context manager that times it:
+    ``with span(name, **attrs) as s``. ``trace_id`` adopts an existing trace
+    (cross-thread propagation); otherwise the parent's trace is inherited,
+    or a fresh trace is started at the root. ``parent`` names the span this
+    one hangs under where that is not the one open around it: the late half
+    of work whose first half ran under a span that has closed since (a
+    launch and the fetch of its result, with other work between them).
+    ``.trace_id`` is the handle to stamp onto queue items / requests for
+    later adoption. ``dur_us`` is the wall time it took, None until it ends.
+    ``cpu_us`` is the CPU time of the thread that ran it (a span exits on
+    the thread that opened it, whatever ``parent`` it was given), taken
+    where ``cpu=True`` asks for it (two system calls) and None otherwise.
+    Read-only for users."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "t0_us", "dur_us")
+                 "t0_us", "dur_us", "cpu_us", "_cpu0_ns", "_token",
+                 "_annotation")
 
-    def __init__(self, name: str, trace_id: str, parent_id: Optional[str],
-                 attrs: Dict):
-        self.name = name
+    def __init__(self, name: str, trace_id: Optional[str] = None,
+                 parent: Optional["Span"] = None, cpu: bool = False,
+                 **attrs):
+        if parent is None:
+            parent = _CURRENT.get()
+        if parent is not None:
+            self.parent_id = parent.span_id
+            if trace_id is None:
+                trace_id = parent.trace_id
+        else:
+            self.parent_id = None
+            if trace_id is None:
+                trace_id = _inherited_trace_id() or new_trace_id()
         self.trace_id = trace_id
-        self.span_id = f"{_RNG.getrandbits(64):016x}"
-        self.parent_id = parent_id
+        self.name = name
+        self.span_id = f"s{next(_SPAN_IDS):x}"
         self.attrs = attrs
-        self.t0_us = _now_us()
-        self.dur_us = None
+        self.dur_us = self.cpu_us = None
+        self.t0_us = _perf_ns() // 1000
+        self._cpu0_ns = _thread_ns() if cpu else None
+
+    def __enter__(self):
+        self._token = _CURRENT.set(self)
+        # a ``jax.profiler.TraceAnnotation`` held for the span's life, once
+        # ``jax`` is imported (telemetry never imports it: lightweight
+        # processes stay off it; None too while ``import jax`` is still
+        # running). No profile running, it is an inactive TraceMe.
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            self._annotation = None
+        else:
+            self._annotation = profiler.TraceAnnotation(
+                self.name, trace_id=self.trace_id, span_id=self.span_id)
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        _CURRENT.reset(self._token)
+        self._annotation = self._token = None
+        self.dur_us = dur_us = _perf_ns() // 1000 - self.t0_us
+        if self._cpu0_ns is not None:
+            self.cpu_us = (_thread_ns() - self._cpu0_ns) // 1000
+        observe = _OBSERVE_DURATION.get(self.name)
+        if observe is None:
+            observe = _OBSERVE_DURATION[self.name] = \
+                _SPAN_DURATION.labels(self.name).observe
+        observe(dur_us)
+        _record_flight_span(self)
+        if _SPOOL_DIR != "":         # a directory is set, or none was read yet
+            _spool(self)
+        profiler = sys.modules.get("mxnet_tpu.profiler")
+        if profiler is not None and profiler._STATE["running"]:
+            _emit_profiler(self, profiler)
 
     def __repr__(self):
         return (f"<Span {self.name} trace={self.trace_id} "
-                f"dur={self.dur_us}us attrs={self.attrs}>")
+                f"dur={self.dur_us}us cpu={self.cpu_us}us "
+                f"attrs={self.attrs}>")
 
 
-@contextmanager
-def span(name: str, trace_id: Optional[str] = None,
-         parent: Optional[Span] = None, **attrs):
-    """Open a nested span. ``trace_id`` adopts an existing trace (cross-thread
-    propagation); otherwise the parent's trace is inherited, or a fresh trace
-    is started at the root. ``parent`` names the span this one hangs under
-    where that is not the one open around it: the late half of work whose
-    first half ran under a span that has closed since (a launch and the
-    fetch of its result, with other work between them). Yields the Span
-    (``.trace_id`` is the handle to stamp onto queue items / requests for
-    later adoption)."""
-    if parent is None:
-        parent = _CURRENT.get()
-    if trace_id is None:
-        if parent is not None:
-            trace_id = parent.trace_id
-        else:
-            trace_id = _inherited_trace_id() or new_trace_id()
-    s = Span(name, trace_id, parent.span_id if parent is not None else None,
-             attrs)
-    token = _CURRENT.set(s)
-    annotation = _xplane_annotation(s)
-    try:
-        yield s
-    finally:
-        if annotation is not None:
-            annotation.__exit__(None, None, None)
-        _CURRENT.reset(token)
-        s.dur_us = _now_us() - s.t0_us
-        _SPAN_DURATION.labels(name).observe(s.dur_us)
-        _record_flight_span(s)
-        _record_spool_span(s)
-        if len(_SPOOL_BUF) >= _SPOOL_FLUSH_N:
-            spool_flush()
-        _emit_profiler(s)
-
-
-def _xplane_annotation(s: Span):
-    """An entered ``jax.profiler.TraceAnnotation`` for ``s``, or None while
-    ``jax`` is not imported (telemetry never imports it: lightweight
-    processes stay off it). No profile running, it is an inactive TraceMe."""
-    # None too while ``import jax`` itself is still running
-    profiler = getattr(sys.modules.get("jax"), "profiler", None)
-    if profiler is None:
-        return None
-    annotation = profiler.TraceAnnotation(
-        s.name, trace_id=s.trace_id, span_id=s.span_id)
-    annotation.__enter__()
-    return annotation
+span = Span
 
 
 def current_span() -> Optional[Span]:
@@ -215,15 +245,16 @@ def self_times(spans) -> Dict[str, int]:
 
 # -- per-pid span spool (the fleet plane's raw material) ----------------------
 #
-# Hot-path discipline mirrors the flight ring: every span exit pays one
-# bounded-deque append; file I/O happens only on a flush (every
-# MXNET_SPAN_SPOOL_FLUSH_N spans, or at exit), and only when a spool
-# directory is configured. With no directory the flush is a buffer clear.
+# Hot-path discipline: with no spool directory a span's exit tests one
+# module global and does nothing else. With one, it pays one bounded-deque
+# append; file I/O happens only on a flush (every MXNET_SPAN_SPOOL_FLUSH_N
+# spans, or at exit). The directory and the cadence are read at the
+# process's first span exit and again at every ``spool_flush()``.
 
 _SPOOL_BUF: deque = deque(maxlen=2048)  # bounded: backlog drops oldest
-_record_spool_span = _SPOOL_BUF.append
 _SPOOL_LOCK = threading.Lock()
-_SPOOL_FLUSH_N = 32          # refreshed from its knob at every flush
+_SPOOL_DIR: Optional[str] = None    # None: not read yet; "": no spool
+_SPOOL_FLUSH_N = 32
 # perf_counter -> wall-clock anchor: spans are timed on the monotonic clock
 # (in-proc ordering), but cross-process assembly needs wall time
 _WALL_ANCHOR_S = time.time() - time.perf_counter()
@@ -234,6 +265,27 @@ _SPOOL_SPANS = REGISTRY.counter(
 _SPOOL_ROTATIONS = REGISTRY.counter(
     "mxtpu_span_spool_rotations_total",
     "Spool-file rotations forced by the MXNET_SPAN_SPOOL_MAX_BYTES size cap.")
+
+
+def _read_spool_knobs():
+    global _SPOOL_DIR, _SPOOL_FLUSH_N
+    _SPOOL_DIR = str(_cfg("MXNET_SPAN_SPOOL_DIR", "") or "")
+    try:
+        _SPOOL_FLUSH_N = max(1, int(_cfg("MXNET_SPAN_SPOOL_FLUSH_N", 32)))
+    except Exception:
+        pass
+
+
+def _spool(s: Span):
+    """Buffer a finished span for the spool file; the first call of the
+    process reads whether there is one."""
+    if _SPOOL_DIR is None:
+        _read_spool_knobs()
+        if not _SPOOL_DIR:
+            return
+    _SPOOL_BUF.append(s)
+    if len(_SPOOL_BUF) >= _SPOOL_FLUSH_N:
+        spool_flush()
 
 
 def spool_path(d: Optional[str] = None) -> str:
@@ -251,27 +303,25 @@ def _spool_line(s: Span) -> Dict:
         "parent_id": s.parent_id,
         "t0_wall": round(_WALL_ANCHOR_S + s.t0_us / 1e6, 6),
         "dur_us": s.dur_us,
+        "cpu_us": s.cpu_us,
         "attrs": _clean_attrs(s.attrs) if s.attrs else {},
     }
 
 
 def spool_flush():
-    """Drain the buffered spans into ``spool-<pid>.jsonl`` (one ``O_APPEND``
-    write for the whole batch; atomic line appends even with several
-    processes sharing the directory). Rotates the file to ``.1`` when it
-    would exceed ``MXNET_SPAN_SPOOL_MAX_BYTES``. Never raises — a broken
-    disk must not take down the span it is trying to record."""
-    global _SPOOL_FLUSH_N
-    try:
-        _SPOOL_FLUSH_N = max(1, int(_cfg("MXNET_SPAN_SPOOL_FLUSH_N", 32)))
-    except Exception:
-        pass
+    """Read the spool's knobs anew and drain the buffered spans into
+    ``spool-<pid>.jsonl`` (one ``O_APPEND`` write for the whole batch;
+    atomic line appends even with several processes sharing the directory).
+    Rotates the file to ``.1`` when it would exceed
+    ``MXNET_SPAN_SPOOL_MAX_BYTES``. Never raises — a broken disk must not
+    take down the span it is trying to record."""
+    _read_spool_knobs()
     with _SPOOL_LOCK:
         if not _SPOOL_BUF:
             return
         batch = list(_SPOOL_BUF)
         _SPOOL_BUF.clear()
-        path = spool_path()
+        path = spool_path(_SPOOL_DIR)
         if not path:
             return
         try:
@@ -336,22 +386,22 @@ def journey(trace_id: str, d: Optional[str] = None) -> List[Dict]:
 
 
 def _reset_spool_for_tests():
-    """Forget buffered spans and the cached inherited trace id (tests that
-    flip MXNET_TRACE_ID / spool knobs mid-process)."""
-    global _INHERITED_RESOLVED, _INHERITED_TRACE
+    """Forget buffered spans, the spool directory and the cached inherited
+    trace id: the next span reads them anew (tests that flip MXNET_TRACE_ID
+    / spool knobs mid-process)."""
+    global _INHERITED_RESOLVED, _INHERITED_TRACE, _SPOOL_DIR
     with _SPOOL_LOCK:
         _SPOOL_BUF.clear()
+    _SPOOL_DIR = None
     _INHERITED_RESOLVED = False
     _INHERITED_TRACE = None
 
 
-def _emit_profiler(s: Span):
-    """Mirror a finished span into the profiler's chrome trace (only when a
-    session is running; module looked up lazily so telemetry never forces the
+def _emit_profiler(s: Span, prof):
+    """Mirror a finished span into the chrome trace of ``prof``, the
+    ``mxnet_tpu.profiler`` module, whose session is running (a span's exit
+    looks the module up in ``sys.modules``: telemetry never forces the
     profiler onto the import path of lightweight processes)."""
-    prof = sys.modules.get("mxnet_tpu.profiler")
-    if prof is None or not prof._STATE["running"]:
-        return
     args = {"trace_id": s.trace_id, "span_id": s.span_id}
     if s.parent_id:
         args["parent_id"] = s.parent_id

@@ -525,6 +525,20 @@ def test_the_ring_keeps_the_readers_invariants(engine):
         assert top["name"] == "decode.iteration"
         assert top is _top(s, ids)
         assert top["start"] <= s["start"] and f["end"] <= top["end"]
+    # the attrs the phase readers rest on
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert all(a["attrs"]["fetch_wait_us"] >= 0
+               for a in by_name["decode.step"])
+    for s in by_name["decode.prefill"]:
+        assert 0 < s["attrs"]["tokens"] <= s["attrs"]["bucket"]
+        assert s["attrs"]["overlapped"] in (0, 1)
+    assert {s["attrs"]["kind"] for s in launches} == {"step", "prefill"}
+    assert all(s["attrs"]["ready"] in (0, 1) for s in fetches)
+    assert by_name["decode.emit"] and all(
+        _top(s, ids)["name"] == "decode.iteration"
+        for s in by_name["decode.emit"])
     # and the reader that walks up from the fetches reads a pass
     readers = {m.NAME: m for m in harness.layer_metric_modules()}
     host = readers["sched_host_ms_per_step.decode"].read({"trace": None})

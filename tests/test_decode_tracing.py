@@ -208,6 +208,65 @@ def test_failover_leaves_no_span_open_and_emits_nothing_twice(engine):
         assert snap[key]["count"] - before[key]["count"] == len(PROMPTS)
 
 
+def test_a_span_keeps_the_cpu_time_of_its_thread():
+    """``cpu_us`` beside ``dur_us`` on a span that asks for it: a span that
+    sleeps has next to none of its wall time, a span that spins until its
+    thread has used 20 ms of the processor has those and, however loaded the
+    host, a far larger share (relative: no speed is held to a number); a
+    span that does not ask reads no clock and says None; the ring's entries
+    and a flight bundle carry the field."""
+    flight.RECORDER.clear()
+    with telemetry.span("test.sleeps", cpu=True) as sleeps:
+        assert sleeps.cpu_us is None and sleeps.dur_us is None
+        time.sleep(0.05)
+    with telemetry.span("test.spins", cpu=True, n=1) as spins:
+        until = time.thread_time_ns() + 20_000_000
+        while time.thread_time_ns() < until:
+            pass
+    assert 0 <= sleeps.cpu_us < 0.2 * sleeps.dur_us
+    assert 20_000 <= spins.cpu_us <= spins.dur_us + 1000
+    assert spins.cpu_us / spins.dur_us > 4 * sleeps.cpu_us / sleeps.dur_us
+    # a span given a parent exits on the thread that opened it: the field
+    # is that thread's
+    with telemetry.span("test.late", parent=sleeps, cpu=True) as late:
+        with telemetry.span("test.unasked") as unasked:
+            pass
+    assert 0 <= late.cpu_us <= late.dur_us + 1000
+    assert unasked.cpu_us is None and unasked.dur_us is not None
+    assert spins.attrs == {"n": 1}              # ``cpu`` is no attr
+    ring = {e["name"]: e for e in flight.recent_spans()}
+    assert ring["test.sleeps"]["cpu_us"] == sleeps.cpu_us
+    assert ring["test.spins"]["cpu_us"] == spins.cpu_us
+    assert ring["test.unasked"]["cpu_us"] is None
+    bundle = {e["name"]: e for e in flight.RECORDER.bundle()["spans"]}
+    assert bundle["test.spins"]["cpu_us"] == spins.cpu_us
+    assert bundle["test.late"]["parent_id"] == sleeps.span_id
+
+
+def test_every_span_of_a_decode_loop_has_its_cpu_time(engine):
+    """Under a real loop every ``decode.*`` span carries ``cpu_us``: the
+    passes took it, and it lies in their ``dur_us`` (the two clocks count
+    whole microseconds apart: 1 ms of room); the others read no clock and
+    say None. Every fetch says whether its result was ``ready``, and the
+    ``fetch_wait_us`` of the ring's steps add up to the endpoint's counter."""
+    before = engine.stats.snapshot()["counters"]["step_fetch_wait_us"]
+    _, _, _, spans, snap, _ = _generate(engine, PROMPTS, BUDGETS)
+    assert len(spans) > 50
+    fetches = [e for e in spans if e["name"] == "decode.fetch"]
+    assert fetches and all(e["attrs"]["ready"] in (0, 1) for e in fetches)
+    for e in spans:
+        if e["name"] == "decode.iteration":
+            assert 0 <= e["cpu_us"] <= e["dur_us"] + 1000, e
+        else:
+            assert e["cpu_us"] is None, e
+    steps = [e for e in spans if e["name"] == "decode.step"]
+    assert sum(e["attrs"]["fetch_wait_us"] for e in steps) == \
+        snap["counters"]["step_fetch_wait_us"] - before
+    # a span's id is a string, one a span of the process
+    assert len({e["span_id"] for e in spans}) == len(spans)
+    assert all(isinstance(e["span_id"], str) for e in spans)
+
+
 def test_self_times_on_a_hand_built_tree():
     def entry(sid, parent, t0, dur):
         return {"name": sid, "span_id": sid, "parent_id": parent,

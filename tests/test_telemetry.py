@@ -454,14 +454,53 @@ def test_telemetry_dump_prometheus_file(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# overhead gate (satellite: instrumented eager dispatch within 10% of the
-# test_eager_latency.py baseline gate)
+# overhead gates: each against a baseline taken call by call in the same
+# window of the same process, so that a loaded host moves both alike
 # ---------------------------------------------------------------------------
-def test_instrumented_eager_dispatch_overhead():
-    """test_eager_latency.py gates p95 eager dispatch at 100 us; with the
-    always-on jit-cache telemetry in the dispatch path the same ops must
-    stay within 10% of that baseline (110 us), measured the same way
-    (best-of-3 windows, warm caches)."""
+def _interleaved_medians(ours, bare, n=400):
+    """(median of ``ours``, median of ``bare``) in microseconds, the two
+    called in turn: the same neighbours load both."""
+    t_ours, t_bare = [], []
+    for _ in range(n):
+        t0 = time.perf_counter_ns()
+        ours()
+        t1 = time.perf_counter_ns()
+        bare()
+        t_bare.append(time.perf_counter_ns() - t1)
+        t_ours.append(t1 - t0)
+    t_ours.sort()
+    t_bare.sort()
+    return t_ours[n // 2] / 1e3, t_bare[n // 2] / 1e3
+
+
+def _best_window(ours, bare, limit, n=400, windows=5):
+    """The window of ``windows`` in which ``ours`` came nearest ``bare``,
+    stopping at the first under ``limit`` times it: a gate asks whether the
+    fast path exists, not whether every window was quiet."""
+    best = None
+    for _ in range(windows):
+        w = _interleaved_medians(ours, bare, n)
+        if best is None or w[0] / w[1] < best[0] / best[1]:
+            best = w
+        if best[0] < limit * best[1]:
+            break
+    return best
+
+
+def test_instrumented_eager_dispatch_overhead(monkeypatch):
+    """The always-on jit-cache telemetry in the eager dispatch path costs
+    the dispatch under a quarter of itself: the same ops dispatched with the
+    telemetry in place and with its counter replaced by one that counts
+    nothing, in turn. A span or a histogram a dispatch (5 us on 20-30) would
+    not pass; the counter's bump reads 1.01-1.06. ``test_eager_latency.py``
+    holds the dispatch itself to the bare jitted call."""
+    from mxnet_tpu.ops import registry as reg
+
+    class NoCounter:
+        def inc(self, n=1.0):
+            pass
+
+    counted, uncounted = reg._JIT_HITS, NoCounter()
     x = mx.nd.array(onp.random.rand(64, 64).astype("float32"))
     y = mx.nd.array(onp.random.rand(64, 64).astype("float32"))
     ops = {
@@ -472,16 +511,62 @@ def test_instrumented_eager_dispatch_overhead():
     for name, f in ops.items():
         for _ in range(30):
             f()
-        best_p95 = None
-        for _ in range(3):
-            ts = []
-            for _ in range(400):
-                t0 = time.perf_counter_ns()
+
+        def bare():
+            monkeypatch.setattr(reg, "_JIT_HITS", uncounted)
+            try:
                 f()
-                ts.append(time.perf_counter_ns() - t0)
-            ts.sort()
-            p95 = ts[int(len(ts) * 0.95)] / 1e3
-            best_p95 = p95 if best_p95 is None else min(best_p95, p95)
-        assert best_p95 < 110.0, (
-            f"{name}: instrumented eager dispatch p95 {best_p95:.1f} us "
-            "exceeds the 100 us baseline + 10% telemetry budget")
+            finally:
+                monkeypatch.setattr(reg, "_JIT_HITS", counted)
+
+        hits = counted.value
+        best = _best_window(f, bare, 1.25)
+        assert counted.value - hits in range(400, 2001, 400)    # one side counts
+        assert best[0] < 1.25 * best[1], (
+            f"{name}: instrumented eager dispatch {best[0]:.1f} us against "
+            f"{best[1]:.1f} us with the telemetry out: over the 25% budget")
+
+
+def test_a_span_costs_a_few_annotations():
+    """A span with no spool directory against the least it could be: a bare
+    ``TraceAnnotation`` and two clock reads, in turn in one window. 5.8-7.3
+    times (the ring, the histogram, the context variable, the thread's CPU
+    clock, the attrs), 8 before the spool and the random ids went. The limit
+    is twice the reading."""
+    import jax
+    from mxnet_tpu.telemetry import tracing
+    tracing._reset_spool_for_tests()
+    assert not tracing.spool_path()
+
+    def ours():
+        with telemetry.span("test.cost", kind="step", bucket=4):
+            pass
+
+    def bare():
+        t0 = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation("test.cost"):
+            pass
+        return time.perf_counter_ns() - t0
+
+    for _ in range(200):
+        ours()
+        bare()
+    best = _best_window(ours, bare, 12.0, n=1000)
+    assert best[0] < 12.0 * best[1], (
+        f"a span costs {best[0]:.2f} us, {best[0] / best[1]:.1f} times a "
+        f"bare annotation and two clock reads ({best[1]:.2f} us)")
+
+
+def test_a_span_without_a_spool_directory_touches_no_spool_state(
+        monkeypatch):
+    from mxnet_tpu.telemetry import tracing
+    monkeypatch.delenv("MXNET_SPAN_SPOOL_DIR", raising=False)
+    tracing._reset_spool_for_tests()
+    flushes = []
+    monkeypatch.setattr(tracing, "spool_flush",
+                        lambda: flushes.append(1))
+    for i in range(100):            # three times the flush cadence
+        with telemetry.span("test.unspooled", i=i):
+            pass
+    assert len(tracing._SPOOL_BUF) == 0 and not flushes
+    assert tracing._SPOOL_DIR == ""         # read once, at the first span

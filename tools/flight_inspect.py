@@ -135,8 +135,11 @@ def render(bundle, path="", threads=False, max_traces=50):
             for s in group:
                 attrs = s.get("attrs") or {}
                 extra = f" {attrs}" if attrs else ""
+                # the CPU time of its thread, on the spans that took it
+                cpu = f" (cpu {_fmt_us(s['cpu_us'])})" \
+                    if s.get("cpu_us") is not None else ""
                 lines.append(f"  +{(s.get('t0_us', 0) - t0) / 1e3:9.3f}ms "
-                             f"{_fmt_us(s.get('dur_us')):>10} "
+                             f"{_fmt_us(s.get('dur_us')):>10}{cpu} "
                              f"{s.get('name')}{extra}")
 
     metrics = bundle.get("metrics", {}).get("metrics", {})
