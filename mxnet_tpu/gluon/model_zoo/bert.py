@@ -281,9 +281,10 @@ class TransformerLM(HybridBlock):
 
     - ``forward(tokens)``: full causal pass, (B, S) -> (B, S, V) logits —
       the training/scoring path and the decode oracle's reference.
-    - ``prefill_collect(tokens)``: full causal pass that also returns every
-      layer's (B, S, H*D) K/V — compiled per sequence-length bucket as the
-      prefill executable.
+    - ``prefill_collect(tokens, last=None)``: full causal pass that also
+      returns every layer's (B, S, H*D) K/V — compiled per sequence-length
+      bucket as the prefill executable. With ``last`` (B,), each prompt's
+      last position, the head multiplies that one row: (B, 1, V) logits.
     - ``decode_step(ids, positions, k_pool, v_pool, tables)``: one token per
       row against its cached context, which every layer reads in the paged
       KV pools through the rows' page tables — compiled per batch bucket as
@@ -294,6 +295,8 @@ class TransformerLM(HybridBlock):
     Both incremental entry points are traced through ``pure_apply(...,
     method=...)`` by serving/generate/engine.py.
     """
+
+    prefill_reads_row = True    # prefill_collect(tokens, last)
 
     def __init__(self, num_layers=2, units=64, hidden_size=128, num_heads=2,
                  vocab_size=256, max_length=128, dropout=0.0,
@@ -328,9 +331,12 @@ class TransformerLM(HybridBlock):
         return F.dot(h.reshape(-1, h.shape[-1]), embed_w.T) \
             .reshape(h.shape[0], h.shape[1], self.vocab_size)
 
-    def prefill_collect(self, tokens):
+    def prefill_collect(self, tokens, last=None):
         """(B, S) tokens -> (logits (B, S, V), k_0, v_0, ..., k_{n-1},
-        v_{n-1}) with each k/v (B, S, H*D)."""
+        v_{n-1}) with each k/v (B, S, H*D). ``last`` (B,), if given: the
+        position of the row a sequence whose logits are read (a prompt's
+        last); that row is taken before the head and the logits are
+        (B, 1, V)."""
         F = _F()
         S = tokens.shape[1]
         positions = F.arange(0, S, dtype="int32")
@@ -340,6 +346,8 @@ class TransformerLM(HybridBlock):
         for layer in self.encoder._layers:
             h, k, v = layer.forward_collect(h, None)
             kvs.extend((k, v))
+        if last is not None:
+            h = F.take_along_axis(h, last.reshape(-1, 1, 1), axis=1)
         embed_w = self._embed_w(h)
         logits = F.dot(h.reshape(-1, h.shape[-1]), embed_w.T) \
             .reshape(h.shape[0], h.shape[1], self.vocab_size)

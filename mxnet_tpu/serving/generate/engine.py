@@ -9,9 +9,10 @@ cache contents. Two families, both routed through
 accounting covers decode traffic:
 
 - **prefill**, bucketed by sequence length (``seq_buckets`` ladder): one
-  full causal forward of a single prompt (``TransformerLM.prefill_collect``
-  traced via ``pure_apply(..., method=...)``), writing every layer's K/V
-  into the sequence's pages and returning the first generated token.
+  full causal forward of a single prompt (the block's
+  ``prefill_collect(tokens, last)`` traced via ``pure_apply(...,
+  method=...)``), writing every layer's K/V into the sequence's pages and
+  returning the first generated token, the arg-max of the one row ``last``.
 - **decode-step**, bucketed by batch size (pow2 ladder): one token for every
   running sequence — run ``TransformerLM.decode_step`` on the pools and the
   rows' page tables, write the new K/V row, greedy-argmax the next token on
@@ -48,9 +49,9 @@ keep every position of a sequence and layers that keep a window of it) gets
 a pool of as many groups (``kv_cache.py``): the executables then carry each
 group's arrays and, in place of the one table a lane, each group's, a window
 group's being a ring; a prefill writes a window group the prompt's last
-window's pages alone. A block whose ``prefill_collect`` takes the row to read
-(``prefill_reads_row``) is given ``length - 1``, so that the head multiplies
-that one row and not the bucket's.
+window's pages alone. ``prefill_collect`` takes the row to read: every
+language model of the zoo states ``prefill_reads_row`` and is given
+``length - 1``, so that the head multiplies that one row and not the bucket's.
 
 Bitwise contract: every model op is per-row and masked lanes carry exactly
 zero softmax weight, so a row's output depends only on its own tokens and
@@ -256,10 +257,15 @@ class DecodeEndpoint:
 
     ``block`` must expose the incremental-decode protocol of
     ``gluon.model_zoo.bert.TransformerLM``: ``num_layers``/``units``
-    attributes, ``prefill_collect(tokens)`` and
-    ``decode_step(ids, positions, k_pool, v_pool, tables)``; optionally
-    ``kv_units``, ``kv_latent``, ``cache_groups``, ``prefill_reads_row``,
-    ``block_length`` and ``mask_token_id`` (module docstring; with them
+    attributes, ``prefill_collect(tokens, last)`` and
+    ``decode_step(ids, positions, k_pool, v_pool, tables)``.
+    ``prefill_collect(tokens, last)`` is the protocol: ``last`` (B,) is the
+    row a prompt whose logits are read, (B, 1, V), and the block states
+    ``prefill_reads_row``; a block without it is served by the old reading
+    (``prefill_collect(tokens)``, ``logits[0, length - 1]``) for
+    compatibility alone. Optionally ``kv_units``, ``kv_latent``,
+    ``cache_groups``, ``block_length`` and ``mask_token_id`` (module
+    docstring; with them
     ``decode_step_reading(ids, positions, rows, *cache)``, whose logits are
     of ``rows`` (B, L) alone), and after the
     layers' K/V a ``decode_step`` may return the rows routed to each expert,

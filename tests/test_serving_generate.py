@@ -25,7 +25,7 @@ from mxnet_tpu.serving.generate import (DecodeEndpoint, DecodeScheduler,
                                         write_prefill, write_step)
 
 
-def _lm(seed=0, **kw):
+def _lm(seed=0, cls=TransformerLM, **kw):
     # the initialisers draw from mx.random's key chain: left where the
     # worker's earlier tests put it, one state in two dozen gives weights
     # whose greedy decode is not history-sensitive (the oracle's own check)
@@ -34,7 +34,7 @@ def _lm(seed=0, **kw):
     cfg = dict(num_layers=2, units=32, hidden_size=64, num_heads=2,
                vocab_size=50, max_length=64)
     cfg.update(kw)
-    lm = TransformerLM(**cfg)
+    lm = cls(**cfg)
     # wide init so greedy argmax is history-sensitive: a decode path that
     # ignored or corrupted the KV context would emit different tokens
     lm.initialize(mx.init.Normal(0.5))
@@ -107,6 +107,48 @@ def test_page_free_then_realloc_is_bitwise_clean(engine):
     other = _serial_decode(engine, [31, 32], 8, 91003)
     again2 = _serial_decode(engine, [21, 22, 23], 8, 91004)
     assert again2 == first and other != first
+
+
+@pytest.mark.parametrize("length", [11, 16])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_prefill_collect_reads_the_row_it_is_given(seed, length):
+    """``prefill_collect(tokens, last)``: the head over the one row ``last``
+    gives that row of the (B, S, V) logits (a (1, U) x (U, V) product in
+    place of one row of an (S, U) x (U, V) one: the last bits may differ,
+    the arg-max does not), and every layer's K and V are bitwise the same."""
+    lm = _lm(seed)
+    rng = onp.random.default_rng(seed)
+    ids = onp.zeros((2, 16), "int32")       # one rung, padded as a bucket is
+    ids[:, :length] = rng.integers(1, 50, (2, length))
+    tokens = mx.nd.array(ids, dtype="int32")
+    last = onp.array([length - 1, length // 2], "int32")
+    whole = [o.asnumpy() for o in lm.prefill_collect(tokens)]
+    row = [o.asnumpy() for o in
+           lm.prefill_collect(tokens, mx.nd.array(last, dtype="int32"))]
+    assert whole[0].shape == (2, 16, 50) and row[0].shape == (2, 1, 50)
+    want = whole[0][onp.arange(2), last]
+    onp.testing.assert_allclose(row[0][:, 0], want, rtol=0, atol=1e-5)
+    assert (row[0][:, 0].argmax(-1) == want.argmax(-1)).all()
+    assert len(row) == len(whole) == 1 + 2 * lm.num_layers
+    for a, b in zip(row[1:], whole[1:]):
+        assert a.shape == (2, 16, 32) and onp.array_equal(a, b)
+
+
+def test_a_block_that_takes_no_row_is_served_by_the_old_reading(engine):
+    """A block without ``prefill_reads_row`` gets ``prefill_collect(tokens)``
+    and row ``length - 1`` of its (1, S, V) logits is read: the same tokens
+    as the row-taking prefill serves."""
+    class WholeLogitsLM(TransformerLM):
+        prefill_reads_row = False
+
+        def prefill_collect(self, tokens):
+            return super().prefill_collect(tokens)
+
+    old = DecodeEndpoint("tlm_whole", _lm(cls=WholeLogitsLM), max_seq_len=64,
+                         max_batch_size=4, page_size=8, num_pages=64)
+    for i, (p, b) in enumerate(zip(PROMPTS[:3], BUDGETS[:3])):
+        assert _serial_decode(old, p, b, 92000 + i) == \
+            _serial_decode(engine, p, b, 92100 + i)
 
 
 def test_defrag_is_bitwise_invisible(engine):

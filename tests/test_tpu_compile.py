@@ -489,6 +489,40 @@ def test_the_cut_models_longest_prefill_compiles_within_the_chip(
     assert "f32[32768,896]" in text and "f32[131072," not in text
 
 
+def test_gpt1s_longest_prefill_multiplies_one_row_by_the_vocabulary(
+        one_chip, monkeypatch):
+    """``gpt1``'s S=512 ``jit_prefill`` at its published widths, no weight
+    drawn: twelve flash kernels, the pools written in place, and no (S, V)
+    array (83 MB of float32 a prefill, 31.8 GFLOP at ``highest``): a
+    ``TransformerLM`` takes the row that is read before its tied head."""
+    import functools
+    from chipbench import harness
+    from mxnet_tpu.gluon.model_zoo.bert import TransformerLM
+    from mxnet_tpu.serving.generate import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell, config = harness.load_cell("gpt1.decode_long")
+    S, V = cell["max_seq_len"], config["vocab_size"]
+    lm = TransformerLM(
+        num_layers=config["n_layer"], units=config["n_embd"],
+        hidden_size=4 * config["n_embd"], num_heads=config["n_head"],
+        vocab_size=V, max_length=config["n_positions"], prefix="gpt1_")
+    plist = list(lm.collect_params().values())
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    params = tuple(sds(tuple(p.shape), jnp.float32) for p in plist)
+    pool = (config["n_layer"], cell["num_pages"], 16, config["n_embd"])
+    comp = jax.jit(functools.partial(engine._prefill, lm, plist, 16, True),
+                   donate_argnums=(4, 5)).lower(
+        params, sds((1, S), I32), sds((1,), I32), sds((1, S // 16), I32),
+        sds(pool, jnp.float32), sds(pool, jnp.float32)).compile()
+    text = comp.as_text()
+    assert not re.search(r"\[(1,)?%d,%d\]" % (S, V), text)
+    assert re.search(r"f32\[(1,)?%d\]" % V, text)     # one row's logits
+    assert text.count("tpu_custom_call") == config["n_layer"]
+    assert "input_output_alias" in text.splitlines()[0]
+    # what the parent's took, the (512, V) logits among it: 46 MB
+    assert comp.memory_analysis().temp_size_in_bytes < 42 << 20
+
+
 def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
                                                monkeypatch):
     """The 32-lane ``jit_decode``: eight paged-attention kernels (two on the
@@ -578,15 +612,18 @@ def test_the_block_step_of_two_blocks_a_lane_compiles_in_place(one_chip,
 # taken out of the text first. ``deepseek_v3``'s prefill is not among them:
 # its head now multiplies the one row that is read. ``sdar_30b_a3b.step`` is
 # PR 35's own (two blocks a lane, the rows of one through the head; made by
-# the same function on that PR's tree): every other program is the parent's.
+# the same function on that PR's tree), and ``gpt1.prefill_s64`` and
+# ``gpt1.prefill_s512`` are PR 37's own (a ``TransformerLM``'s head
+# multiplies the one row that is read; re-made by the same function on that
+# PR's tree): every other program is the parent's.
 # ---------------------------------------------------------------------------
 PARENT_DIGESTS = {
     "gpt1.step":
         "299ddc580175265bde6fac71701056159be0eb49e6ab1c5ec5e1fe5e1e8c1795",
     "gpt1.prefill_s64":
-        "17329dffc7c7888ff4b5ca935b403de7c32143789aef58b684d460dcc4802647",
+        "a565780d948362a0856bec11a711c3095883cc3f3f63f0022be2f71c0dc5704f",
     "gpt1.prefill_s512":
-        "7699916e884597e316aa7c18deab21c29f862dfa1e5179481b238bd10ae7d11b",
+        "116731611874cfde70a33c878f216f62613223f886e6f8a84fee4e2c76a6bb9a",
     "sdar_30b_a3b.step":
         "a06c3600ccca5cd01e2f2970800bcb66a196a39bd1dd81d82af4ed9830dd5b5d",
     "sdar_30b_a3b.prefill_s64":
