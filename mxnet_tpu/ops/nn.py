@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import telemetry as _telemetry
 from .registry import register
 
 
@@ -1041,19 +1042,26 @@ _PAIR_BLOCK = 2048
 # scratch allows: at 32,768 pairs of 2,304 x 896 a pass computes for 2 ms
 # what it reads in 1
 _ALL_HELD_PAIRS = 32768
+_PAIR_SUMS = _telemetry.counter(
+    "mxtpu_moe_pair_sums_total",
+    "Expert passes lowered, by how their (row, expert) pairs reach their "
+    "rows: row_gather = each row reads its own pairs' results once, in its "
+    "own order (one pass over the rows: a step, a prompt of a chip that "
+    "holds every expert); share_passes = a share's pairs 2,048 at a time, "
+    "each pass added to the rows as a product with their 0/1 matrix. "
+    "Counted when a program is traced, not when it runs.",
+    labelnames=("form",))
 
 
-def _expert_pass(x, order, weight, sizes, w_gate, w_up, w_down, top_k):
+def _expert_pass(x, order, sizes, w_gate, w_up, w_down, top_k):
     """The three grouped products over the pairs ``order`` names (sorted by
-    expert, ``sizes`` a held expert), each result row times its ``weight``."""
+    expert, ``sizes`` a held expert): (pairs, H) float32, unweighted; rows
+    past ``sizes.sum()`` are not computed and hold whatever they hold."""
     rows = x[order // top_k]
     gate = _grouped_matmul(rows, w_gate, sizes)
     up = _grouped_matmul(rows, w_up, sizes)
-    y = _grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down,
-                        sizes)
-    # rows past the held experts' pairs are not computed: whatever they hold
-    live = jnp.arange(len(order)) < sizes.sum()
-    return jnp.where(live[:, None], y * weight[:, None], 0.0)
+    return _grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down,
+                           sizes)
 
 
 def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
@@ -1069,21 +1077,36 @@ def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
     expert multiplies exactly its own rows (:func:`_grouped_matmul`).
     ``all_held`` says every expert is here: all T x top_k pairs are then
     gathered at once, or, past ``_ALL_HELD_PAIRS`` of them, the pairs of a
-    block of whole rows at a time (each block is this function over its rows:
-    a row's pairs stay together and are summed in the row's own order; a
-    step's few hundred pairs go as ever). Of a share, the pairs routed here are gathered
-    ``_PAIR_BLOCK`` at a time, as many passes as they fill, and each pass is
-    added to its rows: the work and the scratch grow with the rows routed
-    here, not with all that were routed (a sixteenth of the experts see a
-    sixteenth of 8 x 4,096 prefill rows, whose gathered rows and float32
-    products would be 1.9 GB). The passes are for a share only: adding a
-    pass to its rows costs T x its pairs x H, which a share keeps at a
-    sixteenth of T x T x top_k x H and a chip that holds every expert would
-    pay in full (128 experts of 2,048 x 768, 8 a row, on a v5e: 3.53 ms in
-    one pass against 4.09 in passes at 1,024 rows, 2.84 against 3.00 at 512;
-    PERF.md, PR 32), and it sums a row's pairs in the order they sorted to,
-    where one pass sums them in the row's own order whatever the rest of the
-    batch holds (the step's bitwise contract). Accumulation is float32."""
+    block of whole rows at a time (each block is this function over its
+    rows, written into its place of one result: a row's pairs stay
+    together; a step's few hundred pairs go as ever). Of a share, the pairs
+    routed here are gathered ``_PAIR_BLOCK`` at a time, as many passes as
+    they fill, and each pass is added to its rows: the work and the scratch
+    grow with the rows routed here, not with all that were routed (a
+    sixteenth of the experts see a sixteenth of 8 x 4,096 prefill rows,
+    whose gathered rows and float32 products would be 1.9 GB).
+
+    How a pass's pairs reach their rows. Where all the pairs go in one pass
+    (every expert held, or a share's step or short prompt), each row reads
+    its own pairs' float32 results where they sorted to and sums them,
+    weighted, in its own order ``k = 0, 1, ...``, in float32, rounded to
+    ``x.dtype`` once (:func:`~mxnet_tpu.ops.pallas.pair_sum.pair_sum`): the
+    down-projection's result is read once and nothing of its size is
+    written besides it (on a v5e at 32,768 pairs of 2,304: 0.6 ms a pass
+    where a weighted copy, a copy in pair order and the row sum took 2.9;
+    PERF.md). A pair that was not computed (routed elsewhere, or a padded
+    row's) is never read. A row's result depends on its own pairs alone,
+    whatever the rest of the batch holds (the step's bitwise contract). A
+    share's passes keep the product of each pass with the (row, pair) 0/1
+    matrix: their bound is scratch, not bytes, for holding all of a share's
+    pair results for one read would take T x top_k x H float32 (805 MB at a
+    16,384-row prompt of 4 pairs a row at 3,072 wide). That product costs T
+    x a pass's pairs x H, which a share keeps at a sixteenth of T x T x
+    top_k x H and a chip that holds every expert would pay in full (128
+    experts of 2,048 x 768, 8 a row, on a v5e: 3.53 ms in one pass against
+    4.09 in passes at 1,024 rows, 2.84 against 3.00 at 512; PERF.md), and it
+    sums a row's pairs in the order they sorted to. Accumulation is
+    float32."""
     T, H = x.shape
     top_k = top_i.shape[1]
     held = w_gate.shape[0]
@@ -1096,21 +1119,39 @@ def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
             a = jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
             return a.reshape(-1, rows, a.shape[1])
 
-        out, sizes = lax.map(
-            lambda b: expert_ffn(*b, w_gate, w_up, w_down,
-                                 first_expert=first_expert, all_held=True),
-            (blocks(x, 0), blocks(top_w, 0), blocks(top_i, -1)))
-        return out.reshape(-1, H)[:T], sizes.sum(0)
+        xs, ws, ids = blocks(x, 0), blocks(top_w, 0), blocks(top_i, -1)
+
+        # each block's rows written into their place of one result: stacked
+        # and then cut, the results left 0.7 GB more temporaries in a
+        # 16,384-row prefill of 2,304-wide rows (compiled for a v5e)
+        def one_block(b, carry):
+            out, total = carry
+            part, load = expert_ffn(xs[b], ws[b], ids[b], w_gate, w_up,
+                                    w_down, first_expert=first_expert,
+                                    all_held=True)
+            return (lax.dynamic_update_slice_in_dim(out, part, b * rows, 0),
+                    total + load)
+
+        out, sizes = lax.fori_loop(
+            0, xs.shape[0], one_block,
+            (jnp.zeros((T + pad, H), x.dtype), jnp.zeros((held,), jnp.int32)))
+        return out[:T], sizes
     expert = top_i.reshape(-1) - first_expert
     here = (expert >= 0) & (expert < held)
     expert = jnp.where(here, expert, held)          # elsewhere: sorted last
     order = jnp.argsort(expert, stable=True)
     sizes = jnp.bincount(expert, length=held + 1)[:held].astype(jnp.int32)
-    weight = jnp.where(here, top_w.reshape(-1), 0.0)[order]
     if all_held or pairs <= _PAIR_BLOCK:
-        y = _expert_pass(x, order, weight, sizes, w_gate, w_up, w_down, top_k)
-        out = y[jnp.argsort(order)].reshape(T, top_k, -1).sum(1)
-        return out.astype(x.dtype), sizes
+        _PAIR_SUMS.labels("row_gather").inc()
+        from .pallas.pair_sum import pair_sum
+        y = _expert_pass(x, order, sizes, w_gate, w_up, w_down, top_k)
+        # where each of a row's pairs sorted to; a pair routed elsewhere (or
+        # a padded row's) was not computed and is never read
+        pos = jnp.argsort(order).reshape(T, top_k)
+        return pair_sum(y, pos, expert.reshape(T, top_k), top_w, sizes,
+                        x.dtype), sizes
+    _PAIR_SUMS.labels("share_passes").inc()
+    weight = jnp.where(here, top_w.reshape(-1), 0.0)[order]
     ends = jnp.cumsum(sizes)
     pad = -pairs % _PAIR_BLOCK      # the last pass's slice stays inside
     order = jnp.pad(order, (0, pad))
@@ -1122,8 +1163,11 @@ def expert_ffn(x, top_w, top_i, w_gate, w_up, w_down, *, first_expert=0,
         # what of each expert's run of pairs lies in this pass
         part = jnp.clip(jnp.minimum(ends, at + _PAIR_BLOCK)
                         - jnp.maximum(ends - sizes, at), 0)
-        y = _expert_pass(x, idx, lax.dynamic_slice_in_dim(
-            weight, at, _PAIR_BLOCK), part, w_gate, w_up, w_down, top_k)
+        w = lax.dynamic_slice_in_dim(weight, at, _PAIR_BLOCK)
+        y = _expert_pass(x, idx, part, w_gate, w_up, w_down, top_k)
+        # rows past the held experts' pairs are not computed: whatever they hold
+        live = jnp.arange(_PAIR_BLOCK) < part.sum()
+        y = jnp.where(live[:, None], y * w[:, None], 0.0)
         # each pair's result to its row, as a product with the 0/1 matrix of
         # (row, pair): on a v5e a scatter-add of 2,048 rows of 7,168 into
         # 4,096 takes 3.9 ms, the two products 1.4 (PERF.md, PR 32).

@@ -19,6 +19,7 @@ from mxnet_tpu import serving
 from mxnet_tpu.gluon.model_zoo.mla_lm import MLADecoderLM
 from mxnet_tpu.ops import nn as ops
 from mxnet_tpu.serving.generate import engine as engine_mod
+from three_copies import three_copies
 
 VOCAB, EXPERTS = 96, 16
 YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 16,
@@ -411,6 +412,49 @@ def test_a_share_takes_its_pairs_a_block_at_a_time(monkeypatch):
     assert int(load.sum()) > 2 * 64         # three passes at least
     onp.testing.assert_array_equal(load, load_again)
     onp.testing.assert_allclose(in_passes, at_once, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows", [1, 23])
+def test_a_shares_pass_sums_each_rows_pairs_as_the_three_copies_did(rows):
+    """A share's one pass under the sigmoid rule (a step, a short prompt):
+    each row reads its own pairs held here, those routed elsewhere never
+    computed and never read, to float32 rounding of what the weighted copy,
+    the copy in pair order and the row sum gave."""
+    p = ffn_weights(12)
+    h = jnp.asarray(onp.random.default_rng(13).normal(0, 1, (rows, 64)),
+                    jnp.float32)
+    top_w, top_i = ops.route_grouped_sigmoid(
+        h, p["router"], p["router_bias"], top_k=2, n_group=4, topk_group=2,
+        scale=2.5)
+    experts = tuple(p[k][4:12] for k in ("w_gate", "w_up", "w_down"))
+    out, load = ops.expert_ffn(h, top_w, top_i, *experts, first_expert=4)
+    assert int(load.sum()) == int(((top_i >= 4) & (top_i < 12)).sum())
+    onp.testing.assert_allclose(
+        out, three_copies(h, top_w, top_i, *experts, first=4), rtol=1e-6,
+        atol=1e-7)
+
+
+def test_a_shares_passes_are_counted_by_how_they_reach_their_rows(
+        monkeypatch):
+    """``mxtpu_moe_pair_sums_total`` at a share's shapes: a step's one pass
+    reads its rows' pairs (``row_gather``); a prompt past one pass adds each
+    pass to its rows by the 0/1 product (``share_passes``)."""
+    p = ffn_weights(8)
+    counts = lambda: {f: ops._PAIR_SUMS.labels(f).value
+                      for f in ("row_gather", "share_passes")}
+
+    def traced(rows):
+        before = counts()
+        jax.make_jaxpr(lambda h: ops.moe_ffn(
+            h, p["router"], p["w_gate"][4:12], p["w_up"][4:12],
+            p["w_down"][4:12], p["router_bias"], top_k=2, first_expert=4,
+            n_group=4, topk_group=2, routed_scale=2.5))(
+            jnp.zeros((rows, 64), jnp.float32))
+        return {k: v - before[k] for k, v in counts().items()}
+
+    monkeypatch.setattr(ops, "_PAIR_BLOCK", 64)
+    assert traced(16) == {"row_gather": 1, "share_passes": 0}
+    assert traced(200) == {"row_gather": 0, "share_passes": 1}
 
 
 def test_a_model_that_holds_a_share_of_the_experts(lm, params):

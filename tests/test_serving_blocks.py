@@ -22,6 +22,7 @@ from mxnet_tpu.ops import nn as ops
 from mxnet_tpu.serving.generate import DecodeScheduler
 from mxnet_tpu.serving.generate import engine as engine_mod
 from mxnet_tpu.serving.generate import scheduler as sched_mod
+from three_copies import three_copies
 
 L, MASK, VOCAB = 4, 95, 96
 DIMS = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -580,6 +581,132 @@ def test_a_long_prompts_pairs_go_a_block_of_whole_rows_at_a_time(
         text = str(jax.make_jaxpr(lambda x: ops.moe_ffn(x, *args, top_k=2))(
             x))
         assert "f32[16,64]" in text and "scan" in text
+
+
+def _routed(rows, seed, first=0, held=8):
+    p = layer_weights(seed)
+    x = jnp.asarray(onp.random.default_rng(seed + 1).normal(0, 1, (rows, 64)),
+                    jnp.float32)
+    top_w, top_i = ops.route_softmax(x, p["router"], top_k=2)
+    share = {k: p[k][first:first + held] for k in ("w_gate", "w_up",
+                                                   "w_down")}
+    return x, top_w, top_i, share
+
+
+@pytest.mark.parametrize("form", ["one_pass", "blocks", "share"])
+def test_a_pass_sums_each_rows_pairs_as_the_three_copies_did(
+        form, monkeypatch):
+    """Each row reads its own pairs where they sorted to and sums them in its
+    own order: what the weighted copy, the copy in pair order and the row sum
+    gave, to float32 rounding; a long prompt's blocks each as one pass, the
+    rows that pad the last block routing nowhere and giving 0; a share's
+    pass, whose pairs routed elsewhere were never computed."""
+    first, held = (4, 4) if form == "share" else (0, 8)
+    rows = 53 if form == "blocks" else 16
+    x, top_w, top_i, p = _routed(rows, 11, first, held)
+    args = (p["w_gate"], p["w_up"], p["w_down"])
+    if form == "blocks":
+        monkeypatch.setattr(ops, "_ALL_HELD_PAIRS", 32)
+        pad = -rows % 16
+        bx = jnp.pad(x, ((0, pad), (0, 0)))
+        bw = jnp.pad(top_w, ((0, pad), (0, 0)))
+        bi = jnp.pad(top_i, ((0, pad), (0, 0)), constant_values=-1)
+        want = jnp.concatenate([
+            three_copies(bx[b:b + 16], bw[b:b + 16], bi[b:b + 16], *args)
+            for b in range(0, rows + pad, 16)])
+        # a padded block's rows that route nowhere give exactly 0
+        last, _ = ops.expert_ffn(bx[-16:], bw[-16:], bi[-16:], *args,
+                                 all_held=True)
+        assert not onp.asarray(last[16 - pad:]).any()
+        onp.testing.assert_array_equal(want[rows:], 0.0)
+        want = want[:rows]
+    else:
+        want = three_copies(x, top_w, top_i, *args, first)
+    out, load = ops.expert_ffn(x, top_w, top_i, *args, first_expert=first,
+                               all_held=form != "share")
+    assert int(load.sum()) == int(((top_i >= first)
+                                   & (top_i < first + held)).sum())
+    onp.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-7)
+
+
+# (rows, top_k, experts routed, held, first, width, rows a tile): one tile;
+# tiles whose runs share granules; a share whose pairs routed elsewhere are
+# never read; rows that are not a power of two
+PAIR_SUMS = [pytest.param(64, 8, 16, 16, 0, 256, 64, id="one_tile"),
+             pytest.param(256, 3, 8, 8, 0, 128, 16, id="shared_granules"),
+             pytest.param(64, 4, 32, 8, 8, 128, 8, id="share"),
+             pytest.param(24, 4, 256, 32, 0, 128, 8, id="rows_24")]
+
+
+@pytest.mark.parametrize("T,top_k,E,held,first,H,rows", PAIR_SUMS)
+def test_the_pair_sum_kernel_reads_each_rows_pairs(T, top_k, E, held, first,
+                                                   H, rows):
+    """The Pallas kernel under the interpreter against the plain expression
+    and the three copies, the rows the products did not compute holding
+    NaN: it fetches the granules of each tile's runs, copies a granule two
+    tiles share across, and reads a dead pair nowhere."""
+    from mxnet_tpu.ops.pallas import pair_sum as ps
+    rng = onp.random.default_rng(T + held)
+    top_i = onp.stack([rng.choice(E, top_k, replace=False)
+                       for _ in range(T)]).astype(onp.int32)
+    top_w = rng.random((T, top_k)).astype(onp.float32)
+    expert = top_i.reshape(-1) - first
+    here = (expert >= 0) & (expert < held)
+    expert = onp.where(here, expert, held)
+    order = onp.argsort(expert, kind="stable")
+    sizes = onp.bincount(expert, minlength=held + 1)[:held].astype(onp.int32)
+    y = rng.normal(0, 1, (T * top_k, H)).astype(onp.float32)
+    y[sizes.sum():] = onp.nan
+    weight = onp.where(here, top_w.reshape(-1), 0.0)[order]
+    live = onp.arange(T * top_k) < sizes.sum()
+    want = onp.where(live[:, None], y * weight[:, None], 0.0)[
+        onp.argsort(order)].reshape(T, top_k, H).sum(1, dtype=onp.float32)
+    args = (jnp.asarray(y), jnp.asarray(onp.argsort(order).reshape(T, top_k)),
+            jnp.asarray(expert.reshape(T, top_k)), jnp.asarray(top_w),
+            jnp.asarray(sizes), jnp.float32)
+    dense = ps.pair_sum(*args)
+    kernel = ps._pallas_sum(*args, rows, True)
+    onp.testing.assert_allclose(dense, want, rtol=1e-6, atol=1e-6)
+    onp.testing.assert_allclose(kernel, dense, rtol=1e-6, atol=1e-6)
+    assert not onp.isnan(onp.asarray(kernel)).any()
+    plan = ps._plan(*args[1:5], rows)
+    assert int(plan[4].max()) <= ps._granules(rows, top_k, held) - 1
+    if T // rows > 1 and held <= 8:
+        assert int((plan[3] >= 0).sum()) > 0      # some granule copied across
+
+
+def _pair_sums():
+    return {form: ops._PAIR_SUMS.labels(form).value
+            for form in ("row_gather", "share_passes")}
+
+
+def test_the_counter_says_how_each_pass_reaches_its_rows(monkeypatch):
+    """``mxtpu_moe_pair_sums_total``, counted when a pass is traced: a chip
+    that holds every expert and a share's step read their rows' pairs
+    (``row_gather``); a share's prompt past one pass adds its passes to the
+    rows (``share_passes``)."""
+    monkeypatch.setattr(ops, "_ALL_HELD_PAIRS", 32)
+    p = layer_weights(5)
+    args = (p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    cut = lambda k: p[k][2:4]
+
+    def traced(rows, share=False):
+        x = jnp.zeros((rows, 64), jnp.float32)
+        before = _pair_sums()
+        if share:
+            jax.make_jaxpr(lambda x: ops.moe_ffn(
+                x, p["router"], cut("w_gate"), cut("w_up"), cut("w_down"),
+                top_k=2, first_expert=2))(x)
+        else:
+            jax.make_jaxpr(lambda x: ops.moe_ffn(x, *args, top_k=2))(x)
+        return {k: v - before[k] for k, v in _pair_sums().items()}
+
+    # a prompt of four blocks of whole rows: its one traced pass
+    assert traced(53) == {"row_gather": 1, "share_passes": 0}
+    assert traced(8) == {"row_gather": 1, "share_passes": 0}
+    monkeypatch.setattr(ops, "_PAIR_BLOCK", 16)
+    assert traced(8, share=True) == {"row_gather": 1, "share_passes": 0}
+    assert traced(53, share=True) == {"row_gather": 0, "share_passes": 1}
 
 
 @pytest.mark.parametrize("shares", [2, 4])

@@ -287,10 +287,14 @@ def test_a_share_of_wide_experts_compiles_to_three_grouped_matmul_kernels(
     comp = jax.jit(lambda x, r, g, u, d, b: ops.moe_ffn(
         x, r, g, u, d, b, top_k=8, n_group=8, topk_group=4,
         routed_scale=2.5)).lower(*args).compile()
-    kernels = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call",
-                         comp.as_text())
-    assert sorted(k.split("{")[0] for k in kernels) == sorted(
+    kernels = [k.split("{")[0] for k in re.findall(
+        r"= (\S+) custom-call\([^\n]*tpu_custom_call", comp.as_text())]
+    assert sorted(k for k in kernels if k.startswith("f32[")) == sorted(
         [f"f32[{pairs},{F}]"] * 2 + [f"f32[{pairs},{H}]"])
+    # a step's one pass: its rows read their pairs in one kernel more; a
+    # prompt's passes add theirs to the rows by the 0/1 product
+    assert [k for k in kernels if not k.startswith("f32[")] == (
+        [f"bf16[{rows},{H}]"] if pairs == rows * 8 else [])
     # the gathered rows and the float32 products of one pass, not of all
     # 8 x 4,096 pairs (1.9 GB)
     assert comp.memory_analysis().temp_size_in_bytes < 256 << 20
@@ -466,7 +470,9 @@ def test_the_cut_models_longest_prefill_compiles_within_the_chip(
     """The 16,384-row ``jit_prefill`` at the published widths: eight flash
     kernels and the routed passes' grouped matmuls, under 3.5 GB of
     temporaries beside 10.15 GB of weights and pools, and no (S, V) array:
-    the head multiplies the one row that is read."""
+    the head multiplies the one row that is read. A pass's float32
+    down-projection is the one array of its size: each row reads its own
+    pairs from it (no weighted copy, no copy in pair order)."""
     import functools
     from mxnet_tpu.serving.generate import engine
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -487,6 +493,16 @@ def test_the_cut_models_longest_prefill_compiles_within_the_chip(
     assert len(flash) == 8
     # the routed pairs 32,768 at a time: no product over all 131,072
     assert "f32[32768,896]" in text and "f32[131072," not in text
+    # beside each routed layer's grouped matmul result nothing of its size;
+    # the rows of a pass are the pair-sum kernel's (8 routed layers)
+    assert re.findall(r"= f32\[32768,2304\]\S* ([\w-]+)\(", text) \
+        == ["custom-call"] * 8
+    assert len(re.findall(r"= bf16\[4096,2304\]\S* custom-call\([^\n]*"
+                          r"custom_call_target=\"tpu_custom_call\"", text)) == 8
+    # the parent's tree compiles this program to 2,989,258,752 B of
+    # temporaries (the weighted copy and the copy in pair order beside each
+    # result; its blocks' results stacked, then cut); here 2,393,777,664
+    assert mem.temp_size_in_bytes <= 2_989_258_752, mem.temp_size_in_bytes
 
 
 def test_gpt1s_longest_prefill_multiplies_one_row_by_the_vocabulary(
@@ -526,8 +542,9 @@ def test_gpt1s_longest_prefill_multiplies_one_row_by_the_vocabulary(
 def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
                                                monkeypatch):
     """The 32-lane ``jit_decode``: eight paged-attention kernels (two on the
-    full group's pools, six bounded on the rings) and 24 grouped matmuls;
-    the four pools donated and written in place."""
+    full group's pools, six bounded on the rings), 24 grouped matmuls and
+    the eight layers' pair sums; the four pools donated and written in
+    place."""
     import functools
     from mxnet_tpu.serving.generate import engine
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -538,8 +555,9 @@ def test_the_cut_models_step_compiles_in_place(one_chip, cut_model,
         params, sds((32,), I32), sds((32,), I32), tables,
         sds((32,), jnp.bool_), *pools).compile()
     text = comp.as_text()
-    assert text.count("tpu_custom_call") == 8 + 24
+    assert text.count("tpu_custom_call") == 8 + 24 + 8
     assert len(re.findall(r"= \(f32\[32,1,32,512\]", text)) == 8
+    assert len(re.findall(r"= bf16\[32,2304\]\S* custom-call\(", text)) == 8
     assert "input_output_alias" in text.splitlines()[0]
     for pool in (FULL_POOL, RING_POOL):
         makers = set(re.findall(
@@ -554,7 +572,8 @@ def test_the_block_step_of_two_blocks_a_lane_compiles_in_place(one_chip,
     widths, no weight drawn: eight rows a lane through the layers (six paged
     kernels, 18 grouped matmuls over 64 x 8 x 8 = 4,096 pairs), the rows of
     one block a lane through the head (no (64, 8, V) logits: 311 MB of
-    float32 a step), slot 0's rows written into the donated pools in place."""
+    float32 a step), each layer's 512 rows summing their pairs in one kernel,
+    slot 0's rows written into the donated pools in place."""
     import functools
     from chipbench import harness
     from mxnet_tpu.gluon.model_zoo.moe_lm import MoEDecoderLM
@@ -591,7 +610,8 @@ def test_the_block_step_of_two_blocks_a_lane_compiles_in_place(one_chip,
         == {"f32[64,4,151936]"}
     kernels = re.findall(r"= (\S+?)\{[^}]*\} custom-call\([^\n]*tpu_custom_call",
                          text)
-    assert text.count("tpu_custom_call") == 6 + 18
+    assert text.count("tpu_custom_call") == 6 + 18 + 6
+    assert len(re.findall(r"= bf16\[512,2048\]\S* custom-call\(", text)) == 6
     assert sorted(k for k in kernels if k.startswith("f32[4096")) == \
         ["f32[4096,2048]"] * 6 + ["f32[4096,768]"] * 12
     assert "input_output_alias" in text.splitlines()[0]
@@ -618,8 +638,14 @@ def test_the_block_step_of_two_blocks_a_lane_compiles_in_place(one_chip,
 # PR's tree): every other program is the parent's. ``mellum2_12b``'s, a
 # windowed ``MoEDecoderLM`` of two cache groups, were made on PR 37's tree
 # (the parent of PR 38, which gave that class the attention gate, the
-# post-norms, NoPE layers and the FFN of ``MLADecoderLM``): its programs did
-# not move either.
+# post-norms, NoPE layers and the FFN of ``MLADecoderLM``). The programs
+# whose routed pass is one pass were re-made by the same function on the
+# tree where each row reads its own pairs' results (``ops/pallas/
+# pair_sum.py``): ``sdar_30b_a3b``'s three, ``deepseek_v3.step`` and
+# ``mellum2_12b``'s three. ``trinity_large.share_passes`` (``ops.moe_ffn``
+# at a held share's prefill-pass widths, whose pairs go 2,048 a pass and
+# reach their rows by the 0/1 product) was made on that tree's parent
+# (ae6f1e3) and holds that branch to the parent's text.
 # ---------------------------------------------------------------------------
 PARENT_DIGESTS = {
     "gpt1.step":
@@ -629,19 +655,21 @@ PARENT_DIGESTS = {
     "gpt1.prefill_s512":
         "116731611874cfde70a33c878f216f62613223f886e6f8a84fee4e2c76a6bb9a",
     "sdar_30b_a3b.step":
-        "a06c3600ccca5cd01e2f2970800bcb66a196a39bd1dd81d82af4ed9830dd5b5d",
+        "de131264f9408b99162ea29f29d23889c537d80f526af245d81ba50010312f4f",
     "sdar_30b_a3b.prefill_s64":
-        "3c0a79af02da8ad91edc68b57d4f149d2fe3b87220d69ed7df120f22fb5a8762",
+        "97fe9a4be895531eb2c1c0885684ea33678a89413aa437ec1939b9f4e4d0349b",
     "sdar_30b_a3b.prefill_s512":
-        "bef4d8d52005c627a011ccd974109c6889ea9631ca18e07dc4c9072ec810551f",
+        "9ddd142acb479f772a12734b810af4c1376e78ef830c099b06e53a0551cc1b75",
     "deepseek_v3.step":
-        "47e86cd308853c1b534a7cf8c6f4f02358da7b6d2d7b7f06c528c2f14644a24b",
+        "2248edf58773c697a95ef12914e67ec168fce99be918b383ead46a779dbe1c87",
     "mellum2_12b.step":
-        "b557e70e75a6e71fd4a54cf29b37189bef7958d965c372e5313f499e7567d304",
+        "a37df800ad3afa62c67a04a888ea2835ed9a9aa69ce1ff9fcd4bb5aee94c9352",
     "mellum2_12b.prefill_s64":
-        "85e7f5423fd179af424b992d74b335e7a1bff4d24286a382fbc1abfc6da25347",
+        "cf43b5bca9caf982d3516dab5460fd8dc37c6dd6e1c04dc93ea72985ba27f021",
     "mellum2_12b.prefill_s512":
-        "727c257b0c2b6192a6096df7a42e74fea1c939defe00ab2202fc4fe80a35bb4d",
+        "7ff7b839d57f4f874149be687376b59cf5367719dcab120896a27f4fbf2e6638",
+    "trinity_large.share_passes":
+        "d109a2a7903d0eaebc67c4e5af5e1d3d7e98c011b097ea6426f2405569e87764",
 }
 
 
@@ -743,6 +771,16 @@ def _program_digests(chip):
                 out[f"{name}.prefill_s{S}"] = text_of(
                     eng._prefill_fn().__wrapped__,
                     (params, s((1, S)), s((1,)), tables(1), *pools))
+        # a held share's prompt past one pass, at trinity_large's widths:
+        # 4,096 rows, 32 of 256 experts of 3,072 x 3,072, 4 a row under the
+        # sigmoid rule (16,384 pairs, 2,048 a pass)
+        from mxnet_tpu.ops import nn as ops
+        widths = ((4096, 3072), (3072, 256)) + ((32, 3072, 3072),) * 3 \
+            + ((256,),)
+        out["trinity_large.share_passes"] = text_of(
+            lambda *a: ops.moe_ffn(*a, top_k=4, n_group=1, topk_group=1,
+                                   routed_scale=2.448),
+            tuple(s(w, jnp.bfloat16) for w in widths))
     finally:
         tcc._lower_mosaic_module_to_asm = lower
         jax.default_backend = backend
@@ -830,8 +868,9 @@ def test_trinitys_step_writes_both_groups_in_place(one_chip, trinity,
     """The 24-lane ``jit_decode``: five paged-attention kernels (one on the
     full group's pools, four bounded on the rings) of one name, and twelve
     grouped matmuls of one name (``moe_intermediate_size`` is
-    ``hidden_size``); the four pools donated and written in place, and the
-    rows routed to each held expert brought home (4 layers x 32 experts)."""
+    ``hidden_size``) and the four routed layers' pair sums; the four pools
+    donated and written in place, and the rows routed to each held expert
+    brought home (4 layers x 32 experts)."""
     import functools
     from mxnet_tpu.serving.generate import engine
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -844,7 +883,8 @@ def test_trinitys_step_writes_both_groups_in_place(one_chip, trinity,
         params, sds((B,), I32), sds((B,), I32), tables,
         sds((B,), jnp.bool_), *pools).compile()
     text = comp.as_text()
-    assert text.count("tpu_custom_call") == 5 + 12
+    assert text.count("tpu_custom_call") == 5 + 12 + 4
+    assert len(re.findall(r"= bf16\[24,3072\]\S* custom-call\(", text)) == 4
     assert len(re.findall(r"= \(f32\[24,1,48,1024\]", text)) == 5
     kernels = re.findall(
         r"= (\S+?)\{[^}]*\} custom-call\([^\n]*tpu_custom_call", text)
